@@ -14,9 +14,7 @@ import repro.configs as config_lib
 from repro.checkpointing import checkpoint
 from repro.core.cache import CachePolicy
 from repro.diffusion import sampler, schedule
-from repro.launch.train import train_dit
-from repro.models import common as mcommon
-from repro.models import dit
+from repro.launch.train import restore_dit, train_dit, train_dit_in_child
 
 # --smoke (benchmarks/run.py) shrinks everything via these env knobs.
 # Read at *call* time, never at import: the fleet router (and run.py
@@ -67,37 +65,46 @@ def __getattr__(name: str):
     return fn()
 
 
+def bench_config():
+    """The small DiT config the benches serve (reduced under --smoke)."""
+    cfg = config_lib.get_config("dit-small")
+    return config_lib.reduced(cfg) if reduced() else cfg
+
+
 def get_model():
     """Train (once) and cache the small DiT used by the quality benches."""
-    cfg = config_lib.get_config("dit-small")
-    if reduced():
-        cfg = config_lib.reduced(cfg)
-    specs = dit.dit_specs(cfg)
-    like = mcommon.init_params(specs, jax.random.key(0),
-                               jnp.dtype(cfg.dtype))
-    ckpt = ckpt_dir()
-    step = checkpoint.latest_step(ckpt, "dit")
-    if step >= 0:
-        params = checkpoint.restore(ckpt, step, like, name="dit")
-    else:
-        params = train_dit(cfg, train_steps(), 16, ckpt_dir=ckpt,
+    cfg = bench_config()
+    params = restore_dit(cfg, ckpt_dir())
+    if params is None:
+        params = train_dit(cfg, train_steps(), 16, ckpt_dir=ckpt_dir(),
                            size=img_size())
     return cfg, params
 
 
-def make_fns(cfg, params):
-    size = img_size()
+def ensure_checkpoint() -> None:
+    """Train the bench checkpoint in a child process if it is missing,
+    so a fleet bench's parent never touches a JAX device before its
+    workers (which restore it) boot."""
+    if checkpoint.latest_step(ckpt_dir(), "dit") < 0:
+        train_dit_in_child(bench_config(), train_steps(), 16, ckpt_dir(),
+                           size=img_size())
 
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
 
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        return dit.dit_from_crf(params, crf, tb, cfg, size, size)
-
-    return full_fn, from_crf_fn
+def router_capacity(router, max_batch: int, rounds: int = 2) -> float:
+    """Requests/s of one warm fleet replica serving one full bucket,
+    best of ``rounds`` (the requests share a group, so affinity keeps
+    them on one replica; the replica cuts them by age, so no drain
+    tick is timed)."""
+    from repro.serving.engine import DiffusionRequest
+    best = 0.0
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        futs = [router.submit(DiffusionRequest(request_id=-1 - i, seed=i))
+                for i in range(max_batch)]
+        for f in futs:
+            f.result()
+        best = max(best, max_batch / max(time.perf_counter() - t0, 1e-9))
+    return best
 
 
 def denoiser_flops_per_step(cfg) -> float:
@@ -128,7 +135,7 @@ def ssim(a, b, data_range: float = 2.0) -> float:
                  / ((mu_a ** 2 + mu_b ** 2 + c1) * (va + vb + c2)))
 
 
-def run_policy(cfg, full_fn, from_crf_fn, policy: CachePolicy,
+def run_policy(cfg, full_fn, from_crf_fn, params, policy: CachePolicy,
                x0: jnp.ndarray, n_steps: Optional[int] = None,
                time_it: bool = True) -> Dict:
     if n_steps is None:
@@ -137,14 +144,15 @@ def run_policy(cfg, full_fn, from_crf_fn, policy: CachePolicy,
     n_tok = (img_size() // cfg.patch_size) ** 2
     crf_shape = (x0.shape[0], n_tok, cfg.d_model)
 
-    fn = jax.jit(lambda x: sampler.sample(full_fn, from_crf_fn, x, ts,
-                                          policy, crf_shape=crf_shape))
-    res = fn(x0)
+    fn = jax.jit(lambda p, x: sampler.sample(full_fn, from_crf_fn, p, x,
+                                             ts, policy,
+                                             crf_shape=crf_shape))
+    res = fn(params, x0)
     res.x.block_until_ready()
     wall = None
     if time_it:
         t0 = time.perf_counter()
-        res = fn(x0)
+        res = fn(params, x0)
         res.x.block_until_ready()
         wall = time.perf_counter() - t0
     n_full = int(res.n_full)

@@ -16,6 +16,7 @@ import numpy as np
 from benchmarks import common as B
 from repro.core import frequency
 from repro.diffusion import sampler, schedule
+from repro.models import dit
 
 
 def band_series(crfs: jnp.ndarray, rho: float, method: str):
@@ -49,11 +50,11 @@ def continuity(series: jnp.ndarray) -> float:
 
 def run(out: str = "results/bench/fig2.json"):
     cfg, params = B.get_model()
-    full_fn, _ = B.make_fns(cfg, params)
+    full_fn, _ = dit.denoiser(cfg)
     x0 = jax.random.normal(jax.random.key(3),
                            (2, B.IMG_SIZE, B.IMG_SIZE, cfg.in_channels))
     ts = schedule.timesteps(B.N_STEPS)
-    _, _, crfs = sampler.reference_features(full_fn, x0, ts)
+    _, _, crfs = sampler.reference_features(full_fn, params, x0, ts)
 
     rows = []
     for method in ("dct", "fft"):

@@ -45,7 +45,7 @@ def run(out: str = "results/bench/fig4.json", interval: int = 5):
     ts = schedule.timesteps(B.N_STEPS)
     fwd = jax.jit(lambda lat, t: forward_with_residuals(
         params, lat, jnp.full((lat.shape[0],), t), cfg))
-    full_fn, _ = B.make_fns(cfg, params)
+    full_fn, _ = dit.denoiser(cfg)
 
     pol = CachePolicy(kind="taylorseer", high_order=2)
     feat = None
@@ -71,7 +71,7 @@ def run(out: str = "results/bench/fig4.json", interval: int = 5):
             lw_state = cache_lib.layerwise_update(pol, lw_state, deltas,
                                                   t_now)
             crf_state = cache_lib.update(pol, crf_state, crf, t_now)
-        v, _ = full_fn(x, t_now)
+        v, _ = full_fn(params, x, t_now)
         x = x + (t_next - t_now) * v
 
     rows = [{

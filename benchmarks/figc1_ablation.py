@@ -10,16 +10,17 @@ import jax
 
 from benchmarks import common as B
 from repro.core.cache import CachePolicy
+from repro.models import dit
 
 
 def run(out: str = "results/bench/figc1.json"):
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     x0 = jax.random.normal(jax.random.key(11),
                            (B.BATCH, B.IMG_SIZE, B.IMG_SIZE,
                             cfg.in_channels))
-    base = B.run_policy(cfg, full_fn, from_crf_fn, CachePolicy(kind="none"),
-                        x0)
+    base = B.run_policy(cfg, full_fn, from_crf_fn, params,
+                        CachePolicy(kind="none"), x0)
 
     rows = []
     grids = [
@@ -33,7 +34,7 @@ def run(out: str = "results/bench/figc1.json"):
             for rho in (0.0625, 0.125, 0.25, 0.5):
                 pol = CachePolicy(kind="freqca", interval=n, method=method,
                                   rho=rho, low_order=0, high_order=2)
-                res = B.run_policy(cfg, full_fn, from_crf_fn, pol, x0,
+                res = B.run_policy(cfg, full_fn, from_crf_fn, params, pol, x0,
                                    time_it=False)
                 res["wall_s"] = 0.0
                 row = B.quality_row(f"{method}/rho={rho}/N={n}", res,
@@ -53,7 +54,7 @@ def run(out: str = "results/bench/figc1.json"):
                                       method=method, rho=0.0625,
                                       low_order=lo, high_order=hi)
                     name = f"{method}/({lo},{hi})/N={n}"
-                res = B.run_policy(cfg, full_fn, from_crf_fn, pol, x0,
+                res = B.run_policy(cfg, full_fn, from_crf_fn, params, pol, x0,
                                    time_it=False)
                 res["wall_s"] = 0.0
                 row = B.quality_row(name, res, base["x"], 1.0,

@@ -1,9 +1,11 @@
 """Kernel-path microbenchmarks -> ``results/bench/BENCH_kernels.json``.
 
 Three rows, each pairing a measured wall time with a bytes-moved model
-(the roofline-side story — on CPU the Pallas kernels run in interpret
-mode, so the *bytes* columns are the load-bearing numbers and the
-kernel wall times are correctness-priced, not speed-priced):
+(the roofline-side story).  The kernels take their interpret flag from
+``repro.kernels.ops``: compiled on a TPU, interpreted elsewhere, where
+the *bytes* columns are the load-bearing numbers and the kernel wall
+times are correctness-priced, not speed-priced (each row records
+``interpret``):
 
 * ``cached_step`` — spatial low ring vs the spectral low ring at the
   paper's rho: state bytes, bytes the cached step must move, and the
@@ -27,6 +29,7 @@ from repro.core import frequency
 from repro.core.policies import base as policy_base
 from repro.core.policies.freqca import FreqCaPolicy
 from repro.kernels import dct as dct_kernel
+from repro.kernels import ops
 from repro.models import attention as attn_lib
 
 def _wall(fn, *args, reps: int = 3) -> float:
@@ -96,14 +99,14 @@ def cached_step_row(batch: int, s: int, d: int, rho: float) -> dict:
 
 
 def band_split_row(batch: int, s: int, d: int, rho: float) -> dict:
-    """jnp transform round-trip vs fused spectral kernel (interpret)."""
+    """jnp transform round-trip vs fused spectral kernel."""
     x = jax.random.normal(jax.random.key(1), (batch, s, d))
     itemsize = 4
     m = frequency.spectral_kept_bins(s, rho, "dct")
 
     jnp_split = jax.jit(lambda z: frequency.decompose(z, rho, "dct"))
     kern_split = jax.jit(lambda z: dct_kernel.band_split_spectral(
-        z, rho, "dct", interpret=True))
+        z, rho, "dct", interpret=ops.interpret()))
     return {
         "name": "band_split",
         "batch": batch, "tokens": s, "d_model": d, "rho": rho,
@@ -112,12 +115,13 @@ def band_split_row(batch: int, s: int, d: int, rho: float) -> dict:
         "bytes_jnp": 3 * batch * s * d * itemsize,
         "bytes_kernel": (2 * batch * s * d + batch * m * d) * itemsize,
         "wall_jnp_ms": round(1e3 * _wall(jnp_split, x), 3),
-        "wall_kernel_interpret_ms": round(1e3 * _wall(kern_split, x), 3),
+        "wall_kernel_ms": round(1e3 * _wall(kern_split, x), 3),
+        "interpret": ops.interpret(),
     }
 
 
 def attention_row(batch: int, s: int, heads: int, hd: int) -> dict:
-    """Full-logits sdpa vs flash kernel (interpret), non-causal."""
+    """Full-logits sdpa vs flash kernel, non-causal."""
     q = jax.random.normal(jax.random.key(2), (batch, s, heads, hd))
     k = jax.random.normal(jax.random.key(3), (batch, s, heads, hd))
     v = jax.random.normal(jax.random.key(4), (batch, s, heads, hd))
@@ -135,14 +139,15 @@ def attention_row(batch: int, s: int, heads: int, hd: int) -> dict:
                        + batch * s * heads * hd) * itemsize,
         "bytes_flash": 4 * batch * s * heads * hd * itemsize,
         "wall_sdpa_ms": round(1e3 * _wall(sdpa, q, k, v), 3),
-        "wall_flash_interpret_ms": round(1e3 * _wall(flash, q, k, v), 3),
+        "wall_flash_ms": round(1e3 * _wall(flash, q, k, v), 3),
+        "interpret": ops.interpret(),
     }
 
 
 def _flash_call(q, k, v):
     from repro.kernels import flash_attention as fa
     return fa.flash_attention(q, k, v, 1, causal=False, q_block=128,
-                              kv_block=128, interpret=True)
+                              kv_block=128, interpret=ops.interpret())
 
 
 def run(out: str = "results/bench/BENCH_kernels.json"):

@@ -45,6 +45,22 @@ def main(argv=None) -> None:
                 return f"{lat * 1e6:.0f}", f"{metric}={r[metric]}"
         return "0", ""
 
+    from repro.launch import compile_cache
+    print(f"compile cache: {compile_cache.enable()}")
+    # the fleet benches first: their workers need this process to hold
+    # no JAX device state when they spawn
+    svf = serve_fleet.run(
+        n_requests=16 if args.smoke else 24,
+        max_batch=4 if args.smoke else 8)
+    csv.append("serve_fleet,0,rps_vs_1replica=%s"
+               % svf[-1]["rps_vs_1replica"])
+    svc = serve_chaos.run(n_requests=8 if args.smoke else 12)
+    csv.append("serve_chaos,0,restarts=%s" % svc[-1]["restarts"])
+    svr = serve_multires.run(
+        n_requests=18 if args.smoke else 24,
+        max_batch=4 if args.smoke else 8)
+    csv.append("serve_multires,0,rps_vs_singles=%s"
+               % svr[1]["rps_vs_singles"])
     t1 = table1_flux.run()
     csv.append("table1_flux,%s,%s" % headline(t1))
     if not args.smoke:
@@ -89,18 +105,6 @@ def main(argv=None) -> None:
         max_batch=4 if args.smoke else 8)
     csv.append("serve_quality,0,shed_rps_ratio=%s"
                % svq[-1]["rps_vs_no_shed"])
-    svf = serve_fleet.run(
-        n_requests=16 if args.smoke else 24,
-        max_batch=4 if args.smoke else 8)
-    csv.append("serve_fleet,0,rps_vs_1replica=%s"
-               % svf[-1]["rps_vs_1replica"])
-    svc = serve_chaos.run(n_requests=8 if args.smoke else 12)
-    csv.append("serve_chaos,0,restarts=%s" % svc[-1]["restarts"])
-    svr = serve_multires.run(
-        n_requests=18 if args.smoke else 24,
-        max_batch=4 if args.smoke else 8)
-    csv.append("serve_multires,0,rps_vs_singles=%s"
-               % svr[1]["rps_vs_singles"])
     try:
         rl = roofline.run()
         csv.append("roofline,0,combos=%d" % len(rl))

@@ -32,6 +32,7 @@ from benchmarks import common as B
 from repro.core.policies import FreqCaPolicy
 from repro.serving.engine import DiffusionEngine, DiffusionRequest
 from repro.serving.fleet import FaultInjector, FleetRouter
+from repro.models import dit
 
 MAX_BATCH = 4
 MAX_INFLIGHT = 16
@@ -41,11 +42,11 @@ REJOIN_TIMEOUT_S = 300.0
 def fleet_engine(max_batch: int, interval: int, max_wait_s: float):
     """Worker-side engine builder — module-level so its
     ``functools.partial`` pickles under spawn.  Each worker restores
-    the checkpoint the parent's ``get_model()`` already trained."""
+    the checkpoint ``B.ensure_checkpoint()`` wrote before the spawn."""
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     n_tok = (B.IMG_SIZE // cfg.patch_size) ** 2
-    return DiffusionEngine(full_fn, from_crf_fn,
+    return DiffusionEngine(full_fn, from_crf_fn, params,
                            (B.IMG_SIZE, B.IMG_SIZE, cfg.in_channels),
                            (n_tok, cfg.d_model),
                            FreqCaPolicy(interval=interval, method="dct"),
@@ -73,7 +74,7 @@ def run(out: str = "results/bench/BENCH_serve_chaos.json",
         n_requests: int = 12,
         title: str = "Chaos — kill 1 of 2 replicas mid-stream"):
     factory = functools.partial(fleet_engine, MAX_BATCH, 5, 0.02)
-    B.get_model()               # train/restore once, before any spawn
+    B.ensure_checkpoint()       # trained in a child, before any spawn
 
     rows = []
     for scenario in ("no_fault", "kill_one_of_two"):

@@ -26,23 +26,23 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 
 from benchmarks import common as B
 from repro.core.policies import FreqCaPolicy
 from repro.launch.serve import poisson_stream, serve_fleet_open_loop
-from repro.serving.engine import DiffusionEngine, DiffusionRequest
+from repro.serving.engine import DiffusionEngine
 from repro.serving.fleet import FleetRouter
+from repro.models import dit
 
 
 def fleet_engine(max_batch: int, interval: int, max_wait_s: float):
     """Worker-side engine builder — module-level so its
     ``functools.partial`` pickles under spawn.  Each worker restores
-    the checkpoint the parent's ``get_model()`` already trained."""
+    the checkpoint ``B.ensure_checkpoint()`` wrote before the spawn."""
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     n_tok = (B.IMG_SIZE // cfg.patch_size) ** 2
-    return DiffusionEngine(full_fn, from_crf_fn,
+    return DiffusionEngine(full_fn, from_crf_fn, params,
                            (B.IMG_SIZE, B.IMG_SIZE, cfg.in_channels),
                            (n_tok, cfg.d_model),
                            FreqCaPolicy(interval=interval, method="dct"),
@@ -55,19 +55,18 @@ def run(out: str = "results/bench/BENCH_serve_fleet.json",
         clients: int = 4,
         title: str = "Fleet serving — 1 vs 2 replicas, same stream"):
     factory = functools.partial(fleet_engine, max_batch, interval, 0.02)
+    B.ensure_checkpoint()       # trained in a child, before any spawn
 
-    # capacity probe in-process: drain one full bucket on a warmed
-    # engine, then set the arrival rate far enough above capacity that
-    # one replica is saturated and two have headroom to show scaling
-    probe = factory()
-    probe.warmup(buckets=[max_batch])
-    t0 = time.perf_counter()
-    for i in range(max_batch):
-        probe.submit(DiffusionRequest(request_id=i, seed=i))
-    probe.serve_until_drained()
-    capacity = max_batch / max(time.perf_counter() - t0, 1e-9)
-    rate = 3.0 * capacity
-    del probe
+    # capacity probe on a separate warmed replica, so both rows start
+    # from equally fresh replicas: set the arrival rate far enough above
+    # it that one replica is saturated and two have headroom to show
+    # scaling
+    probe = FleetRouter(factory, n_replicas=1)
+    try:
+        probe.start()
+        rate = 3.0 * B.router_capacity(probe, max_batch)
+    finally:
+        probe.shutdown(drain=True)
 
     host_cpus = os.cpu_count() or 1
     host_limited = host_cpus < 3
@@ -78,7 +77,7 @@ def run(out: str = "results/bench/BENCH_serve_fleet.json",
             router.start()
             # identical arrival plan both rows: same seed, same rate
             plan = poisson_stream(n_requests, rate, B.IMG_SIZE,
-                                  B.get_model()[0].in_channels,
+                                  B.bench_config().in_channels,
                                   edit_every=0)
             outs, wall = serve_fleet_open_loop(router, plan,
                                                clients=clients)

@@ -13,7 +13,10 @@ the (batch-bucket, shape-bucket) signature path:
   a submit carrying an undeclared shape rejected with
   ``ShapeMismatchError`` before it touches the queue.
 * **multires_fleet** — the same plan through a ``FleetRouter`` over 2
-  replicas, each warming the full ladder.  Asserted: nothing dropped,
+  replicas, each warming the full ladder.  It runs first, so this
+  process holds no JAX device state when the workers spawn; its
+  capacity probe sets the stream's arrival rate.  Asserted: nothing
+  dropped,
   ``submitted == resolved + failed`` (a bad-shape submit through the
   router fails fast and leaves the counters in step), zero
   steady-state recompiles on every replica.
@@ -34,8 +37,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-
-import jax.numpy as jnp
 
 from benchmarks import common as B
 from repro.core.policies import FreqCaPolicy
@@ -65,20 +66,11 @@ def multires_engine(max_batch: int, interval: int, max_wait_s: float,
     shape-generic (image side recovered from the token count), so one
     callable serves the whole ladder."""
     cfg, params = B.get_model()
-
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        side = int(round(crf.shape[1] ** 0.5)) * cfg.patch_size
-        return dit.dit_from_crf(params, crf, tb, cfg, side, side)
-
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     sizes = list(sizes) if sizes else [B.img_size()]
     pairs = shape_pairs(cfg, sizes)
-    return DiffusionEngine(full_fn, from_crf_fn, pairs[0][0], pairs[0][1],
+    return DiffusionEngine(full_fn, from_crf_fn, params, pairs[0][0],
+                           pairs[0][1],
                            FreqCaPolicy(interval=interval, method="dct"),
                            n_steps=B.N_STEPS, max_batch=max_batch,
                            max_wait_s=max_wait_s, shapes=pairs[1:])
@@ -104,10 +96,59 @@ def run(out: str = "results/bench/BENCH_serve_multires.json",
         n_requests: int = 18, max_batch: int = 4, interval: int = 5,
         title: str = "Multi-resolution serving — one (batch, shape) "
                      "bucketed engine"):
-    cfg, _ = B.get_model()
+    cfg = B.bench_config()
     sizes = ladder_sizes()
     pairs = shape_pairs(cfg, sizes)
     rows = []
+
+    # --- leg 3 (run first): 2-replica fleet, the mixed stream ----------
+    # this process touches no device before the workers have booted
+    B.ensure_checkpoint()
+    factory = functools.partial(multires_engine, max_batch, interval,
+                                0.02, sizes)
+    router = FleetRouter(factory, n_replicas=2)
+    try:
+        router.start()
+        # capacity probe (primary shape) on one warm replica: sets an
+        # arrival rate an engine can sustain without the open-loop
+        # replay dragging on for minutes
+        rate = 2.0 * B.router_capacity(router, max_batch)
+        plan = poisson_stream(n_requests, rate, B.img_size(),
+                              cfg.in_channels, edit_every=0, shapes=pairs)
+        bad = DiffusionRequest(request_id=-1, seed=0,
+                               latent_shape=(B.img_size() + 2,) * 2
+                               + (cfg.in_channels,))
+        fplan = [dataclasses.replace(r, submit_time=0.0) for r in plan]
+        f_outs, f_wall = serve_fleet_open_loop(router, fplan, clients=4)
+        # bad-shape submit through the router: synchronous rejection,
+        # counters stay in step (submitted never incremented)
+        try:
+            router.submit(dataclasses.replace(bad))
+            fleet_bad_rejected = False
+        except ShapeMismatchError:
+            fleet_bad_rejected = True
+        fm = router.fleet_metrics()
+        rt = router.status()["counters"]
+    finally:
+        router.shutdown(drain=True)
+    s = fm.summary()
+    fleet_steady = {idx: pr["steady_recompiles"]
+                    for idx, pr in s["per_replica"].items()}
+    fleet_row = {
+        "leg": "multires_fleet",
+        "replicas": 2,
+        "shapes": len(pairs),
+        "submitted": n_requests,
+        "served": len(f_outs),
+        "dropped": n_requests - len(f_outs),
+        "unresolved": rt["submitted"] - rt["resolved"] - rt["failed"],
+        "wall_s": round(f_wall, 3),
+        "req_per_s": round(len(f_outs) / max(f_wall, 1e-9), 3),
+        "steady_recompiles": fleet_steady,
+        "bad_shape_rejected": fleet_bad_rejected,
+        "shape_keys": s["fleet"].get("shape_keys", 0),
+    }
+
 
     # --- leg 1: one engine, mixed-shape Poisson stream ------------------
     eng = multires_engine(max_batch, interval, 0.02, sizes=sizes)
@@ -115,25 +156,12 @@ def run(out: str = "results/bench/BENCH_serve_multires.json",
     budget = eng.signature_budget()
     warm_sigs = eng.compiled_buckets()
 
-    # capacity probe (primary shape): sets an arrival rate the engine
-    # can sustain without the open-loop replay dragging on for minutes
-    t0 = time.perf_counter()
-    for i in range(max_batch):
-        eng.submit(DiffusionRequest(request_id=10_000 + i, seed=i))
-    eng.serve_until_drained()
-    rate = 2.0 * max_batch / max(time.perf_counter() - t0, 1e-9)
-
     pre = eng.metrics_dict()["compile_misses"]
     pure_cuts = _count_pure_cuts(eng)
-    plan = poisson_stream(n_requests, rate, B.img_size(), cfg.in_channels,
-                          edit_every=0, shapes=pairs)
     outs, wall = serve_open_loop(eng, plan)
     steady = eng.metrics_dict()["compile_misses"] - pre
 
     # bad-shape submit: rejected at the API boundary, queue untouched
-    bad = DiffusionRequest(request_id=-1, seed=0,
-                           latent_shape=(B.img_size() + 2,) * 2
-                           + (cfg.in_channels,))
     try:
         eng.submit(bad)
         bad_rejected = False
@@ -199,43 +227,7 @@ def run(out: str = "results/bench/BENCH_serve_multires.json",
         "singles_signatures_total": singles_sigs,
     })
     del eng
-
-    # --- leg 3: 2-replica fleet, same mixed stream ----------------------
-    factory = functools.partial(multires_engine, max_batch, interval,
-                                0.02, sizes)
-    router = FleetRouter(factory, n_replicas=2)
-    try:
-        router.start()
-        fplan = [dataclasses.replace(r, submit_time=0.0) for r in plan]
-        f_outs, f_wall = serve_fleet_open_loop(router, fplan, clients=4)
-        # bad-shape submit through the router: synchronous rejection,
-        # counters stay in step (submitted never incremented)
-        try:
-            router.submit(dataclasses.replace(bad))
-            fleet_bad_rejected = False
-        except ShapeMismatchError:
-            fleet_bad_rejected = True
-        fm = router.fleet_metrics()
-        rt = router.status()["counters"]
-    finally:
-        router.shutdown(drain=True)
-    s = fm.summary()
-    fleet_steady = {idx: pr["steady_recompiles"]
-                    for idx, pr in s["per_replica"].items()}
-    rows.append({
-        "leg": "multires_fleet",
-        "replicas": 2,
-        "shapes": len(pairs),
-        "submitted": n_requests,
-        "served": len(f_outs),
-        "dropped": n_requests - len(f_outs),
-        "unresolved": rt["submitted"] - rt["resolved"] - rt["failed"],
-        "wall_s": round(f_wall, 3),
-        "req_per_s": round(len(f_outs) / max(f_wall, 1e-9), 3),
-        "steady_recompiles": fleet_steady,
-        "bad_shape_rejected": fleet_bad_rejected,
-        "shape_keys": s["fleet"].get("shape_keys", 0),
-    })
+    rows.append(fleet_row)
 
     # rows carry per-leg schemas: one table per leg
     for r in rows:

@@ -44,6 +44,7 @@ from repro.core.policies import (FreqCaErrorBudgetPolicy, FreqCaPolicy,
 from repro.diffusion import sampler, schedule
 from repro.serving import metrics as metrics_lib
 from repro.serving.engine import DiffusionEngine, DiffusionRequest
+from repro.models import dit
 
 BUDGETS = (0.05, 0.2, 0.5)
 INTERVALS = (2, 3, 5)
@@ -66,20 +67,20 @@ class _SchedMeasured(FreqCaErrorBudgetPolicy):
                               peak=jnp.maximum(state.peak, acc)), act
 
 
-def _stiff_fns(cfg, params, n_steps):
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+def _stiff_fns(cfg, n_steps):
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     freq = 0.5 * n_steps          # ~0.5 rad per step at any n_steps
 
-    def stiff_full(x, t):
-        _, crf = full_fn(x, jnp.full((), 0.5))
+    def stiff_full(params, x, t):
+        _, crf = full_fn(params, x, jnp.full((), 0.5))
         # amplitude decays with t^2: early trajectory stiff, tail calm
         crf = crf * (1.0 + AMP * t * t * jnp.sin(freq * t))
-        return from_crf_fn(crf, t), crf
+        return from_crf_fn(params, crf, t), crf
 
     return stiff_full, from_crf_fn
 
 
-def _pareto_rows(cfg, full_fn, from_crf_fn, n_steps):
+def _pareto_rows(cfg, full_fn, from_crf_fn, params, n_steps):
     n_tok = (B.IMG_SIZE // cfg.patch_size) ** 2
     x0 = jax.random.normal(jax.random.key(0),
                            (B.BATCH, B.IMG_SIZE, B.IMG_SIZE,
@@ -88,9 +89,9 @@ def _pareto_rows(cfg, full_fn, from_crf_fn, n_steps):
     crf_shape = (B.BATCH, n_tok, cfg.d_model)
 
     def run_pol(pol):
-        fn = jax.jit(lambda x: sampler.sample(
-            full_fn, from_crf_fn, x, ts, pol, crf_shape=crf_shape))
-        res = fn(x0)
+        fn = jax.jit(lambda p, x: sampler.sample(
+            full_fn, from_crf_fn, p, x, ts, pol, crf_shape=crf_shape))
+        res = fn(params, x0)
         res.x.block_until_ready()
         return res
 
@@ -135,8 +136,8 @@ def _pareto_rows(cfg, full_fn, from_crf_fn, n_steps):
     return rows, wins
 
 
-def _shed_rows(cfg, full_fn, from_crf_fn, n_steps, n_requests, max_batch,
-               tight, shed_factor, shed_depth):
+def _shed_rows(cfg, full_fn, from_crf_fn, params, n_steps, n_requests,
+               max_batch, tight, shed_factor, shed_depth):
     n_tok = (B.IMG_SIZE // cfg.patch_size) ** 2
     tight_pol = FreqCaErrorBudgetPolicy(
         method="dct", rho=0.25).with_budget(tight)
@@ -145,7 +146,7 @@ def _shed_rows(cfg, full_fn, from_crf_fn, n_steps, n_requests, max_batch,
     rows = []
     for name, depth in [("no_shed", None), ("shed", shed_depth)]:
         eng = DiffusionEngine(
-            full_fn, from_crf_fn,
+            full_fn, from_crf_fn, params,
             (B.IMG_SIZE, B.IMG_SIZE, cfg.in_channels),
             (n_tok, cfg.d_model), tight_pol, n_steps=n_steps,
             max_batch=max_batch, shed_depth=depth,
@@ -198,10 +199,11 @@ def run(out: str = "results/bench/BENCH_serve_quality.json",
         title: str = "Quality SLO — error budgets, shedding"):
     n_steps = n_steps or max(B.N_STEPS, 32)
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = _stiff_fns(cfg, params, n_steps)
-    pareto, wins = _pareto_rows(cfg, full_fn, from_crf_fn, n_steps)
-    shed_rows = _shed_rows(cfg, full_fn, from_crf_fn, n_steps, n_requests,
-                           max_batch, tight, shed_factor, shed_depth)
+    full_fn, from_crf_fn = _stiff_fns(cfg, n_steps)
+    pareto, wins = _pareto_rows(cfg, full_fn, from_crf_fn, params, n_steps)
+    shed_rows = _shed_rows(cfg, full_fn, from_crf_fn, params, n_steps,
+                           n_requests, max_batch, tight, shed_factor,
+                           shed_depth)
     B.print_table(title + " (Pareto)", pareto)
     B.print_table(title + " (shedding)", shed_rows)
     rows = pareto + shed_rows

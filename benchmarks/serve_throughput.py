@@ -43,12 +43,13 @@ from repro.launch.serve import (mixed_stream, poisson_stream,
                                 serve_threaded_open_loop)
 from repro.serving import metrics as metrics_lib
 from repro.serving.engine import DiffusionEngine, DiffusionRequest
+from repro.models import dit
 
 
-def _engine(full_fn, from_crf_fn, cfg, policy, max_batch, pad_to_max=False,
-            max_wait_s=0.0, group_policies=False):
+def _engine(full_fn, from_crf_fn, params, cfg, policy, max_batch,
+            pad_to_max=False, max_wait_s=0.0, group_policies=False):
     n_tok = (B.IMG_SIZE // cfg.patch_size) ** 2
-    return DiffusionEngine(full_fn, from_crf_fn,
+    return DiffusionEngine(full_fn, from_crf_fn, params,
                            (B.IMG_SIZE, B.IMG_SIZE, cfg.in_channels),
                            (n_tok, cfg.d_model), policy,
                            n_steps=B.N_STEPS, max_batch=max_batch,
@@ -60,7 +61,7 @@ def run(out: str = "results/bench/BENCH_serve.json",
         n_requests: int = 24, max_batch: int = 8, interval: int = 5,
         title: str = "Serving throughput — bucketed vs pad-to-max"):
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     policy = FreqCaPolicy(interval=interval, method="dct")
 
     def row(name, eng, outs, wall, warm, warm_misses):
@@ -85,7 +86,7 @@ def run(out: str = "results/bench/BENCH_serve.json",
 
     rows = []
     for name, pad in [("pad_to_max (seed)", True), ("bucketed", False)]:
-        eng = _engine(full_fn, from_crf_fn, cfg, policy, max_batch,
+        eng = _engine(full_fn, from_crf_fn, params, cfg, policy, max_batch,
                       pad_to_max=pad)
         # pad-to-max only ever sees one signature; bucketed precompiles
         # the whole ladder — both amortised over the process lifetime
@@ -99,7 +100,7 @@ def run(out: str = "results/bench/BENCH_serve.json",
     # open-loop Poisson client against the bucketed engine: arrivals at
     # ~75% of its closed-loop throughput, batches cut by queue pressure
     rate = max(0.75 * rows[-1]["req_per_s"], 0.5)
-    eng = _engine(full_fn, from_crf_fn, cfg, policy, max_batch,
+    eng = _engine(full_fn, from_crf_fn, params, cfg, policy, max_batch,
                   max_wait_s=0.02)
     warm = eng.warmup()
     warm_misses = eng.metrics_dict()["compile_misses"]
@@ -130,7 +131,7 @@ def run_mixed(out: str = "results/bench/BENCH_serve_mixed.json",
     from repro.serving.scheduler import bucket_sizes
 
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     default = FreqCaPolicy(interval=interval, method="dct")
     policies = [default,
                 ForaPolicy(interval=max(interval // 2, 1)),
@@ -151,7 +152,7 @@ def run_mixed(out: str = "results/bench/BENCH_serve_mixed.json",
     rows = []
     for name, grouped in [("ungrouped (per-mix sigs)", False),
                           ("grouped (policy-pure)", True)]:
-        eng = _engine(full_fn, from_crf_fn, cfg, default, max_batch,
+        eng = _engine(full_fn, from_crf_fn, params, cfg, default, max_batch,
                       group_policies=grouped)
         # grouped: one uniform ladder per compatibility group covers
         # every signature a policy-pure former can cut.  Ungrouped: the
@@ -227,7 +228,7 @@ def run_async(out: str = "results/bench/BENCH_serve_async.json",
     out ``max_wait_s``, on top of the p95/TTFR latency win.)
     """
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     policy = FreqCaPolicy(interval=interval, method="dct")
 
     # n_requests deliberately NOT a multiple of max_batch: under
@@ -237,7 +238,7 @@ def run_async(out: str = "results/bench/BENCH_serve_async.json",
         n_requests += 1
 
     def fresh_engine():
-        eng = _engine(full_fn, from_crf_fn, cfg, policy, max_batch,
+        eng = _engine(full_fn, from_crf_fn, params, cfg, policy, max_batch,
                       max_wait_s=0.15)
         eng.warmup()
         return eng, eng.metrics_dict()["compile_misses"]

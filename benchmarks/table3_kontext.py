@@ -14,27 +14,28 @@ from benchmarks import common as B
 from repro.core.cache import CachePolicy
 from repro.data import synthetic
 from repro.diffusion import schedule
+from repro.models import dit
 
 
 def run(method: str = "dct", title: str = "Table 3 — Kontext-like editing (DCT)",
         out: str = "results/bench/table3.json", tau: float = 0.6):
     cfg, params = B.get_model()
-    full_fn, from_crf_fn = B.make_fns(cfg, params)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
     ref_img = synthetic.shapes_batch(jax.random.key(7), B.BATCH,
                                      size=B.IMG_SIZE,
                                      channels=cfg.in_channels)
     noise = jax.random.normal(jax.random.key(8), ref_img.shape)
     x0 = schedule.add_noise(ref_img, noise, tau)
 
-    base = B.run_policy(cfg, full_fn, from_crf_fn, CachePolicy(kind="none"),
-                        x0)
+    base = B.run_policy(cfg, full_fn, from_crf_fn, params,
+                        CachePolicy(kind="none"), x0)
     rows = [B.quality_row("full edit (baseline)", base, base["x"],
                           base["wall_s"], base["flops"])]
     for interval in (5, 7, 10):
         for kind in ("fora", "taylorseer", "freqca"):
             pol = CachePolicy(kind=kind, interval=interval, method=method,
                               rho=0.0625, high_order=2)
-            res = B.run_policy(cfg, full_fn, from_crf_fn, pol, x0)
+            res = B.run_policy(cfg, full_fn, from_crf_fn, params, pol, x0)
             rows.append(B.quality_row(f"{kind}(N={interval})", res,
                                       base["x"], base["wall_s"],
                                       base["flops"]))

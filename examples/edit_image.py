@@ -17,17 +17,7 @@ from repro.models import dit
 cfg = config_lib.get_config("dit-small")
 params = train_dit(cfg, steps=120, batch=16, ckpt_dir="", size=32)
 
-
-def full_fn(x, t):
-    tb = jnp.full((x.shape[0],), t)
-    out = dit.dit_forward(params, x, tb, cfg)
-    return out.velocity, out.crf
-
-
-def from_crf_fn(crf, t):
-    tb = jnp.full((crf.shape[0],), t)
-    return dit.dit_from_crf(params, crf, tb, cfg, 32, 32)
-
+full_fn, from_crf_fn = dit.denoiser(cfg)
 
 ref = synthetic.shapes_batch(jax.random.key(3), 2, size=32,
                              channels=cfg.in_channels)
@@ -37,9 +27,9 @@ x0 = schedule.add_noise(ref, noise, tau)
 ts = schedule.timesteps(50) * tau           # resume from t = tau
 crf_shape = (2, (32 // cfg.patch_size) ** 2, cfg.d_model)
 
-full = sampler.sample(full_fn, from_crf_fn, x0, ts,
+full = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
                       policies.NoCachePolicy(), crf_shape=crf_shape)
-fast = sampler.sample(full_fn, from_crf_fn, x0, ts,
+fast = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
                       policies.FreqCaPolicy(interval=5, method="fft"),
                       crf_shape=crf_shape)
 err = float(jnp.linalg.norm(fast.x - full.x) / jnp.linalg.norm(full.x))

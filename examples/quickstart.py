@@ -23,26 +23,16 @@ print("registered cache policies:", ", ".join(policies.available()))
 cfg = config_lib.get_config("dit-small")
 params = train_dit(cfg, steps=120, batch=16, ckpt_dir="", size=32)
 
-
-def full_fn(x, t):
-    tb = jnp.full((x.shape[0],), t)
-    out = dit.dit_forward(params, x, tb, cfg)
-    return out.velocity, out.crf
-
-
-def from_crf_fn(crf, t):
-    tb = jnp.full((crf.shape[0],), t)
-    return dit.dit_from_crf(params, crf, tb, cfg, 32, 32)
-
+full_fn, from_crf_fn = dit.denoiser(cfg)
 
 x0 = jax.random.normal(jax.random.key(0), (4, 32, 32, cfg.in_channels))
 ts = schedule.timesteps(50)
 crf_shape = (4, (32 // cfg.patch_size) ** 2, cfg.d_model)
 
-full = sampler.sample(full_fn, from_crf_fn, x0, ts,
+full = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
                       policies.NoCachePolicy(), crf_shape=crf_shape)
 pol = policies.FreqCaPolicy(interval=5, method="dct", rho=0.0625)
-freqca = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+freqca = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                         crf_shape=crf_shape)
 err = float(jnp.linalg.norm(freqca.x - full.x) / jnp.linalg.norm(full.x))
 print(f"uncached: {int(full.n_full)} full steps; "

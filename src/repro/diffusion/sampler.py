@@ -18,9 +18,12 @@ Each step the bank's ``decide`` returns a per-lane activation mask:
   a mixed generation+editing batch never shares one global activation
   decision.  A lane behaves exactly as it would alone in the batch.
 
-The denoiser is abstract: ``full_fn(x, t) -> (velocity, crf)`` and
-``from_crf_fn(crf, t) -> velocity``; both DiT and backbone-wrapped
-assigned architectures plug in (repro.models.dit).
+The denoiser is abstract: ``full_fn(params, x, t) -> (velocity, crf)``
+and ``from_crf_fn(params, crf, t) -> velocity``; both DiT and
+backbone-wrapped assigned architectures plug in (repro.models.dit).
+The weights travel as the ``params`` argument, never as closure
+constants, so a jitted sampler takes them as inputs and its executable
+embeds none of them.
 """
 from __future__ import annotations
 
@@ -45,8 +48,8 @@ class SampleResult(NamedTuple):
     feedback: Optional[policy_base.ErrorFeedback] = None
 
 
-def sample(full_fn: Callable, from_crf_fn: Callable, x_init: jnp.ndarray,
-           ts: jnp.ndarray, policy: PolicyArg,
+def sample(full_fn: Callable, from_crf_fn: Callable, params,
+           x_init: jnp.ndarray, ts: jnp.ndarray, policy: PolicyArg,
            crf_shape: Tuple[int, ...], crf_dtype=jnp.float32,
            return_trajectory: bool = False) -> SampleResult:
     """Euler rectified-flow sampling from t=1 to t=0 under a cache policy.
@@ -74,7 +77,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, x_init: jnp.ndarray,
 
         def full_branch(op):
             x_, state_ = op
-            v_full, crf = full_fn(x_, t_now)
+            v_full, crf = full_fn(params, x_, t_now)
             if bank.uses_error_feedback:
                 # score the prediction the cache WOULD have served for
                 # this step (pre-update state) against the fresh CRF,
@@ -87,17 +90,19 @@ def sample(full_fn: Callable, from_crf_fn: Callable, x_init: jnp.ndarray,
             else:
                 state_ = bank.apply_update(state_, crf, ctx, mask)
             if bank.scalar_decision:
-                return v_full, state_
+                return v_full.astype(x_.dtype), state_
             # lanes that did not activate keep their own schedule: they
             # consume the cached prediction even though the batch paid
             # for a forward (quality decoupling across lanes)
-            v_hat = from_crf_fn(bank.predict(state_, ctx), t_now)
+            v_hat = from_crf_fn(params, bank.predict(state_, ctx), t_now)
             m = mask.reshape((batch,) + (1,) * (v_full.ndim - 1))
-            return jnp.where(m, v_full, v_hat.astype(v_full.dtype)), state_
+            v = jnp.where(m, v_full, v_hat.astype(v_full.dtype))
+            return v.astype(x_.dtype), state_
 
         def cached_branch(op):
             x_, state_ = op
-            return from_crf_fn(bank.predict(state_, ctx), t_now), state_
+            v = from_crf_fn(params, bank.predict(state_, ctx), t_now)
+            return v.astype(x_.dtype), state_
 
         if bank.always_full:
             act = jnp.asarray(True)
@@ -107,7 +112,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, x_init: jnp.ndarray,
             v, state = jax.lax.cond(act, full_branch, cached_branch,
                                     (x, state))
         dt = (t_next - t_now).astype(x.dtype)
-        x_new = x + dt * v.astype(x.dtype)
+        x_new = x + dt * v
         out = (x_new if return_trajectory else (),
                jnp.asarray(act, jnp.int32), mask.astype(jnp.int32))
         return (x_new, state), out
@@ -123,7 +128,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, x_init: jnp.ndarray,
                         feedback=feedback)
 
 
-def reference_features(full_fn: Callable, x_init: jnp.ndarray,
+def reference_features(full_fn: Callable, params, x_init: jnp.ndarray,
                        ts: jnp.ndarray):
     """Run the un-cached sampler, returning per-step (x, crf) trajectories.
 
@@ -131,7 +136,7 @@ def reference_features(full_fn: Callable, x_init: jnp.ndarray,
     """
     def step(x, tt):
         t_now, t_next = tt
-        v, crf = full_fn(x, t_now)
+        v, crf = full_fn(params, x, t_now)
         x_next = x + (t_next - t_now).astype(x.dtype) * v.astype(x.dtype)
         return x_next, (x_next, crf)
 

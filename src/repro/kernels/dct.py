@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 from repro.core import frequency
@@ -100,7 +101,7 @@ def band_split_dispatch_ok(s: int, d: int, block: int = 128) -> bool:
 
 def spectral_dispatch_ok(s: int, d: int, block: int = 256) -> bool:
     """Shapes the spectral kernels' default tiling accepts
-    (``band_split_spectral`` block_d and
+    (``band_split_spectral`` and
     ``freqca_fused.freqca_predict_fused_spectral`` block_s/block_d are
     all 256)."""
     return d % min(block, d) == 0 and s % min(block, s) == 0
@@ -116,61 +117,82 @@ def band_split(x: jnp.ndarray, rho: float, method: str = "dct",
 
 
 # ---------------------------------------------------------------------------
-# spectral band split: (low coefficients, spatial high) in one pass
+# spectral band split: (low coefficients, spatial high), token axis tiled
 # ---------------------------------------------------------------------------
 
-def _band_split_spectral_kernel(basis_ref, x_ref, low_ref, high_ref):
-    """basis [m, S]; x [S, bd] -> low = B·x [m, bd], high = x − Bᵀ·low.
+def _spectral_analysis_kernel(basis_ref, x_ref, low_ref, acc_ref):
+    """basis [m, bs]; x [bs, bd] -> low [m, bd] = Σ over S tiles of B·x.
 
-    Both outputs come out of ONE read of the x tile: the analysis
-    matmul produces the compressed low-band coefficients directly (no
-    S×S projection matmul, no spatial low band ever materialised) and
-    the synthesis-transpose matmul immediately yields the high
-    residual."""
-    x = x_ref[...].astype(jnp.float32)
-    b = basis_ref[...].astype(jnp.float32)
-    low = jnp.dot(b, x, preferred_element_type=jnp.float32)
-    low_ref[...] = low.astype(low_ref.dtype)
-    recon = jnp.dot(b.T, low, preferred_element_type=jnp.float32)
-    high_ref[...] = (x - recon).astype(high_ref.dtype)
+    The S tiles are the innermost grid axis: the f32 accumulator stays
+    in VMEM scratch and the output tile is written on the last one."""
+    si = pl.program_id(2)
+
+    @pl.when(si == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += jnp.dot(basis_ref[...].astype(jnp.float32),
+                            x_ref[...].astype(jnp.float32),
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(si == pl.num_programs(2) - 1)
+    def _store():
+        low_ref[...] = acc_ref[...]
+
+
+def _spectral_residual_kernel(synth_ref, low_ref, x_ref, high_ref):
+    """synth [bs, m]; low [m, bd]; x [bs, bd] -> high = x − Bᵀ·low."""
+    recon = jnp.dot(synth_ref[...].astype(jnp.float32), low_ref[...],
+                    preferred_element_type=jnp.float32)
+    high_ref[...] = (x_ref[...].astype(jnp.float32)
+                     - recon).astype(high_ref.dtype)
 
 
 def band_split_spectral(x: jnp.ndarray, rho: float, method: str = "dct",
-                        block_d: int = 256, interpret: bool = True):
+                        block_s: int = 256, block_d: int = 256,
+                        interpret: bool = True):
     """Fused spectral band split: ``(low_spec [B, m, D], high [B, S, D])``.
 
     ``m = frequency.spectral_kept_bins(S, rho, method)`` — the low band
     lives in the frequency domain at a ``rho`` fraction of the spatial
-    footprint (the SpectralCache representation).  The token axis is
-    VMEM-resident per tile (S·block_d floats), so the grid runs over D
-    tiles only; ``low + high`` reconstruction means
+    footprint (the SpectralCache representation).  Two passes, both
+    tiled over the token axis so VMEM holds ``block_s`` tokens at a
+    time whatever S is (a whole-token-axis tile ran out of v5e VMEM at
+    S=4096, D=3072): the analysis pass accumulates ``B·x`` over S tiles
+    into an f32 ``[m, block_d]`` tile, and the residual pass reads x
+    again and writes ``high = x − Bᵀ·low`` from that f32 low band, so
     ``Bᵀ·low_spec + high == x`` to float round-off.
     """
-    _, s, d = x.shape
+    b, s, d = x.shape
     basis = frequency.low_band_basis(s, rho, method)
+    synth = basis.T
     m = basis.shape[0]
+    bs = min(block_s, s)
     bd = min(block_d, d)
-    assert d % bd == 0, (d, bd)
-    grid = (d // bd,)
-
-    def run_one(x2):  # [S, D]
-        return pl.pallas_call(
-            _band_split_spectral_kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((m, s), lambda j: (0, 0)),
-                pl.BlockSpec((s, bd), lambda j: (0, j)),
-            ],
-            out_specs=[
-                pl.BlockSpec((m, bd), lambda j: (0, j)),
-                pl.BlockSpec((s, bd), lambda j: (0, j)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((m, d), x.dtype),
-                jax.ShapeDtypeStruct((s, d), x.dtype),
-            ],
-            interpret=interpret,
-        )(basis, x2)
-
-    low, high = jax.vmap(run_one)(x)
-    return low, high
+    assert s % bs == 0 and d % bd == 0, (s, d, bs, bd)
+    squeezed = pl.Squeezed()
+    low = pl.pallas_call(
+        _spectral_analysis_kernel,
+        grid=(b, d // bd, s // bs),
+        in_specs=[
+            pl.BlockSpec((m, bs), lambda n, j, i: (0, i)),
+            pl.BlockSpec((squeezed, bs, bd), lambda n, j, i: (n, i, j)),
+        ],
+        out_specs=pl.BlockSpec((squeezed, m, bd), lambda n, j, i: (n, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, m, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((m, bd), jnp.float32)],
+        interpret=interpret,
+    )(basis, x)
+    high = pl.pallas_call(
+        _spectral_residual_kernel,
+        grid=(b, s // bs, d // bd),
+        in_specs=[
+            pl.BlockSpec((bs, m), lambda n, i, j: (i, 0)),
+            pl.BlockSpec((squeezed, m, bd), lambda n, i, j: (n, 0, j)),
+            pl.BlockSpec((squeezed, bs, bd), lambda n, i, j: (n, i, j)),
+        ],
+        out_specs=pl.BlockSpec((squeezed, bs, bd), lambda n, i, j: (n, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, s, d), x.dtype),
+        interpret=interpret,
+    )(synth, low, x)
+    return low.astype(x.dtype), high

@@ -16,6 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hermite
 
@@ -76,18 +77,21 @@ def freqca_predict_fused(low: jnp.ndarray, high_hist: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def _fused_spectral_kernel(w_ref, synth_ref, low_ref, hist_ref, o_ref):
-    """synth [bs, m]; low [m, bd]; hist [K, bs, bd]; w [K].
+    """w [B·K] (SMEM); synth [bs, m]; low [m, bd]; hist [K, bs, bd].
 
-    ẑ tile = synth·low + Σ_k w_k hist_k — the low band is synthesised
-    from its m spectral rows on the MXU inside the same pass that FMAs
-    the K high-band history tiles, so the cached step reads only
-    K·S·D + m·D + S·m floats from HBM and writes S·D once."""
+    ẑ tile = synth·low + Σ_k w[b, k]·hist_k — the low band is
+    synthesised from its m spectral rows on the MXU inside the same pass
+    that FMAs the K high-band history tiles, so the cached step reads
+    only K·S·D + m·D + S·m floats from HBM and writes S·D once.  The
+    lane's K weights are scalars read from SMEM (scalar prefetch): a
+    ``(K,)`` VMEM block per lane is not a tile Mosaic accepts."""
+    k = hist_ref.shape[0]
+    lane = pl.program_id(0)
     acc = jnp.dot(synth_ref[...].astype(jnp.float32),
                   low_ref[...].astype(jnp.float32),
                   preferred_element_type=jnp.float32)
-    k = hist_ref.shape[0]
     for i in range(k):                      # K is tiny & static: unrolled FMA
-        acc += w_ref[i] * hist_ref[i].astype(jnp.float32)
+        acc += w_ref[lane * k + i] * hist_ref[i].astype(jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -102,27 +106,29 @@ def freqca_predict_fused_spectral(low_spec: jnp.ndarray, synth: jnp.ndarray,
     synth: [S, m] synthesis basis (``frequency.low_band_basis(S).T``);
     high_hist: [B, K, S, D]; w: [B, K] per-lane folded Hermite weights
     (lanes activate at different times, so each carries its own fold).
+    The grid runs over (lane, S tiles, D tiles).
     """
     b, kh, s, d = high_hist.shape
     bs = min(block_s, s)
     bd = min(block_d, d)
     assert s % bs == 0 and d % bd == 0, (s, d, bs, bd)
     m = synth.shape[1]
-    grid = (s // bs, d // bd)
-
-    def run_one(w1, low1, hist1):  # [K], [m, D], [K, S, D]
-        return pl.pallas_call(
-            _fused_spectral_kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((kh,), lambda i, j: (0,)),
-                pl.BlockSpec((bs, m), lambda i, j: (i, 0)),
-                pl.BlockSpec((m, bd), lambda i, j: (0, j)),
-                pl.BlockSpec((kh, bs, bd), lambda i, j: (0, i, j)),
-            ],
-            out_specs=pl.BlockSpec((bs, bd), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((s, d), high_hist.dtype),
-            interpret=interpret,
-        )(w1, synth, low1, hist1)
-
-    return jax.vmap(run_one)(w.astype(jnp.float32), low_spec, high_hist)
+    squeezed = pl.Squeezed()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, s // bs, d // bd),
+        in_specs=[
+            pl.BlockSpec((bs, m), lambda n, i, j, w_ref: (i, 0)),
+            pl.BlockSpec((squeezed, m, bd), lambda n, i, j, w_ref: (n, 0, j)),
+            pl.BlockSpec((squeezed, kh, bs, bd),
+                         lambda n, i, j, w_ref: (n, 0, i, j)),
+        ],
+        out_specs=pl.BlockSpec((squeezed, bs, bd),
+                               lambda n, i, j, w_ref: (n, i, j)),
+    )
+    return pl.pallas_call(
+        _fused_spectral_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, s, d), high_hist.dtype),
+        interpret=interpret,
+    )(w.astype(jnp.float32).reshape(-1), synth, low_spec, high_hist)

@@ -36,15 +36,25 @@ from repro.kernels import ssd_scan as ssd_kernel
 # backend selection (lazy — never frozen at import time)
 # ---------------------------------------------------------------------------
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def backend() -> str:
-    """'pallas' | 'xla' — from ``REPRO_KERNELS``, else by jax backend."""
+    """'pallas' | 'xla' — from ``REPRO_KERNELS``, else by jax backend.
+
+    On a TPU the kernels are the served path: ``REPRO_KERNELS=xla``
+    there is refused rather than silently taking them out."""
     env = os.environ.get("REPRO_KERNELS", "").strip().lower()
+    if env == "xla" and _on_tpu():
+        raise ValueError("REPRO_KERNELS=xla would take the Pallas kernels "
+                         "out of the served path on a TPU; unset it")
     if env in ("pallas", "xla"):
         return env
     if env:
         raise ValueError(
             f"REPRO_KERNELS must be 'pallas' or 'xla', got {env!r}")
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return "pallas" if _on_tpu() else "xla"
 
 
 def use_pallas() -> bool:
@@ -53,16 +63,20 @@ def use_pallas() -> bool:
 
 def interpret() -> bool:
     """Pallas interpret mode: forced via ``REPRO_KERNELS_INTERPRET``,
-    else on everywhere except a real TPU backend."""
+    else on everywhere except a real TPU backend, where it is never on
+    (forcing it there is refused)."""
     env = os.environ.get("REPRO_KERNELS_INTERPRET", "").strip().lower()
     if env in ("1", "true"):
+        if _on_tpu():
+            raise ValueError("REPRO_KERNELS_INTERPRET=1 on a TPU would "
+                             "interpret the kernels; unset it")
         return True
     if env in ("0", "false"):
         return False
     if env:
         raise ValueError("REPRO_KERNELS_INTERPRET must be 0/false or "
                          f"1/true, got {env!r}")
-    return jax.default_backend() != "tpu"
+    return not _on_tpu()
 
 
 def __getattr__(name: str):

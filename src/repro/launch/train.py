@@ -66,6 +66,45 @@ def train_dit(cfg: DiTConfig, steps: int, batch: int, ckpt_dir: str,
     return params
 
 
+def restore_dit(cfg: DiTConfig, ckpt_dir: str):
+    """The latest ``dit`` checkpoint in ``ckpt_dir``; None if it has none."""
+    step = checkpoint.latest_step(ckpt_dir, "dit")
+    if step < 0:
+        return None
+    like = common.abstract_params(dit.dit_specs(cfg), jnp.dtype(cfg.dtype))
+    return checkpoint.restore(ckpt_dir, step, like, name="dit")
+
+
+def _train_dit_child(conn, cfg, steps, batch, ckpt_dir, size):
+    train_dit(cfg, steps, batch, ckpt_dir=ckpt_dir, size=size)
+    conn.send(jax.default_backend())
+    conn.close()
+
+
+def train_dit_in_child(cfg: DiTConfig, steps: int, batch: int,
+                       ckpt_dir: str, size: int = 32) -> str:
+    """Train in a spawned child process that writes the checkpoint to
+    ``ckpt_dir``, so the caller never touches a JAX device (the parent
+    of a fleet must not hold the chip its workers need).  Returns the
+    JAX platform the child trained on."""
+    import multiprocessing as mp
+    ctx = mp.get_context("spawn")
+    parent_conn, child_conn = ctx.Pipe()
+    proc = ctx.Process(target=_train_dit_child,
+                       args=(child_conn, cfg, steps, batch, ckpt_dir, size),
+                       name="train-dit")
+    proc.start()
+    child_conn.close()
+    try:
+        platform = parent_conn.recv()
+    except EOFError:
+        platform = None
+    proc.join()
+    if platform is None or proc.exitcode != 0:
+        raise RuntimeError(f"training child exited with {proc.exitcode}")
+    return platform
+
+
 def train_lm(cfg: ModelConfig, steps: int, batch: int, seq: int,
              ckpt_dir: str, seed: int = 0, log_every: int = 5):
     if cfg.is_encdec:

@@ -16,6 +16,7 @@ Two denoisers:
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any, Dict, NamedTuple, Optional
 
@@ -263,6 +264,49 @@ def dit_from_crf(params, crf: jnp.ndarray, t: jnp.ndarray, cfg: DiTConfig,
     """FreqCa skip path: predicted CRF -> velocity (final layer only)."""
     cond = _time_cond(params, t, cfg, crf.dtype)
     return _final_layer(params, crf, cond, cfg, h, w)
+
+
+def denoiser(cfg: DiTConfig):
+    """The sampler's denoiser pair for ``cfg``: ``full_fn(params, x, t)
+    -> (velocity, crf)`` and ``from_crf_fn(params, crf, t) -> velocity``.
+
+    Weights arrive as the ``params`` argument (never closed over), so a
+    jitted sampler takes them as inputs.  ``from_crf_fn`` is
+    shape-generic: the square image side is recovered from the CRF's
+    token count, so one pair serves a whole shape ladder."""
+    def full_fn(params, x, t):
+        tb = jnp.full((x.shape[0],), t)
+        out = dit_forward(params, x, tb, cfg)
+        return out.velocity, out.crf
+
+    def from_crf_fn(params, crf, t):
+        tb = jnp.full((crf.shape[0],), t)
+        side = math.isqrt(crf.shape[1]) * cfg.patch_size
+        return dit_from_crf(params, crf, tb, cfg, side, side)
+
+    return full_fn, from_crf_fn
+
+
+def random_params(cfg: DiTConfig, seed: int, device=None):
+    """Seeded random weights in ``cfg.dtype``, every leaf drawn.
+
+    The AdaLN-zero modulations and the zero-initialised output
+    projection of ``dit_specs`` make an untrained model's blocks the
+    identity and its velocity zero; drawing them too gives a model whose
+    forward does real work (checks and smoke runs without trained
+    weights).  One jitted program, so no f32 copy of the weights is
+    ever held; with ``device`` it runs there and the weights are made
+    in place."""
+    specs = jax.tree.map(
+        lambda s: dataclasses.replace(s, init="normal")
+        if s.init == "zeros" else s,
+        dit_specs(cfg), is_leaf=lambda x: isinstance(x, ParamSpec))
+    out = (None if device is None
+           else jax.sharding.SingleDeviceSharding(device))
+    init = jax.jit(lambda k: common.init_params(specs, k,
+                                                jnp.dtype(cfg.dtype)),
+                   out_shardings=out)
+    return init(jax.random.key(seed))
 
 
 # ---------------------------------------------------------------------------

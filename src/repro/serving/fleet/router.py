@@ -77,7 +77,7 @@ from typing import Dict, List, Optional
 
 from repro.analysis.runtime import make_condition, make_lock
 from repro.serving.fleet.fleet_metrics import FleetMetrics
-from repro.serving.fleet.worker import Replica
+from repro.serving.fleet.worker import DeviceReplica, Replica
 from repro.serving.scheduler import (DiffusionRequest, ShapeMismatchError,
                                      resolve_shape_key,
                                      validate_request_shape)
@@ -115,6 +115,11 @@ class FleetRouter:
     engines' default and is only used to compute affinity keys for
     requests with ``policy=None``.
 
+    ``per_device=True`` runs replica ``i`` on a thread of this process
+    with its engine on ``jax.devices()[i]`` (``factory`` then takes a
+    ``device`` index): the placement for a TPU host, where one process
+    holds the chips.  The default spawns one process per replica.
+
     Robustness knobs (all off by default, matching the PR-7 fleet):
     ``max_restarts`` enables the supervisor; ``max_inflight`` bounds
     per-replica queues (0 = unbounded); ``retry_budget`` is the number
@@ -136,7 +141,7 @@ class FleetRouter:
                  shed_factor: Optional[float] = None,
                  restart_backoff_base_s: float = 0.5,
                  restart_backoff_cap_s: float = 30.0,
-                 fault_injector=None):
+                 fault_injector=None, per_device: bool = False):
         if n_replicas < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
         if retry_budget < 1:
@@ -159,6 +164,7 @@ class FleetRouter:
         self.restart_backoff_base_s = restart_backoff_base_s
         self.restart_backoff_cap_s = restart_backoff_cap_s
         self.fault_injector = fault_injector
+        self.per_device = per_device
 
         self.replicas: List[Replica] = []
         self.supervisor = None
@@ -197,6 +203,9 @@ class FleetRouter:
         ctx = mp.get_context("spawn")
         start_n = self._starts.get(idx, 0)
         self._starts[idx] = start_n + 1
+        if self.per_device:
+            return DeviceReplica(idx, self.factory, warm=self.warm,
+                                 start_n=start_n)
         fault = (self.fault_injector.spec_for(idx, start_n)
                  if self.fault_injector is not None else None)
         return Replica(idx, self.factory, warm=self.warm,

@@ -112,7 +112,7 @@ class ServeMetrics:
     # actual per-lane cache-state footprint of the engine's policy
     # (spectral low ring included) — set once at warmup
     cache_state_bytes_per_lane: Optional[int] = None
-    # latest jit-cache probe (None until pushed; -1 = probe unavailable)
+    # latest jit-cache probe (None until pushed)
     compiled_signatures: Optional[int] = None
     # per compatibility group:
     # [n_batches, n_requests, occupancy_sum, budget_events, errors]

@@ -24,7 +24,8 @@ from repro.launch import steps as steps_lib
 from repro.roofline import hlo_analysis
 
 arch, shape = sys.argv[1], sys.argv[2]
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh:
     spec = steps_lib.build(arch, shape, mesh)
     compiled = jax.jit(spec.fn, in_shardings=spec.in_shardings,
@@ -85,18 +86,12 @@ SIZE = 8
 assert jax.device_count() == 8
 cfg = config_lib.reduced(config_lib.get_config("dit-small"))
 params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
+full_fn, from_crf_fn = dit.denoiser(cfg)
 
-def full_fn(x, t):
-    tb = jnp.full((x.shape[0],), t)
-    out = dit.dit_forward(params, x, tb, cfg)
-    return out.velocity, out.crf
-
-def from_crf_fn(crf, t):
-    tb = jnp.full((crf.shape[0],), t)
-    return dit.dit_from_crf(params, crf, tb, cfg, SIZE, SIZE)
-
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-eng = DiffusionEngine(full_fn, from_crf_fn, (SIZE, SIZE, cfg.in_channels),
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+eng = DiffusionEngine(full_fn, from_crf_fn, params,
+                      (SIZE, SIZE, cfg.in_channels),
                       (16, cfg.d_model),
                       CachePolicy(kind="freqca", interval=3),
                       n_steps=6, max_batch=4, mesh=mesh)

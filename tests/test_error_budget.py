@@ -33,22 +33,14 @@ def dit_fns():
     from repro.models import common, dit
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
+    full_fn, from_crf_fn = dit.denoiser(cfg)
 
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        return dit.dit_from_crf(params, crf, tb, cfg, SIZE, SIZE)
-
-    return cfg, full_fn, from_crf_fn
+    return cfg, full_fn, from_crf_fn, params
 
 
 def make_engine(dit_fns, policy, max_batch=4, **kw):
-    cfg, full_fn, from_crf_fn = dit_fns
-    return DiffusionEngine(full_fn, from_crf_fn,
+    cfg, full_fn, from_crf_fn, params = dit_fns
+    return DiffusionEngine(full_fn, from_crf_fn, params,
                            (SIZE, SIZE, cfg.in_channels),
                            (16, cfg.d_model), policy,
                            n_steps=N_STEPS, max_batch=max_batch, **kw)
@@ -199,12 +191,12 @@ def test_state_bytes_count_feedback_scalars():
 def _rough_fns(s=4, d=8, size=4, ch=2, amp=0.3, freq=8.0):
     """CRF oscillates fast in t, so Hermite forecasts err at a rate the
     budget can meter.  s*d must equal size*size*ch."""
-    def full_fn(x, t):
+    def full_fn(params, x, t):
         crf = jnp.tanh(x.reshape(x.shape[0], s, d))
         crf = crf + amp * jnp.sin(freq * t)
         return crf.reshape(x.shape) * 0.1, crf
 
-    def from_crf_fn(crf, t):
+    def from_crf_fn(params, crf, t):
         return crf.reshape(crf.shape[0], size, size, ch) * 0.1
 
     return full_fn, from_crf_fn
@@ -214,7 +206,7 @@ def _run_eb(budget, n_steps=40):
     full_fn, from_crf_fn = _rough_fns()
     x0 = jax.random.normal(jax.random.key(3), (2, 4, 4, 2))
     pol = FreqCaErrorBudgetPolicy(method="dct", rho=0.25).with_budget(budget)
-    return sampler.sample(full_fn, from_crf_fn, x0,
+    return sampler.sample(full_fn, from_crf_fn, None, x0,
                           schedule.timesteps(n_steps), pol,
                           crf_shape=(2, 4, 8))
 
@@ -242,7 +234,7 @@ def test_non_feedback_policies_report_no_feedback():
                 policies.ForaPolicy(interval=2),
                 policies.FreqCaAdaptivePolicy(method="dct", rho=0.25,
                                               tea_threshold=0.3)):
-        res = sampler.sample(full_fn, from_crf_fn, x0,
+        res = sampler.sample(full_fn, from_crf_fn, None, x0,
                              schedule.timesteps(12), pol,
                              crf_shape=(2, 4, 8))
         assert res.feedback is None, pol

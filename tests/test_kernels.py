@@ -185,6 +185,23 @@ def test_ops_backend_read_lazily(monkeypatch):
 
 
 @pytest.mark.pallas
+def test_ops_refuse_to_leave_the_kernels_on_a_tpu(monkeypatch):
+    """On a TPU the kernels are the served path: forcing the XLA
+    fallback or interpret mode there raises instead of passing
+    silently."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.delenv("REPRO_KERNELS", raising=False)
+    monkeypatch.delenv("REPRO_KERNELS_INTERPRET", raising=False)
+    assert ops.backend() == "pallas" and not ops.interpret()
+    monkeypatch.setenv("REPRO_KERNELS", "xla")
+    with pytest.raises(ValueError, match="served path"):
+        ops.backend()
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "1")
+    with pytest.raises(ValueError, match="interpret"):
+        ops.interpret()
+
+
 def test_ops_band_split_spectral_backends_agree(monkeypatch):
     """The same call routed through both backends returns the same
     split (the pallas jits carry interpret/backend as static args, so

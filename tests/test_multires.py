@@ -35,20 +35,10 @@ def multi_fns():
     from repro.models import common, dit
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
-
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        # shape-generic: image side recovered from the token count, so
-        # ONE callable serves every rung of the ladder
-        tb = jnp.full((crf.shape[0],), t)
-        side = int(round(crf.shape[1] ** 0.5)) * cfg.patch_size
-        return dit.dit_from_crf(params, crf, tb, cfg, side, side)
-
-    return cfg, full_fn, from_crf_fn
+    # shape-generic: from_crf_fn recovers the image side from the token
+    # count, so ONE callable serves every rung of the ladder
+    full_fn, from_crf_fn = dit.denoiser(cfg)
+    return cfg, full_fn, from_crf_fn, params
 
 
 def shape_pair(cfg, size):
@@ -57,9 +47,10 @@ def shape_pair(cfg, size):
 
 
 def make_multi_engine(multi_fns, max_batch=2, **kw):
-    cfg, full_fn, from_crf_fn = multi_fns
+    cfg, full_fn, from_crf_fn, params = multi_fns
     pairs = [shape_pair(cfg, s) for s in SIZES]
-    return DiffusionEngine(full_fn, from_crf_fn, pairs[0][0], pairs[0][1],
+    return DiffusionEngine(full_fn, from_crf_fn, params, pairs[0][0],
+                           pairs[0][1],
                            CachePolicy(kind="freqca", interval=3),
                            n_steps=N_STEPS, max_batch=max_batch,
                            shapes=pairs[1:], **kw)
@@ -355,7 +346,7 @@ def test_scheduler_with_ladder_rejects():
 
 def test_async_submit_bad_shape_raises_no_orphan_future(multi_fns):
     from repro.serving.async_engine import AsyncDiffusionEngine
-    cfg, full_fn, from_crf_fn = multi_fns
+    cfg = multi_fns[0]
     pairs = [shape_pair(cfg, s) for s in SIZES]
     eng = AsyncDiffusionEngine(make_multi_engine(multi_fns))
     eng.start()

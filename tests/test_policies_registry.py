@@ -20,18 +20,10 @@ from repro.models import common, dit
 def tiny_dit():
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
-
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        return dit.dit_from_crf(params, crf, tb, cfg, 8, 8)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
 
     x0 = jax.random.normal(jax.random.key(1), (2, 8, 8, cfg.in_channels))
-    return cfg, full_fn, from_crf_fn, x0
+    return cfg, full_fn, from_crf_fn, params, x0
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +103,8 @@ def test_compatibility_keys():
 # golden equivalence vs the legacy string-`kind` sampler
 # ---------------------------------------------------------------------------
 
-def _legacy_sample(full_fn, from_crf_fn, x_init, ts, policy, crf_shape,
+def _legacy_sample(full_fn, from_crf_fn, params, x_init, ts, policy,
+                   crf_shape,
                    crf_dtype=jnp.float32):
     """Verbatim port of the seed sampler (string-`kind` dispatch +
     sampler-resident tea0 carries) — the golden reference."""
@@ -127,7 +120,7 @@ def _legacy_sample(full_fn, from_crf_fn, x_init, ts, policy, crf_shape,
 
         def full_branch(op):
             x_, state_ = op
-            v, crf = full_fn(x_, t_now)
+            v, crf = full_fn(params, x_, t_now)
             if policy.kind == "freqca_a":
                 pred = cache_lib.predict(policy, state_, t_now)
                 err = jnp.linalg.norm(
@@ -140,7 +133,7 @@ def _legacy_sample(full_fn, from_crf_fn, x_init, ts, policy, crf_shape,
         def cached_branch(op):
             x_, state_ = op
             crf_hat = cache_lib.predict(policy, state_, t_now)
-            return (from_crf_fn(crf_hat, t_now), state_, 0,
+            return (from_crf_fn(params, crf_hat, t_now), state_, 0,
                     jnp.zeros((), jnp.float32))
 
         if policy.kind == "teacache":
@@ -202,12 +195,12 @@ def test_golden_equivalence_scheduled(tiny_dit, pol):
     """Registered policy objects match the legacy spatial-cache path on
     the seed configs (scheduled policies, batch > 1) — bitwise except
     for the spectral freqca low band (see _assert_golden)."""
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
     crf_shape = (2, 16, cfg.d_model)
-    want_x, want_full = _legacy_sample(full_fn, from_crf_fn, x0, ts, pol,
-                                       crf_shape)
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+    want_x, want_full = _legacy_sample(full_fn, from_crf_fn, params, x0,
+                                       ts, pol, crf_shape)
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=crf_shape)
     _assert_golden(pol, res.x, want_x)
     assert int(res.n_full) == int(want_full)
@@ -223,13 +216,13 @@ def test_golden_equivalence_adaptive_solo(tiny_dit, pol):
     """Adaptive policies match the legacy path at batch 1, where the
     legacy batch-global decision IS the lane decision.  (At batch > 1
     the new path is per-lane by design — covered below.)"""
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
     x0 = x0[:1]
     crf_shape = (1, 16, cfg.d_model)
-    want_x, want_full = _legacy_sample(full_fn, from_crf_fn, x0, ts, pol,
-                                       crf_shape)
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+    want_x, want_full = _legacy_sample(full_fn, from_crf_fn, params, x0,
+                                       ts, pol, crf_shape)
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=crf_shape)
     _assert_golden(pol, res.x, want_x)
     assert int(res.n_full_lanes[0]) == int(want_full)
@@ -243,18 +236,18 @@ def test_mixed_batch_lane_matches_solo(tiny_dit):
     """A lane keeps its solo-batch behaviour inside a mixed-policy
     batch: the `none` lane matches its solo uncached run, the cached
     lane matches its solo cached run, and per-lane n_full decouple."""
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(16)
     mix = (CachePolicy(kind="none"),
            CachePolicy(kind="freqca", interval=4, rho=0.25))
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts, mix,
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, mix,
                          crf_shape=(2, 16, cfg.d_model))
     assert int(res.n_full_lanes[0]) == 16
     assert int(res.n_full_lanes[1]) < 16
     assert int(res.n_full) == 16        # forwards = union of activations
     for j, pol in enumerate(mix):
-        solo = sampler.sample(full_fn, from_crf_fn, x0[j:j + 1], ts, pol,
-                              crf_shape=(1, 16, cfg.d_model))
+        solo = sampler.sample(full_fn, from_crf_fn, params, x0[j:j + 1],
+                              ts, pol, crf_shape=(1, 16, cfg.d_model))
         assert int(solo.n_full_lanes[0]) == int(res.n_full_lanes[j])
         np.testing.assert_allclose(np.asarray(res.x[j]),
                                    np.asarray(solo.x[0]), atol=1e-5)
@@ -264,14 +257,14 @@ def test_uniform_adaptive_batch_is_per_lane(tiny_dit):
     """A single adaptive policy over a batch now decides per lane: each
     lane matches its solo run even when the other lane's content would
     have flipped the old batch-global decision."""
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
     pol = CachePolicy(kind="freqca_a", tea_threshold=0.3, rho=0.25)
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=(2, 16, cfg.d_model))
     for j in range(2):
-        solo = sampler.sample(full_fn, from_crf_fn, x0[j:j + 1], ts, pol,
-                              crf_shape=(1, 16, cfg.d_model))
+        solo = sampler.sample(full_fn, from_crf_fn, params, x0[j:j + 1],
+                              ts, pol, crf_shape=(1, 16, cfg.d_model))
         assert int(solo.n_full_lanes[0]) == int(res.n_full_lanes[j])
         np.testing.assert_allclose(np.asarray(res.x[j]),
                                    np.asarray(solo.x[0]), atol=1e-5)
@@ -285,12 +278,12 @@ def test_freqca_a_warmup_follows_high_order(tiny_dit):
     """With an unbounded error budget freqca_a activates exactly its
     warm-up steps — which must track `high_order`, not the old
     hard-coded 3, so a bigger ring is never sampled underfilled."""
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
     for high_order, want in [(2, 3), (4, 5)]:
         pol = CachePolicy(kind="freqca_a", tea_threshold=1e9,
                           high_order=high_order, rho=0.25)
-        res = sampler.sample(full_fn, from_crf_fn, x0[:1], ts, pol,
+        res = sampler.sample(full_fn, from_crf_fn, params, x0[:1], ts, pol,
                              crf_shape=(1, 16, cfg.d_model))
         assert int(res.n_full_lanes[0]) == want, (high_order, want)
 
@@ -325,9 +318,9 @@ def test_foca_calibrated_forecast():
 
 
 def test_foca_samples_end_to_end(tiny_dit):
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts,
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
                          CachePolicy(kind="foca", interval=5),
                          crf_shape=(2, 16, cfg.d_model))
     assert bool(jnp.isfinite(res.x).all())
@@ -471,15 +464,15 @@ def test_sampler_pallas_dispatch_matches_xla(tiny_dit, monkeypatch):
     """Full sample() under REPRO_KERNELS=pallas (interpret) matches the
     XLA dispatch path — the CI guard that keeps the kernel-backed cache
     datapath from rotting."""
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(12)
     pol = CachePolicy(kind="freqca", interval=4, method="dct", rho=0.25)
     crf_shape = (2, 16, cfg.d_model)
     monkeypatch.setenv("REPRO_KERNELS", "xla")
-    want = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+    want = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                           crf_shape=crf_shape)
     monkeypatch.setenv("REPRO_KERNELS", "pallas")
-    got = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+    got = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=crf_shape)
     assert int(got.n_full) == int(want.n_full)
     np.testing.assert_allclose(np.asarray(got.x), np.asarray(want.x),

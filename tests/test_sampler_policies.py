@@ -23,27 +23,19 @@ from repro.models import common, dit
 def tiny_dit():
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
-
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        return dit.dit_from_crf(params, crf, tb, cfg, 8, 8)
+    full_fn, from_crf_fn = dit.denoiser(cfg)
 
     x0 = jax.random.normal(jax.random.key(1), (2, 8, 8, cfg.in_channels))
-    return cfg, full_fn, from_crf_fn, x0
+    return cfg, full_fn, from_crf_fn, params, x0
 
 
 @pytest.mark.parametrize("kind", ["none", "fora", "taylorseer", "foca",
                                   "freqca"])
 def test_policies_sample_finite(tiny_dit, kind):
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
     pol = CachePolicy(kind=kind, interval=5, method="dct", rho=0.25)
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=(2, 16, cfg.d_model))
     assert bool(jnp.isfinite(res.x).all())
     if kind == "none":
@@ -54,27 +46,27 @@ def test_policies_sample_finite(tiny_dit, kind):
 
 
 def test_speedup_matches_interval(tiny_dit):
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     n_steps = 50
     ts = schedule.timesteps(n_steps)
     pol = CachePolicy(kind="freqca", interval=5, method="dct")
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=(2, 16, cfg.d_model))
     # paper: speedup ~ N as C_pred -> 0; 50 steps at N=5 -> 10 + warmup 2
     assert int(res.n_full) <= n_steps // 5 + 3
 
 
 def test_freqca_not_worse_than_fora(tiny_dit):
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(30)
-    ref = sampler.sample(full_fn, from_crf_fn, x0, ts,
+    ref = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
                          CachePolicy(kind="none"),
                          crf_shape=(2, 16, cfg.d_model))
 
     def err(kind, **kw):
         pol = CachePolicy(kind=kind, interval=5, method="dct", rho=0.25,
                           **kw)
-        res = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+        res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                              crf_shape=(2, 16, cfg.d_model))
         return float(jnp.mean(jnp.square(res.x - ref.x)))
 
@@ -85,9 +77,9 @@ def test_freqca_not_worse_than_fora(tiny_dit):
 
 
 def test_reference_features_trajectory(tiny_dit):
-    cfg, full_fn, _, x0 = tiny_dit
+    cfg, full_fn, _, params, x0 = tiny_dit
     ts = schedule.timesteps(8)
-    x, xs, crfs = sampler.reference_features(full_fn, x0, ts)
+    x, xs, crfs = sampler.reference_features(full_fn, params, x0, ts)
     assert xs.shape[0] == 8 and crfs.shape[0] == 8
     assert bool(jnp.isfinite(crfs).all())
 
@@ -121,14 +113,14 @@ def test_layerwise_vs_crf_prediction():
 
 def test_teacache_adaptive_compute(tiny_dit):
     """TeaCache: lower threshold -> more full steps (monotone knob)."""
-    cfg, full_fn, from_crf_fn, x0 = tiny_dit
+    cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     import jax, jax.numpy as jnp
     # perturb nothing: use the trained-enough fixture; thresholds sweep
     ts = schedule.timesteps(20)
     fulls = []
     for th in (0.01, 1e9):
         pol = CachePolicy(kind="teacache", tea_threshold=th)
-        res = sampler.sample(full_fn, from_crf_fn, x0, ts, pol,
+        res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                              crf_shape=(2, 16, cfg.d_model))
         fulls.append(int(res.n_full))
         assert bool(jnp.isfinite(res.x).all())

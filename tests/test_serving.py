@@ -33,22 +33,14 @@ def dit_fns():
     from repro.models import common, dit
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
+    full_fn, from_crf_fn = dit.denoiser(cfg)
 
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        return dit.dit_from_crf(params, crf, tb, cfg, SIZE, SIZE)
-
-    return cfg, full_fn, from_crf_fn
+    return cfg, full_fn, from_crf_fn, params
 
 
 def make_engine(dit_fns, max_batch=4, n_steps=N_STEPS, **kw):
-    cfg, full_fn, from_crf_fn = dit_fns
-    return DiffusionEngine(full_fn, from_crf_fn, (SIZE, SIZE,
+    cfg, full_fn, from_crf_fn, params = dit_fns
+    return DiffusionEngine(full_fn, from_crf_fn, params, (SIZE, SIZE,
                                                   cfg.in_channels),
                            (16, cfg.d_model),
                            CachePolicy(kind="freqca", interval=3),
@@ -666,3 +658,32 @@ def test_async_shutdown_without_drain_cancels_queued(dit_fns):
     if not fut.cancelled():
         assert fut.result(timeout=0).request_id == 0
     assert eng.scheduler.depth == 0
+
+
+def test_sampler_executables_take_weights_as_inputs():
+    """The weights are arguments of the jitted sampler, never constants
+    baked into it: the lowered program does not grow with the model,
+    so a FLUX-width model lowers at all and every signature shares one
+    copy of its weights."""
+    import dataclasses
+
+    from repro.core import policies
+    from repro.models import common, dit
+    cfg = config_lib.reduced(config_lib.get_config("dit-small"))
+    sizes = {}
+    for n_layers in (1, 4):
+        c = dataclasses.replace(cfg, n_layers=n_layers)
+        params = common.init_params(dit.dit_specs(c), jax.random.key(0))
+        full_fn, from_crf_fn = dit.denoiser(c)
+        eng = DiffusionEngine(full_fn, from_crf_fn, params,
+                              (SIZE, SIZE, c.in_channels), (16, c.d_model),
+                              policies.FreqCaPolicy(interval=3),
+                              n_steps=N_STEPS, max_batch=1)
+        x = jnp.zeros((1, SIZE, SIZE, c.in_channels))
+        text = eng._jit_run.lower(eng.params, x, eng.policy,
+                                  eng.crf_shape).as_text()
+        nbytes = sum(p.nbytes for p in jax.tree.leaves(params))
+        sizes[n_layers] = (len(text), nbytes)
+    (text1, bytes1), (text4, bytes4) = sizes[1], sizes[4]
+    assert bytes4 - bytes1 > 500_000          # the model grew ...
+    assert abs(text4 - text1) < 1_000         # ... its program did not

@@ -55,17 +55,10 @@ def test_lm_training_reduces_loss():
 def test_serving_engine_end_to_end():
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
+    full_fn, from_crf_fn = dit.denoiser(cfg)
 
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        return dit.dit_from_crf(params, crf, tb, cfg, 8, 8)
-
-    eng = DiffusionEngine(full_fn, from_crf_fn, (8, 8, cfg.in_channels),
+    eng = DiffusionEngine(full_fn, from_crf_fn, params,
+                          (8, 8, cfg.in_channels),
                           (16, cfg.d_model),
                           CachePolicy(kind="freqca", interval=5),
                           n_steps=20, max_batch=4)
@@ -81,17 +74,10 @@ def test_serving_engine_end_to_end():
 def test_editing_request_denoises_from_reference():
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     params = common.init_params(dit.dit_specs(cfg), jax.random.key(0))
+    full_fn, from_crf_fn = dit.denoiser(cfg)
 
-    def full_fn(x, t):
-        tb = jnp.full((x.shape[0],), t)
-        out = dit.dit_forward(params, x, tb, cfg)
-        return out.velocity, out.crf
-
-    def from_crf_fn(crf, t):
-        tb = jnp.full((crf.shape[0],), t)
-        return dit.dit_from_crf(params, crf, tb, cfg, 8, 8)
-
-    eng = DiffusionEngine(full_fn, from_crf_fn, (8, 8, cfg.in_channels),
+    eng = DiffusionEngine(full_fn, from_crf_fn, params,
+                          (8, 8, cfg.in_channels),
                           (16, cfg.d_model),
                           CachePolicy(kind="freqca", interval=3),
                           n_steps=10, max_batch=2)
@@ -109,17 +95,17 @@ def test_backbone_denoiser_freqca():
     params = common.init_params(dit.backbone_denoiser_specs(cfg),
                                 jax.random.key(0))
 
-    def full_fn(x, t):
+    def full_fn(params, x, t):
         tb = jnp.full((x.shape[0],), t)
         out = dit.backbone_denoiser_forward(params, x, tb, cfg)
         return out.velocity, out.crf
 
-    def from_crf_fn(crf, t):
+    def from_crf_fn(params, crf, t):
         return dit.backbone_denoiser_from_crf(params, crf, cfg, 8, 8)
 
     x0 = jax.random.normal(jax.random.key(1), (2, 8, 8, 4))
     ts = schedule.timesteps(12)
-    res = sampler.sample(full_fn, from_crf_fn, x0, ts,
+    res = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
                          CachePolicy(kind="freqca", interval=4, rho=0.25),
                          crf_shape=(2, 16, cfg.d_model))
     assert bool(jnp.isfinite(res.x).all())

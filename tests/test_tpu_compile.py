@@ -1,0 +1,78 @@
+"""Compile-only rehearsals of the served kernels for a TPU v5e chip.
+
+Each case lowers one Pallas kernel at the FLUX.1-dev serving widths
+(1024 px: 4096 image tokens, d=3072; 4608 joint tokens with the 512
+text tokens; 24 heads of 128) and compiles it for one chip of a
+described ``v5e:2x2`` topology — no chip attached.  The compiler then
+refuses what interpret mode cannot see: blocks not aligned to the
+tiling, and more VMEM than a kernel may use.
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU library, and pytest-xdist
+workers all import this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import frequency
+from repro.kernels import dct as dct_kernel
+from repro.kernels import flash_attention as fa
+from repro.kernels import freqca_fused
+
+S_IMG, S_JOINT, D, HEADS, HD = 4096, 4096 + 512, 3072, 24, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:     # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("rho", [1 / 16, 1 / 4])
+def test_band_split_spectral_compiles_at_flux_width(one_chip, rho):
+    fn = functools.partial(dct_kernel.band_split_spectral, rho=rho,
+                           interpret=False)
+    _compile(fn, one_chip, ((4, S_IMG, D), jnp.bfloat16))
+
+
+def test_fused_spectral_predict_compiles_at_flux_width(one_chip):
+    m = frequency.spectral_kept_bins(S_IMG, 1 / 16, "dct")
+    fn = functools.partial(freqca_fused.freqca_predict_fused_spectral,
+                           interpret=False)
+    _compile(fn, one_chip,
+             ((4, m, D), jnp.float32), ((S_IMG, m), jnp.float32),
+             ((4, 3, S_IMG, D), jnp.float32), ((4, 3), jnp.float32))
+
+
+@pytest.mark.parametrize("batch,seq", [(4, S_IMG), (1, S_JOINT)])
+def test_noncausal_flash_compiles_at_flux_width(one_chip, batch, seq):
+    fn = functools.partial(fa.flash_attention, q_per_kv=1, causal=False,
+                           interpret=False)
+    shape = ((batch, seq, HEADS, HD), jnp.bfloat16)
+    _compile(fn, one_chip, shape, shape, shape)
