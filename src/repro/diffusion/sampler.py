@@ -37,6 +37,13 @@ from repro.core.policies import registry as policy_registry
 
 PolicyArg = Union[object, Sequence[object]]   # Policy | spec | per-lane seq
 
+# name scopes of the two step bodies: every op of a full step (the
+# denoiser forward and the cache update) or of a cached step (the CRF
+# prediction and the final layer) carries one in its op metadata, so a
+# device trace splits the sampler's time by step kind
+FULL_STEP = "sampler.full_step"
+CACHED_STEP = "sampler.cached_step"
+
 
 class SampleResult(NamedTuple):
     x: jnp.ndarray                  # final latents
@@ -75,6 +82,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
                                       crf_dtype=crf_dtype)
         state, mask = bank.decide(state, ctx)
 
+        @jax.named_scope(FULL_STEP)
         def full_branch(op):
             x_, state_ = op
             v_full, crf = full_fn(params, x_, t_now)
@@ -99,6 +107,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
             v = jnp.where(m, v_full, v_hat.astype(v_full.dtype))
             return v.astype(x_.dtype), state_
 
+        @jax.named_scope(CACHED_STEP)
         def cached_branch(op):
             x_, state_ = op
             v = from_crf_fn(params, bank.predict(state_, ctx), t_now)

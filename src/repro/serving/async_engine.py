@@ -42,6 +42,8 @@ from concurrent.futures import CancelledError  # noqa: F401  (re-export)
 from concurrent.futures import Future, InvalidStateError, wait
 from typing import List, Optional, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.engine import DiffusionEngine
 from repro.serving.scheduler import DiffusionRequest
 
@@ -112,7 +114,8 @@ class AsyncDiffusionEngine:
         the request's batch completes.
         """
         fut: Future = Future()
-        with self.scheduler.cv:
+        with TraceAnnotation("serving.submit", request=req.request_id), \
+                self.scheduler.cv:
             if self._stop:
                 raise RuntimeError("engine has been shut down")
             if id(req) in self._futures or id(req) in self._inflight:
@@ -176,6 +179,15 @@ class AsyncDiffusionEngine:
                                    f"{timeout}s")
 
     # --- worker ----------------------------------------------------------
+    # The worker's turn is a flat run of profiler spans, none inside
+    # another, so a trace names what the host was doing at every moment:
+    # ``serving.wait`` (nothing to cut), ``serving.form_batch``, the
+    # engine's ``serving.build_x_init`` / ``dispatch`` / ``sync`` /
+    # ``results`` (``DiffusionEngine.execute_plan``), ``serving.resolve``.
+    # A batch can have several ``serving.form_batch`` spans, one per try:
+    # a try that finds the queue young and underfull cuts nothing and is
+    # followed by a ``serving.wait``; its span carries the id of the
+    # batch the next cut will be.
     def _run(self) -> None:
         sched = self.scheduler
         while True:
@@ -185,15 +197,19 @@ class AsyncDiffusionEngine:
                     if not sched.queue:
                         if self._stop:
                             return
-                        sched.cv.wait()
+                        with TraceAnnotation("serving.wait"):
+                            sched.cv.wait()
                         continue
                     flush = self._stop or self._drains > 0
                     self.metrics.observe_queue_depth(len(sched.queue))
-                    plan = sched.form_batch(flush=flush)
+                    with TraceAnnotation("serving.form_batch",
+                                         batch=self.engine.next_batch):
+                        plan = sched.form_batch(flush=flush)
                     if plan is None:
                         # deadline-aware nap: wake exactly when age or a
                         # deadline would cut (or earlier, on a submit)
-                        sched.cv.wait(sched.seconds_until_ready())
+                        with TraceAnnotation("serving.wait"):
+                            sched.cv.wait(sched.seconds_until_ready())
                 # a future whose client already cancelled it is dropped
                 # here (its lane still runs — the plan is cut); the rest
                 # move to RUNNING so late cancels can no longer race the
@@ -221,15 +237,17 @@ class AsyncDiffusionEngine:
                 if fut is not None and not fut.done():
                     fut.set_exception(e)
             return
-        if self._t0 is not None:
-            self.metrics.observe_first_result(time.perf_counter() - self._t0)
-        for fut, res in zip(futs, results, strict=True):
-            if fut is None:
-                continue
-            try:
-                fut.set_result(res)
-            except InvalidStateError:
-                # the future moved to RUNNING above, so a client cancel
-                # can't race us — but a second resolution must degrade
-                # to a counter, never kill the worker thread
-                self.metrics.observe_duplicate_result()
+        with TraceAnnotation("serving.resolve", batch=results[0].batch):
+            if self._t0 is not None:
+                self.metrics.observe_first_result(
+                    time.perf_counter() - self._t0)
+            for fut, res in zip(futs, results, strict=True):
+                if fut is None:
+                    continue
+                try:
+                    fut.set_result(res)
+                except InvalidStateError:
+                    # the future moved to RUNNING above, so a client
+                    # cancel can't race us — but a second resolution
+                    # must degrade to a counter, never kill the worker
+                    self.metrics.observe_duplicate_result()

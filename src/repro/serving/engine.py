@@ -43,6 +43,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import runtime as sanitize
 from repro.configs.base import ModelConfig
@@ -69,6 +70,9 @@ class DiffusionResult(NamedTuple):
     # budget triggered for this request's lane
     realized_error: Optional[float] = None
     budget_events: Optional[int] = None
+    # the engine's batch counter: lanes of one batch share it, and so do
+    # the batch's ``serving.*`` profiler spans
+    batch: int = 0
 
 
 class DiffusionEngine:
@@ -118,6 +122,8 @@ class DiffusionEngine:
                                    default_shape=self.default_shape,
                                    allowed_shapes=self._allowed_shapes)
         self.metrics = ServeMetrics()
+        # id of the next batch ``execute_plan`` runs
+        self.next_batch = 0
         self._ts = schedule.timesteps(n_steps)
 
         def run(params, x_init, lane_policies, crf_feat):
@@ -306,8 +312,21 @@ class DiffusionEngine:
         per-request results.  This is the single execution path shared by
         the sync drivers (``run_batch``) and ``AsyncDiffusionEngine``'s
         worker thread — only one thread may call it at a time (the async
-        engine guarantees this by owning a single worker)."""
-        x_init = self._place(self.build_x_init(plan))
+        engine guarantees this by owning a single worker).
+
+        Each phase is a profiler span carrying the batch id (see
+        ``AsyncDiffusionEngine`` for the worker's spans around it):
+        ``serving.build_x_init``, ``serving.dispatch`` (asynchronous),
+        ``serving.sync`` (the device finishing the batch) and
+        ``serving.results``."""
+        batch = self.next_batch
+        self.next_batch += 1
+        # the request ids are joined only while a trace is being taken
+        ids = (",".join(str(r.request_id) for r in plan.requests)
+               if TraceAnnotation.is_enabled() else "")
+        with TraceAnnotation("serving.build_x_init", batch=batch,
+                             requests=ids):
+            x_init = self._place(self.build_x_init(plan))
         sig = self._normalize_signature(plan.lane_policies(self.policy))
         crf = (tuple(plan.crf_shape) if plan.crf_shape is not None
                else self.crf_shape)
@@ -321,39 +340,46 @@ class DiffusionEngine:
         cache_before = self.compiled_buckets()
         t0 = time.perf_counter()
         params = self.params
-        x, n_forwards, lane_full, feedback = self._jit_run(params, x_init,
-                                                           sig, crf)
-        x.block_until_ready()
+        with TraceAnnotation("serving.dispatch", batch=batch,
+                             bucket=plan.bucket):
+            x, n_forwards, lane_full, feedback = self._jit_run(params, x_init,
+                                                               sig, crf)
+        with TraceAnnotation("serving.sync", batch=batch):
+            x.block_until_ready()
         wall = time.perf_counter() - t0
-        lane_err = lane_ev = None
-        if feedback is not None:
-            lane_err = [float(v) for v in feedback.realized[:plan.n_real]]
-            lane_ev = [int(v) for v in feedback.events[:plan.n_real]]
-        self.metrics.observe_compile(
-            hit=self.compiled_buckets() == cache_before)
-        self.metrics.observe_compiled_signatures(self.compiled_buckets())
-        self.metrics.observe_batch(
-            plan.bucket, plan.n_real, wall, int(n_forwards), self.n_steps,
-            lane_full=[int(v) for v in lane_full[:plan.n_real]],
-            group_key=plan.group_key,
-            lane_errors=lane_err, lane_events=lane_ev,
-            shape_key=self._shape_label(lat, crf))
-        self.metrics.observe_shed_events(self.scheduler.shed_events)
-        out = []
-        for i, r in enumerate(plan.requests):   # padded lanes never leak
-            err = lane_err[i] if lane_err is not None else None
-            ev = lane_ev[i] if lane_ev is not None else None
-            wait = max(0.0, plan.formed_at - r.submit_time)
-            self.metrics.observe_request(wait, wait + wall,
-                                         n_full=int(lane_full[i]),
-                                         realized_error=err,
-                                         budget_events=ev)
-            out.append(DiffusionResult(r.request_id, x[i],
-                                       int(lane_full[i]), wall, wait,
-                                       plan.bucket,
-                                       realized_error=err,
-                                       budget_events=ev))
-        return out
+        with TraceAnnotation("serving.results", batch=batch):
+            # the per-lane counts to the host in one transfer: iterating
+            # a device array dispatches a slice and a read per lane
+            n_forwards, lane_full, feedback = jax.device_get(
+                (n_forwards, lane_full, feedback))
+            lane_full = [int(v) for v in lane_full[:plan.n_real]]
+            lane_err = lane_ev = None
+            if feedback is not None:
+                lane_err = [float(v) for v in feedback.realized[:plan.n_real]]
+                lane_ev = [int(v) for v in feedback.events[:plan.n_real]]
+            self.metrics.observe_compile(
+                hit=self.compiled_buckets() == cache_before)
+            self.metrics.observe_compiled_signatures(self.compiled_buckets())
+            self.metrics.observe_batch(
+                plan.bucket, plan.n_real, wall, int(n_forwards), self.n_steps,
+                lane_full=lane_full, group_key=plan.group_key,
+                lane_errors=lane_err, lane_events=lane_ev,
+                shape_key=self._shape_label(lat, crf))
+            self.metrics.observe_shed_events(self.scheduler.shed_events)
+            out = []
+            for i, r in enumerate(plan.requests):   # padded lanes never leak
+                err = lane_err[i] if lane_err is not None else None
+                ev = lane_ev[i] if lane_ev is not None else None
+                wait = max(0.0, plan.formed_at - r.submit_time)
+                self.metrics.observe_request(wait, wait + wall,
+                                             n_full=lane_full[i],
+                                             realized_error=err,
+                                             budget_events=ev)
+                out.append(DiffusionResult(r.request_id, x[i], lane_full[i],
+                                           wall, wait, plan.bucket,
+                                           realized_error=err,
+                                           budget_events=ev, batch=batch))
+            return out
 
     # backwards-compatible alias (pre-async name)
     _execute = execute_plan

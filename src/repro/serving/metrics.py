@@ -1,6 +1,7 @@
 """Serving metrics: queue depth, batch occupancy, latency percentiles,
-full-step fraction, per-request full-step counts, time-to-first-result,
-compile-cache accounting, and policy-group accounting.
+full-step fraction, batch forwards, per-request full-step counts,
+time-to-first-result, compile-cache accounting, and policy-group
+accounting.
 
 Compute and quality are tracked separately now that activation is
 per-lane: ``full_step_fraction`` charges every lane of a batch for each
@@ -49,17 +50,30 @@ def _metrics_lock() -> threading.Lock:
     return make_lock("ServeMetrics._lock")
 
 
-# snapshot schema: counters sum under merge, lists concatenate, and the
-# optionals carry their own fold (min / max / sum-of-present)
+# snapshot schema: counters sum under merge, running maxima take the
+# larger, lists concatenate, and the optionals carry their own fold
+# (min / max / sum-of-present)
 _COUNTER_FIELDS = ("compile_hits", "compile_misses", "full_steps",
-                   "total_steps", "budget_events_total", "shed_events",
-                   "duplicate_results", "stale_pong_kills")
+                   "total_steps", "forwards", "budget_events_total",
+                   "shed_events", "duplicate_results", "stale_pong_kills")
+_MAX_FIELDS = ("max_queue_depth", "max_lane_full_spread")
 _LIST_FIELDS = ("batch_walls", "batch_buckets", "batch_occupancy",
-                "batch_lane_spread", "request_waits", "request_latencies",
-                "request_full_steps", "request_realized_errors",
-                "queue_depths")
+                "request_waits", "request_latencies", "request_full_steps",
+                "request_realized_errors")
+# lists that snapshots of an older wire schema carry in place of the
+# running maxima: field -> the list whose maximum it is
+_LEGACY_LISTS = {"max_queue_depth": "queue_depths",
+                 "max_lane_full_spread": "batch_lane_spread"}
 _OPTIONAL_FIELDS = ("time_to_first_result_s", "cache_state_bytes_per_lane",
                     "compiled_signatures")
+
+
+def _max_of(d: Dict, field: str) -> int:
+    """A running maximum from a snapshot, or the maximum of the list an
+    older snapshot carries in its place."""
+    if field in d:
+        return int(d[field])
+    return int(max(d.get(_LEGACY_LISTS[field], ()), default=0))
 
 
 def percentile(xs: List[float], q: float) -> float:
@@ -80,9 +94,15 @@ class ServeMetrics:
     batch_walls: List[float] = dataclasses.field(default_factory=list)
     batch_buckets: List[int] = dataclasses.field(default_factory=list)
     batch_occupancy: List[float] = dataclasses.field(default_factory=list)
-    batch_lane_spread: List[int] = dataclasses.field(default_factory=list)
+    # largest spread of activated steps across the lanes of one batch: 0
+    # under a batch-global decision, > 0 once lanes follow their own
+    # schedules
+    max_lane_full_spread: int = 0
     full_steps: int = 0
     total_steps: int = 0
+    # batch forwards (full steps of a batch, any lane activating) summed
+    # over batches
+    forwards: int = 0
     # request-level observations
     request_waits: List[float] = dataclasses.field(default_factory=list)
     request_latencies: List[float] = dataclasses.field(default_factory=list)
@@ -96,8 +116,8 @@ class ServeMetrics:
     # latest scheduler shed counter (budgets relaxed under queue
     # pressure; requests are never dropped)
     shed_events: int = 0
-    # queue depth samples (taken whenever the engine polls the queue)
-    queue_depths: List[int] = dataclasses.field(default_factory=list)
+    # largest queue depth seen whenever the engine polls the queue
+    max_queue_depth: int = 0
     # futures whose second resolution was absorbed (requeue races on
     # the exactly-once path; see FleetRouter._finish / _serve)
     duplicate_results: int = 0
@@ -137,7 +157,7 @@ class ServeMetrics:
 
     def observe_queue_depth(self, depth: int) -> None:
         with self._lock:
-            self.queue_depths.append(int(depth))
+            self.max_queue_depth = max(self.max_queue_depth, int(depth))
 
     def observe_first_result(self, elapsed_s: float) -> None:
         """Record time-to-first-result once (later calls are no-ops)."""
@@ -214,10 +234,8 @@ class ServeMetrics:
                 if lane_errors:
                     g[4].extend(float(e) for e in lane_errors)
             if lane_full:
-                # spread across lanes of one batch: 0 under a batch-global
-                # decision, > 0 once lanes follow their own schedules
-                self.batch_lane_spread.append(
-                    max(lane_full) - min(lane_full))
+                self.max_lane_full_spread = max(
+                    self.max_lane_full_spread, max(lane_full) - min(lane_full))
             self.batch_walls.append(float(wall_s))
             self.batch_buckets.append(int(bucket))
             self.batch_occupancy.append(n_real / max(bucket, 1))
@@ -225,6 +243,7 @@ class ServeMetrics:
             # forward, so the compute fraction is forwards-based
             self.full_steps += int(n_forwards) * int(bucket)
             self.total_steps += int(n_steps) * int(bucket)
+            self.forwards += int(n_forwards)
 
     def observe_request(self, wait_s: float, latency_s: float,
                         n_full: Optional[int] = None,
@@ -258,10 +277,8 @@ class ServeMetrics:
             lats = list(self.request_latencies)
             waits = list(self.request_waits)
             fulls = [float(v) for v in self.request_full_steps]
-            spread = list(self.batch_lane_spread)
             buckets = list(self.batch_buckets)
             occ = list(self.batch_occupancy)
-            depths = list(self.queue_depths)
             ttfr = self.time_to_first_result_s
             state_bytes = self.cache_state_bytes_per_lane
             hits, misses = self.compile_hits, self.compile_misses
@@ -271,6 +288,8 @@ class ServeMetrics:
             budget_events = self.budget_events_total
             shed = self.shed_events
             stale_kills = self.stale_pong_kills
+            forwards = self.forwards
+            spread, depth = self.max_lane_full_spread, self.max_queue_depth
             per_group = {
                 k: {"batches": g[0], "requests": g[1],
                     "mean_occupancy": round(g[2] / max(g[0], 1), 3),
@@ -294,6 +313,10 @@ class ServeMetrics:
             "request_latency_p50_s": round(percentile(lats, 50), 4),
             "request_latency_p95_s": round(percentile(lats, 95), 4),
             "request_wait_p50_s": round(percentile(waits, 50), 4),
+            # batch forwards: the full steps the device ran, whatever
+            # the lane count; a trace's time under the sampler's
+            # full-step scope over this count is one full step
+            "forwards": forwards,
             "full_step_fraction": round(frac, 4),
             "skip_compute_fraction": round(1.0 - frac, 4),
             "request_full_p50": percentile(fulls, 50),
@@ -305,7 +328,7 @@ class ServeMetrics:
             "budget_events": budget_events,
             "shed_events": shed,
             "stale_pong_kills": stale_kills,
-            "max_lane_full_spread": max(spread, default=0),
+            "max_lane_full_spread": spread,
             "compile_hits": hits,
             "compile_misses": misses,
             "compiled_signatures": signatures,
@@ -313,7 +336,7 @@ class ServeMetrics:
             "per_group": per_group,
             "shape_keys": len(per_shape),
             "per_shape": per_shape,
-            "max_queue_depth": max(depths, default=0),
+            "max_queue_depth": depth,
             "time_to_first_result_s": (None if ttfr is None
                                        else round(ttfr, 4)),
             "cache_state_bytes_per_lane": state_bytes,
@@ -327,12 +350,10 @@ class ServeMetrics:
                 batch_walls=list(self.batch_walls),
                 batch_buckets=list(self.batch_buckets),
                 batch_occupancy=list(self.batch_occupancy),
-                batch_lane_spread=list(self.batch_lane_spread),
                 request_waits=list(self.request_waits),
                 request_latencies=list(self.request_latencies),
                 request_full_steps=list(self.request_full_steps),
                 request_realized_errors=list(self.request_realized_errors),
-                queue_depths=list(self.queue_depths),
                 group_batches={k: v[:4] + [list(v[4])]
                                for k, v in self.group_batches.items()},
                 shape_batches={k: list(v)
@@ -348,7 +369,7 @@ class ServeMetrics:
         way to read raw counters from outside: benchmarks and the fleet
         aggregator go through this instead of reaching into fields)."""
         with self._lock:
-            d = {f: getattr(self, f) for f in _COUNTER_FIELDS}
+            d = {f: getattr(self, f) for f in _COUNTER_FIELDS + _MAX_FIELDS}
             d.update({f: list(getattr(self, f)) for f in _LIST_FIELDS})
             d.update({f: getattr(self, f) for f in _OPTIONAL_FIELDS})
             d["group_batches"] = {k: v[:4] + [list(v[4])]
@@ -364,10 +385,13 @@ class ServeMetrics:
 
         Missing fields default (0 / [] / None) so snapshots written by
         an older wire schema — a replica one release behind its router
-        — still load."""
+        — still load; one that carries the observation lists in place of
+        the running maxima loads their maxima."""
         m = cls()
         for f in _COUNTER_FIELDS:
             setattr(m, f, int(d.get(f, 0)))
+        for f in _MAX_FIELDS:
+            setattr(m, f, _max_of(d, f))
         for f in _LIST_FIELDS:
             setattr(m, f, list(d.get(f, ())))
         for f in _OPTIONAL_FIELDS:
@@ -385,9 +409,10 @@ class ServeMetrics:
         """Fold snapshots (``ServeMetrics`` or ``to_dict`` dicts) from
         independent engines into one fleet-wide instance.
 
-        Counters sum, observation lists concatenate (so ``summary()``
-        percentiles are exact fleet-wide, not averages of averages),
-        ``time_to_first_result_s`` is the fleet minimum,
+        Counters sum, running maxima take the largest, observation lists
+        concatenate (so ``summary()`` percentiles are exact fleet-wide,
+        not averages of averages), ``time_to_first_result_s`` is the
+        fleet minimum,
         ``cache_state_bytes_per_lane`` the maximum (replicas of one
         deployment report the same figure), and ``compiled_signatures``
         the fleet total of present probes.  Associative: merging merges
@@ -398,6 +423,8 @@ class ServeMetrics:
             d = part if isinstance(part, dict) else part.to_dict()
             for f in _COUNTER_FIELDS:
                 setattr(merged, f, getattr(merged, f) + int(d.get(f, 0)))
+            for f in _MAX_FIELDS:
+                setattr(merged, f, max(getattr(merged, f), _max_of(d, f)))
             for f in _LIST_FIELDS:
                 getattr(merged, f).extend(d.get(f, ()))
             ttfr = d.get("time_to_first_result_s")

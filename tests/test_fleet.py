@@ -100,6 +100,50 @@ def test_metrics_merge_is_lossless_and_associative():
     assert right.summary() == merged.summary()
 
 
+def test_metrics_forwards_and_running_maxima_roundtrip_and_merge():
+    parts = [_sample_metrics(seed=s) for s in range(3)]
+    parts[1].observe_queue_depth(9)
+    parts[2].observe_batch(4, 2, 0.1, 5, N_STEPS, lane_full=[5, 1])
+    m = parts[0]
+    assert (m.forwards, m.max_queue_depth, m.max_lane_full_spread) \
+        == (6, 2, 1)
+    back = ServeMetrics.from_dict(m.to_dict())
+    assert (back.forwards, back.max_queue_depth,
+            back.max_lane_full_spread) == (6, 2, 1)
+    merged = ServeMetrics.merge([p.to_dict() for p in parts])
+    # forwards sum; the maxima take the largest of any replica
+    assert merged.forwards == merged.summary()["forwards"] == 6 + 6 + 11
+    assert merged.summary()["max_queue_depth"] == 9
+    assert merged.summary()["max_lane_full_spread"] == 4
+
+
+def test_metrics_keep_no_list_per_worker_turn():
+    m = ServeMetrics()
+    before = m.to_dict()
+    for depth in range(1000):
+        m.observe_queue_depth(depth % 7)
+    after = m.to_dict()
+    assert after["max_queue_depth"] == 6
+    assert {k: v for k, v in after.items() if isinstance(v, list)} \
+        == {k: v for k, v in before.items() if isinstance(v, list)}
+
+
+def test_metrics_snapshot_with_observation_lists_still_loads():
+    """A replica one release behind ships the raw lists in place of the
+    running maxima: they load as their maxima and merge with new ones."""
+    old = _sample_metrics().to_dict()
+    for f in ("max_queue_depth", "max_lane_full_spread", "forwards"):
+        del old[f]
+    old.update(queue_depths=[1, 7, 2], batch_lane_spread=[0, 3, 1])
+    m = ServeMetrics.from_dict(old)
+    assert (m.max_queue_depth, m.max_lane_full_spread, m.forwards) \
+        == (7, 3, 0)
+    merged = ServeMetrics.merge([old, _sample_metrics(seed=1)])
+    assert merged.summary()["max_queue_depth"] == 7
+    assert merged.summary()["max_lane_full_spread"] == 3
+    assert merged.forwards == 6
+
+
 def test_fleet_metrics_summary_sections():
     snaps = {i: _sample_metrics(seed=i).to_dict() for i in range(2)}
     fm = FleetMetrics(snaps, routing={"affinity_hits": 5, "spills": 1},
@@ -282,6 +326,7 @@ def test_async_engine_absorbs_duplicate_resolution():
     from concurrent.futures import Future
 
     from repro.serving.async_engine import AsyncDiffusionEngine
+    from repro.serving.engine import DiffusionResult
     from repro.serving.metrics import ServeMetrics
 
     class _Eng:
@@ -289,7 +334,7 @@ def test_async_engine_absorbs_duplicate_resolution():
             self.metrics = ServeMetrics()
 
         def execute_plan(self, plan):
-            return ["res"]
+            return [DiffusionResult(0, None, 0, 0.0)]
 
     aeng = AsyncDiffusionEngine.__new__(AsyncDiffusionEngine)
     aeng.engine = _Eng()

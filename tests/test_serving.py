@@ -9,6 +9,7 @@ per group, bitwise-golden equivalence against the ungrouped mixed-lane
 path — sync and through the async engine under concurrent
 submitters)."""
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ import pytest
 import repro.configs as config_lib
 from repro.core.cache import CachePolicy
 from repro.data import synthetic
-from repro.diffusion import schedule
+from repro.diffusion import sampler, schedule
 from repro.serving import metrics as metrics_lib
 from repro.serving.async_engine import AsyncDiffusionEngine
 from repro.serving.engine import DiffusionEngine, DiffusionRequest
@@ -687,3 +688,77 @@ def test_sampler_executables_take_weights_as_inputs():
     (text1, bytes1), (text4, bytes4) = sizes[1], sizes[4]
     assert bytes4 - bytes1 > 500_000          # the model grew ...
     assert abs(text4 - text1) < 1_000         # ... its program did not
+
+
+# ---------------------------------------------------------------------------
+# profiler scopes and spans
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("policy,scopes", [
+    ("freqca", {sampler.FULL_STEP, sampler.CACHED_STEP}),
+    ("none", {sampler.FULL_STEP})])
+def test_sampler_step_scopes_in_op_metadata(dit_fns, policy, scopes):
+    """Every op of a full step and of a cached step carries its step's
+    name scope; the uncached policy calls the full step alone."""
+    from repro.core import policies
+    cfg, full_fn, from_crf_fn, params = dit_fns
+    pol = (policies.FreqCaPolicy(interval=3) if policy == "freqca"
+           else policies.NoCachePolicy())
+    eng = DiffusionEngine(full_fn, from_crf_fn, params,
+                          (SIZE, SIZE, cfg.in_channels), (16, cfg.d_model),
+                          pol, n_steps=N_STEPS, max_batch=1)
+    x = jnp.zeros((1, SIZE, SIZE, cfg.in_channels))
+    text = eng._jit_run.lower(eng.params, x, eng.policy,
+                              eng.crf_shape).as_text(debug_info=True)
+    assert {s for s in (sampler.FULL_STEP, sampler.CACHED_STEP)
+            if s in text} == scopes
+
+
+WORKER_SPANS = ("serving.form_batch", "serving.build_x_init",
+                "serving.dispatch", "serving.sync", "serving.results",
+                "serving.resolve")
+
+
+@pytest.mark.parametrize("max_wait_s", [0.0, 30.0])
+def test_worker_spans_are_flat_and_share_batch_ids(dit_fns, tmp_path,
+                                                   max_wait_s):
+    """A profiled async run holds one of each worker span per batch, all
+    carrying the batch id its results report, one submit span per
+    request, and no program span inside another on any thread.  With a
+    wait before an underfull cut, the tries that cut nothing add
+    ``serving.form_batch`` spans with the next batch's id."""
+    from jax.profiler import ProfileData
+    eng = make_engine(dit_fns, max_wait_s=max_wait_s)
+    eng.warmup()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with AsyncDiffusionEngine(eng) as aeng:
+            futs = [aeng.submit(DiffusionRequest(request_id=i, seed=i))
+                    for i in range(6)]
+            futs[0].result(timeout=120)
+            # time for a try at the rest, which a wait leaves uncut
+            # until the exit's drain cuts it
+            time.sleep(0.2)
+        results = [f.result(timeout=120) for f in futs]
+    finally:
+        jax.profiler.stop_trace()
+    profile = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    threads = [sorted((e.start_ns, e.end_ns, e.name, dict(e.stats))
+                      for e in ln.events if e.name.startswith("serving."))
+               for p in profile.planes for ln in p.lines]
+    spans = [s for t in threads for s in t]
+    batches = sorted({r.batch for r in results})
+    assert len(batches) >= 2
+    for name in WORKER_SPANS[1:]:
+        assert sorted(s[3]["batch"] for s in spans if s[2] == name) \
+            == batches, name
+    tries = sorted(s[3]["batch"] for s in spans
+                   if s[2] == "serving.form_batch")
+    assert sorted(set(tries)) == batches
+    # 6 requests in batches of at most 4: the last batch is underfull,
+    # and with a wait it is tried before the drain cuts it
+    assert (len(tries) > len(batches)) == (max_wait_s > 0)
+    assert sorted(s[3]["request"] for s in spans
+                  if s[2] == "serving.submit") == list(range(6))
+    for t in threads:
+        assert all(a[1] <= b[0] for a, b in zip(t, t[1:])), t

@@ -76,3 +76,44 @@ def test_noncausal_flash_compiles_at_flux_width(one_chip, batch, seq):
                            interpret=False)
     shape = ((batch, seq, HEADS, HD), jnp.bfloat16)
     _compile(fn, one_chip, shape, shape, shape)
+
+
+def test_step_scopes_leave_the_kernel_names(one_chip, monkeypatch):
+    """The sampler's step scopes reach every kernel's op metadata and
+    leave its instruction named after the jitted function that calls it,
+    the name the benchmark's trace reduction finds it by: a FreqCa
+    engine's whole sampler at 1024 tokens, d=256, compiled for one chip."""
+    import re
+
+    from repro.configs.base import DiTConfig
+    from repro.core import policies
+    from repro.diffusion import sampler
+    from repro.models import dit
+    from repro.serving.engine import DiffusionEngine
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "0")
+    cfg = DiTConfig(arch_id="tiny", n_layers=1, d_model=256, n_heads=2,
+                    d_ff=512, patch_size=2, in_channels=4, text_dim=0,
+                    n_text_tokens=0, dtype="bfloat16")
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: dit.random_params(cfg, 0)))
+    full_fn, from_crf_fn = dit.denoiser(cfg)
+    eng = DiffusionEngine(full_fn, from_crf_fn, params, (64, 64, 4),
+                          (1024, 256), policies.FreqCaPolicy(interval=5),
+                          n_steps=6, max_batch=2)
+    x = jax.ShapeDtypeStruct((2, 64, 64, 4), jnp.float32, sharding=one_chip)
+    text = eng._jit_run.lower(params, x, eng.policy,
+                              eng.crf_shape).compile().as_text()
+    kernels = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            name = line.split(" = ")[0].strip().lstrip("%")
+            scope = re.search(r'op_name="([^"]+)"', line).group(1)
+            kernels[re.sub(r"\.\d+$", "", name)] = scope
+    assert set(kernels) == {"_flash", "_band_split_spectral_pallas",
+                            "_freqca_predict_spectral_pallas"}
+    assert f"/{sampler.FULL_STEP}/" in kernels["_flash"]
+    assert f"/{sampler.FULL_STEP}/" in kernels["_band_split_spectral_pallas"]
+    assert f"/{sampler.CACHED_STEP}/" \
+        in kernels["_freqca_predict_spectral_pallas"]
