@@ -14,11 +14,21 @@ times are correctness-priced, not speed-priced (each row records
 * ``band_split`` — pure-jnp ``frequency.decompose`` (transform
   round-trip) vs the fused spectral Pallas kernel (one pass emitting
   ``(low_spec, high)``).
-* ``attention`` — full-logits ``_sdpa`` vs the flash kernel at a shape
-  above the DiT's ``_FLASH_MIN_SEQ`` routing threshold.
+* ``attention`` — the non-causal flash kernel in bf16 at the two
+  served shapes (FLUX cut: B=4, S=4096, 24 x 128; DiT-XL/2: B=8,
+  S=1024, 16 x 72) with its default tiles: wall time beside the share of
+  ``bench/work.flash``'s roofline (on a chip in ``bench/peaks.json``;
+  None elsewhere).
+
+``python -m benchmarks.kernel_bench --sweep 512x512,1024x512,...`` times
+the served shapes at each listed (q_block, kv_block) instead, on the
+chip, into ``results/bench/BENCH_flash_sweep.json``.
 """
 from __future__ import annotations
 
+import argparse
+import functools
+import json
 import time
 
 import jax
@@ -30,7 +40,6 @@ from repro.core.policies import base as policy_base
 from repro.core.policies.freqca import FreqCaPolicy
 from repro.kernels import dct as dct_kernel
 from repro.kernels import ops
-from repro.models import attention as attn_lib
 
 def _wall(fn, *args, reps: int = 3) -> float:
     out = fn(*args)
@@ -120,49 +129,78 @@ def band_split_row(batch: int, s: int, d: int, rho: float) -> dict:
     }
 
 
-def attention_row(batch: int, s: int, heads: int, hd: int) -> dict:
-    """Full-logits sdpa vs flash kernel, non-causal."""
-    q = jax.random.normal(jax.random.key(2), (batch, s, heads, hd))
-    k = jax.random.normal(jax.random.key(3), (batch, s, heads, hd))
-    v = jax.random.normal(jax.random.key(4), (batch, s, heads, hd))
-    mask = jnp.ones((1, s, s), bool)
-    itemsize = 4
-    sdpa = jax.jit(lambda a, b, c: attn_lib._sdpa(a, b, c, mask, 1))
-    flash = jax.jit(_flash_call)
+# the flash kernel's two served shapes: (cell's config, B, S, heads, hd)
+SERVED_ATTENTION = (("flux1-dev-cut", 4, 4096, 24, 128),
+                    ("dit-xl2-512", 8, 1024, 16, 72))
+
+
+def attention_row(batch: int, s: int, heads: int, hd: int,
+                  dtype=jnp.bfloat16, blocks: tuple | None = None) -> dict:
+    """The non-causal flash kernel at one shape, at the (q, kv) blocks
+    given (None: ``flash_attention.tiles``): host wall time of a call,
+    the work ``bench/work.flash`` counts, and on a chip listed in
+    ``bench/peaks.json`` the share of that work's roofline."""
+    from bench import cell, work
+    from repro.kernels import flash_attention as fa
+    q, k, v = (jax.random.normal(kk, (batch, s, heads, hd)).astype(dtype)
+               for kk in jax.random.split(jax.random.key(2), 3))
+    qb, kb = blocks or fa.tiles(s, s, hd, dtype)
+    flash = jax.jit(functools.partial(
+        fa.flash_attention, q_per_kv=1, causal=False, q_block=qb,
+        kv_block=kb, interpret=ops.interpret()))
+    wall = _wall(flash, q, k, v, reps=5)
+    need = work.flash(batch, s, heads, hd, jnp.dtype(dtype).name)
+    share = bound = None
+    peak = cell.peaks().get(jax.devices()[0].device_kind)
+    if not ops.interpret() and peak is not None:
+        least, bound = work.roofline_s(need, peak)
+        share = round(100.0 * least / wall, 3)
     return {
         "name": "attention",
         "batch": batch, "tokens": s, "heads": heads, "head_dim": hd,
-        # sdpa materialises the [B, H, S, S] logits+probs at fusion
-        # boundaries; flash keeps them in VMEM
-        "bytes_sdpa": (3 * batch * s * heads * hd
-                       + 2 * batch * heads * s * s
-                       + batch * s * heads * hd) * itemsize,
-        "bytes_flash": 4 * batch * s * heads * hd * itemsize,
-        "wall_sdpa_ms": round(1e3 * _wall(sdpa, q, k, v), 3),
-        "wall_flash_ms": round(1e3 * _wall(flash, q, k, v), 3),
+        "dtype": jnp.dtype(dtype).name, "q_block": qb, "kv_block": kb,
+        "grid_steps": batch * heads * (s // qb) * (s // kb),
+        "flops": need.flops, "bytes": need.bytes,
+        "wall_flash_ms": round(1e3 * wall, 3),
+        # host clock around the call: an upper bound on device time
+        "flash_roofline_pct": share, "bound": bound,
         "interpret": ops.interpret(),
     }
 
 
-def _flash_call(q, k, v):
+def sweep(blocks, out: str = "results/bench/BENCH_flash_sweep.json"):
+    """Each served attention shape at ``tiles``' blocks and at every
+    (q_block, kv_block) of ``blocks`` that divides its length: the
+    sweep the caps ``Q_CAP``/``TILE_CAP`` are chosen from."""
     from repro.kernels import flash_attention as fa
-    return fa.flash_attention(q, k, v, 1, causal=False, q_block=128,
-                              kv_block=128, interpret=ops.interpret())
+    rows = []
+    for config, batch, s, heads, hd in SERVED_ATTENTION:
+        seen = set()
+        for qb, kb in [fa.tiles(s, s, hd, jnp.bfloat16), *blocks]:
+            qb, kb = min(qb, s), min(kb, s)
+            if s % qb or s % kb or (qb, kb) in seen:
+                continue
+            seen.add((qb, kb))
+            row = attention_row(batch, s, heads, hd, jnp.bfloat16, (qb, kb))
+            rows.append({"config": config, **row})
+            print(json.dumps(rows[-1]), flush=True)
+    B.save_rows(out, rows)
+    return rows
 
 
 def run(out: str = "results/bench/BENCH_kernels.json"):
     # call-time read: run.py --smoke sets BENCH_REDUCED after import
     if B.reduced():
         batch, s, d = 1, 256, 128
-        attn_s, heads, hd = 256, 2, 32
+        attention = [("tiny", 1, 256, 2, 32)]
     else:
         batch, s, d = 2, 1024, 512
-        attn_s, heads, hd = 1024, 4, 64
+        attention = SERVED_ATTENTION
     rho = 0.0625
     rows = [
         cached_step_row(batch, s, d, rho),
         band_split_row(batch, s, d, rho),
-        attention_row(batch, attn_s, heads, hd),
+        *(attention_row(b, n, h, hd) for _, b, n, h, hd in attention),
     ]
     for row in rows:  # heterogeneous schemas: one table per row
         B.print_table(f"Kernel paths — {row['name']}", [row])
@@ -177,8 +215,17 @@ def run(out: str = "results/bench/BENCH_kernels.json"):
     return rows
 
 
-def main():
-    run()
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--sweep", metavar="QxK,...",
+                    help="time the served attention shapes at these "
+                         "blocks instead, e.g. 512x512,1024x512")
+    args = ap.parse_args(argv)
+    if args.sweep is None:
+        run()
+        return
+    sweep([tuple(int(n) for n in b.split("x"))
+           for b in args.sweep.split(",")])
 
 
 if __name__ == "__main__":

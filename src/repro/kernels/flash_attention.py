@@ -6,6 +6,13 @@ normaliser l) lives in VMEM scratch across kv steps and the output tile
 is written once on the last step — the TPU-native version of the
 jnp blockwise path in ``models/attention.blockwise_sdpa`` (its oracle).
 
+The MXU takes Q, K and V at their input dtype with f32 accumulation
+(a product of two bf16 values is exact in f32); only the probabilities
+are rounded, to V's dtype, for the PV product.  The logits, m, l, acc
+and the final division stay in f32.  Tiles follow from the call's shape
+(``tiles``): each grid step carries a fixed cost, so the blocks are as
+large as v5e's default scoped VMEM allows.
+
 This is what the roofline's "memory term is an upper bound" note refers
 to (EXPERIMENTS.md §Roofline): the XLA-level blockwise path materialises
 [qb x kb] logits tiles at fusion boundaries, while this kernel keeps
@@ -13,6 +20,7 @@ them in VMEM/VREGs.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -21,19 +29,91 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
+# largest q block, and largest [q block x kv block] logits tile (4 MiB of
+# f32): at both served shapes, the pair within VMEM_BUDGET that an
+# on-chip sweep (benchmarks/kernel_bench.py --sweep) timed fastest
+Q_CAP, TILE_CAP = 2048, 1 << 20
+# v5e's default scoped VMEM per kernel (not raised: ``vmem_limit_bytes``)
+VMEM_BUDGET = 16 * 1024 * 1024
+# contract the last dims of q [qb, hd] and k [kb, hd]: q·kᵀ with no transpose
+_QK_DIMS = (((1,), (1,)), ((), ()))
 
 
-def dispatch_ok(s: int, q_block: int = 128, kv_block: int = 128) -> bool:
-    """Self-attention shapes ``flash_attention``'s default tiling
-    accepts (it asserts ``s % qb == 0`` at trace time) — dispatch
-    layers pre-check here so the predicate can't drift from the block
-    defaults."""
-    return s % min(q_block, s) == 0 and s % min(kv_block, s) == 0
+def _block(n: int, cap: int) -> int:
+    """``n`` itself if it fits the cap, else its largest power-of-two
+    divisor up to the cap."""
+    if n <= cap:
+        return n
+    return math.gcd(n, 1 << (cap.bit_length() - 1))
+
+
+def _blocks(s: int, t: int) -> tuple[int, int]:
+    """The q block up to ``Q_CAP``, then the kv block up to what keeps
+    the logits tile within ``TILE_CAP``."""
+    bq = _block(s, Q_CAP)
+    return bq, _block(t, TILE_CAP // bq)
+
+
+def vmem_bytes(bq: int, bk: int, hd: int, dtype) -> int:
+    """VMEM the kernel holds at blocks (bq, bk): double-buffered Q, K, V
+    and O blocks (rows padded to 128 lanes), the f32 acc, the
+    lane-replicated m and l, and the f32 [bq, bk] logits and
+    probabilities.  Conservative: over blocks of 256 to 4096 at head
+    sizes 72, 128 and 256 in bf16 and f32, the v5e compiler accepted
+    every pair this puts under ``VMEM_BUDGET`` (and some above it)."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = -(-hd // LANES) * LANES
+    blocks = 2 * (2 * bq + 2 * bk) * lanes * item
+    scratch = bq * lanes * 4 + 2 * bq * LANES * 4
+    return blocks + scratch + 2 * bq * bk * 4
+
+
+def _halve(n: int, b: int) -> int:
+    """``b`` halved if the half still divides ``n`` in whole lanes."""
+    h = b // 2
+    return h if b % 2 == 0 and h % LANES == 0 and n % h == 0 else b
+
+
+def tiles(s: int, t: int, hd: int, dtype) -> tuple[int, int]:
+    """(block_q, block_k) for ``s`` queries over ``t`` keys of head size
+    ``hd``: the largest power-of-two divisor of each length up to its
+    cap (the whole length if it fits; ``_blocks``), halved — kv first —
+    while ``vmem_bytes`` exceeds the default scoped VMEM."""
+    bq, bk = _blocks(s, t)
+    while vmem_bytes(bq, bk, hd, dtype) > VMEM_BUDGET:
+        nk = _halve(t, bk)
+        if nk != bk:
+            bk = nk
+            continue
+        nq = _halve(s, bq)
+        if nq == bq:
+            break
+        bq = nq
+    return bq, bk
+
+
+def dispatch_ok(s: int) -> bool:
+    """Self-attention lengths the default tiles serve: each block is the
+    whole sequence or a whole number of 128-row lanes, so no length
+    falls back to tiny steps.  ``tiles`` only ever halves a block to
+    another such block, so the rule holds at every head size and dtype
+    (every length the former fixed 128-blocks took qualifies)."""
+    return all(b == s or b % LANES == 0 for b in _blocks(s, s))
+
+
+def _lanes(x, n: int):
+    """A lane-replicated [rows, 128] statistic as [rows, n]."""
+    reps, rem = divmod(n, LANES)
+    if rem == 0:
+        return x if reps == 1 else jnp.tile(x, (1, reps))
+    if reps == 0:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                  *, causal: bool, window: int, kb: int, nk: int,
-                  scale: float):
+                  *, causal: bool, window: int, nk: int, scale: float):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -43,47 +123,50 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)          # [qb, hd]
-    k = k_ref[0, 0].astype(jnp.float32)          # [kb, hd]
-    v = v_ref[0, 0].astype(jnp.float32)          # [kb, hd]
-    qb = q.shape[0]
+    q = q_ref[0, 0]                              # [qb, hd]
+    k = k_ref[0, 0]                              # [kb, hd]
+    v = v_ref[0, 0]                              # [kb, hd]
+    qb, kb = q.shape[0], k.shape[0]
 
-    logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    q_pos = qi * qb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 0)
-    k_pos = ki * kb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 1)
-    mask = jnp.ones((qb, kb), jnp.bool_)
-    if causal:
-        mask &= k_pos <= q_pos
-    if window > 0:
-        mask &= k_pos > q_pos - window
-    logits = jnp.where(mask, logits, NEG_INF)
+    logits = jax.lax.dot_general(q, k, _QK_DIMS,
+                                 preferred_element_type=jnp.float32) * scale
+    if causal or window > 0:
+        q_pos = qi * qb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 0)
+        k_pos = ki * kb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 1)
+        keep = [k_pos <= q_pos] if causal else []
+        if window > 0:
+            keep.append(k_pos > q_pos - window)
+        logits = jnp.where(functools.reduce(jnp.logical_and, keep), logits,
+                           NEG_INF)
 
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-    p = jnp.exp(logits - m_new[:, None])
+    m_prev = m_ref[...]                          # [qb, 128], lanes equal
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    p = jnp.exp(logits - _lanes(m_new, kb))
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + \
-        jnp.dot(p, v, preferred_element_type=jnp.float32)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     m_ref[...] = m_new
-    l_ref[...] = l_new
+    acc_ref[...] = acc_ref[...] * _lanes(corr, acc_ref.shape[1]) + \
+        jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)[:, None]
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / _lanes(l, acc_ref.shape[1])
                        ).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, q_per_kv: int, causal: bool = True,
-                    window: int = 0, q_block: int = 128,
-                    kv_block: int = 128, interpret: bool = True):
-    """q: [B, S, Hq, hd]; k, v: [B, T, Hkv, hd] -> [B, S, Hq, hd]."""
+                    window: int = 0, q_block: int | None = None,
+                    kv_block: int | None = None, interpret: bool = True):
+    """q: [B, S, Hq, hd]; k, v: [B, T, Hkv, hd] -> [B, S, Hq, hd].
+
+    ``q_block``/``kv_block`` of None take ``tiles``' blocks; an explicit
+    block is clipped to the sequence."""
     b, s, hq, hd = q.shape
     t, hkv = k.shape[1], k.shape[2]
-    qb = min(q_block, s)
-    kb = min(kv_block, t)
+    auto_q, auto_k = tiles(s, t, hd, q.dtype)
+    qb = auto_q if q_block is None else min(q_block, s)
+    kb = auto_k if kv_block is None else min(kv_block, t)
     assert s % qb == 0 and t % kb == 0, (s, t, qb, kb)
     nq, nk = s // qb, t // kb
     g = q_per_kv
@@ -96,9 +179,8 @@ def flash_attention(q, k, v, q_per_kv: int, causal: bool = True,
     kg = k.transpose(0, 2, 1, 3).reshape(b * hkv, nk, kb, hd)
     vg = v.transpose(0, 2, 1, 3).reshape(b * hkv, nk, kb, hd)
 
-    import functools
     kernel = functools.partial(_flash_kernel, causal=causal, window=window,
-                               kb=kb, nk=nk, scale=scale)
+                               nk=nk, scale=scale)
     out = pl.pallas_call(
         kernel,
         grid=(b * hkv * g, nq, nk),
@@ -114,9 +196,11 @@ def flash_attention(q, k, v, q_per_kv: int, causal: bool = True,
         out_shape=jax.ShapeDtypeStruct((b * hkv * g, nq, qb, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((qb, hd), jnp.float32),
-            pltpu.VMEM((qb,), jnp.float32),
-            pltpu.VMEM((qb,), jnp.float32),
+            pltpu.VMEM((qb, LANES), jnp.float32),
+            pltpu.VMEM((qb, LANES), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, kg, vg)
     return out.reshape(b, hkv, g, s, hd).transpose(0, 3, 1, 2, 4) \

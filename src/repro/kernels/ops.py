@@ -218,7 +218,8 @@ def _flash(q, k, v, q_per_kv, causal, window, q_block, kv_block,
 
 
 def flash(q, k, v, q_per_kv: int, causal: bool = True, window: int = 0,
-          q_block: int = 128, kv_block: int = 128):
-    """Flash attention (GQA) kernel."""
+          q_block: int | None = None, kv_block: int | None = None):
+    """Flash attention (GQA) kernel; blocks of None follow from the
+    shape (``flash_attention.tiles``)."""
     return _flash(q, k, v, q_per_kv, causal, window, q_block, kv_block,
                   interpret())

@@ -2,7 +2,8 @@
 
 Each case lowers one Pallas kernel at the FLUX.1-dev serving widths
 (1024 px: 4096 image tokens, d=3072; 4608 joint tokens with the 512
-text tokens; 24 heads of 128) and compiles it for one chip of a
+text tokens; 24 heads of 128), or flash at DiT-XL/2's (1024 tokens, 16
+heads of 72), and compiles it for one chip of a
 described ``v5e:2x2`` topology — no chip attached.  The compiler then
 refuses what interpret mode cannot see: blocks not aligned to the
 tiling, and more VMEM than a kernel may use.
@@ -75,6 +76,15 @@ def test_noncausal_flash_compiles_at_flux_width(one_chip, batch, seq):
     fn = functools.partial(fa.flash_attention, q_per_kv=1, causal=False,
                            interpret=False)
     shape = ((batch, seq, HEADS, HD), jnp.bfloat16)
+    _compile(fn, one_chip, shape, shape, shape)
+
+
+def test_noncausal_flash_compiles_at_dit_xl2_width(one_chip):
+    """DiT-XL/2 at 512 px as served: B=8, S=1024, 16 heads of 72 (not a
+    whole 128 lanes), bf16, at the default tiles."""
+    fn = functools.partial(fa.flash_attention, q_per_kv=1, causal=False,
+                           interpret=False)
+    shape = ((8, 1024, 16, 72), jnp.bfloat16)
     _compile(fn, one_chip, shape, shape, shape)
 
 
