@@ -8,9 +8,36 @@ metric: a new one is a new file.
   bench/traffic/<traffic>.json      load parameters (see loadgen.py)
   bench/limits/<cell>.json          the limits ``correct`` is held to
   bench/metrics/<metric>.py         ``read(run)`` for each metric
-  bench/programs/<family>.py        builds the program's denoiser
+  bench/programs/<family>.py        the program's side of a family
   bench/references/<family>.py      the plain reference of the family
   bench/peaks.json                  chip peaks by ``device_kind``
+
+A family is the ``family`` of a configuration file.  Its program file
+defines:
+
+  denoiser(model, name)             (full_fn, from_crf_fn)
+  weights(model, name, seed, device)  the weights, on the device
+  policy(spec)                      the cache policy of a traffic file
+  request(cell, arrival, lat)       the served ``DiffusionRequest`` of an
+                                    arrival, its conditioning drawn from
+                                    a fold of the arrival's seed
+  engine(cell, full_fn, from_crf_fn, params, lat, crf, pol)
+                                    the ``DiffusionEngine``
+  attention_tokens(model, s)        tokens an attention call runs over,
+                                    for ``s`` image tokens
+  flash_calls(model)                flash calls of one full lane-step
+  forward_flops(model, s)           operations of one forward of one image
+
+and its reference file:
+
+  make_weights(model, seed)         the reference's own weights
+  Reference(model, policy, n_steps, lat, quant=None)
+                                    ``.sample(weights, **inputs)`` ->
+                                    (latents, number of full steps)
+  inputs(ref, cell, arrival)        the keyword inputs of ``sample`` for
+                                    an arrival, drawn as ``request``
+                                    draws them
+  rel_err(got, want)                the compared relative error
 """
 from __future__ import annotations
 

@@ -3,8 +3,10 @@
 After the window closes and the program's state is off the device, a
 sample of the requests the window completed, drawn from the seed, is
 served again by the plain float32 reference of the configuration's
-family, one image at a time, from the same request seeds, edit
-references and weight seed.  Compared, each with its limit from
+family, one image at a time, from the weight seed and each request's
+inputs as the family's reference file draws them from the arrival
+(``inputs``: the same seeds, edit references and conditioning the
+program file's ``request`` served).  Compared, each with its limit from
 ``bench/limits/<cell>.json``:
 
   latent_rel_err_max  largest ||x − x_ref|| / ||x_ref|| of the final
@@ -58,10 +60,7 @@ def compare(run) -> Dict[str, dict]:
                                    loadgen.fold(run.seed, "weights"))
     errs, off = [], 0
     for a in sample(run, cell.limits["check_requests"]):
-        x0 = ref.x_init(a.seed,
-                        loadgen.edit_reference(a, lat) if a.edit else None,
-                        cell.traffic.get("edit_strength", 0.0))
-        x, n_full = ref.sample(weights, x0)
+        x, n_full = ref.sample(weights, **ref_mod.inputs(ref, cell, a))
         errs.append(ref_mod.rel_err(run.latents[a.index], x))
         off += int(a.result.n_full_steps != n_full)
     bad = sum(int(not np.isfinite(x).all()) for x in run.latents.values())

@@ -47,10 +47,7 @@ def control_run(cell, seed: int, seconds: float) -> serve.Run:
             a.result = Served(bucket=1, n_full_steps=None)
     weights = ref_mod.make_weights(cell.model, loadgen.fold(seed, "weights"))
     for a in check.sample(run, cell.limits["check_requests"]):
-        x0 = ctl.x_init(a.seed,
-                        loadgen.edit_reference(a, lat) if a.edit else None,
-                        cell.traffic.get("edit_strength", 0.0))
-        x, n_full = ctl.sample(weights, x0)
+        x, n_full = ctl.sample(weights, **ref_mod.inputs(ctl, cell, a))
         run.latents[a.index] = np.asarray(x)
         a.result = Served(bucket=1, n_full_steps=n_full)
     del weights

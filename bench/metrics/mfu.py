@@ -1,9 +1,11 @@
 """Model FLOP utilisation of the whole step, in %: the useful operations
 of every image completed in the traced window, over the window's length
 times the chips times the bf16 peak.  Useful means real lanes only: each
-image's full steps at the analytic cost of a forward (plus the band split
-that fills a FreqCa cache) and its cached steps at the analytic cost of
-the reconstruction and final layer; padded lanes count nothing."""
+image's full steps at the analytic cost of a forward by its family's
+count (``forward_flops`` of ``bench/programs/<family>.py``) plus the band
+split that fills a FreqCa cache, and its cached steps at the analytic
+cost of the reconstruction and final layer; padded lanes count
+nothing."""
 from bench import work
 
 
@@ -12,7 +14,8 @@ def read(run):
         return None
     model, policy = run.cell.model, run.cell.policy
     n_steps = run.cell.engine["n_steps"]
-    flops = sum(work.image_flops(model, run.tokens, policy,
+    forward = run.program.forward_flops(model, run.tokens)
+    flops = sum(work.image_flops(forward, model, run.tokens, policy,
                                  a.result.n_full_steps, n_steps)
                 for a in run.completed())
     if not flops:

@@ -27,6 +27,9 @@ configuration describes:
   layer on the sum.
 * sampling: times ``linspace(1, 0, n_steps + 1)``, ``x += (t' − t)·v``
   from seeded unit noise, or for an edit from ``(1 − s)·ref + s·noise``.
+* inputs: ``inputs`` gives, from an arrival, every input of
+  ``Reference.sample`` but the weights, drawn from the arrival's seed as
+  the program file's ``request`` draws them.
 
 Every matrix product runs at ``Precision.HIGHEST`` on float32 copies of
 the weights.  ``quant="fp8"`` instead rounds both operands of every
@@ -41,6 +44,8 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import loadgen
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -333,6 +338,14 @@ class Reference:
                 v = self._cached(weights, feat, t)
             x = x + (ts[i + 1] - ts[i]) * v
         return x, sum(self.schedule)
+
+
+def inputs(ref: Reference, cell, a: loadgen.Arrival) -> dict:
+    """The keyword inputs of ``ref.sample`` for arrival ``a``: its start
+    latents, from the edit reference where ``a`` is an edit."""
+    edit = loadgen.edit_reference(a, ref.lat_shape) if a.edit else None
+    return {"x": ref.x_init(a.seed, edit,
+                            cell.traffic.get("edit_strength", 0.0))}
 
 
 def rel_err(got, want) -> float:

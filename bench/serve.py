@@ -15,6 +15,7 @@ so the trace holds whole batches only.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import glob
 import shutil
@@ -71,8 +72,15 @@ class Run:
 
     @property
     def tokens(self) -> int:
+        """Image tokens of one request: the length of its cached feature."""
         from bench import work
         return work.image_tokens(self.cell.model, self.cell.traffic["image_px"])
+
+    @functools.cached_property
+    def program(self):
+        """The family's program file, ``bench/programs/<family>.py``."""
+        from bench import cell as cell_lib
+        return cell_lib.program(self.cell.family)
 
     def completed(self) -> List[loadgen.Arrival]:
         return [a for a in self.plan if a.result is not None]
@@ -94,7 +102,7 @@ class Run:
                 <= self.close_s]
 
 
-def _warm_plan(cell, engine, bucket: int, lat: tuple):
+def _warm_plan(prog, cell, bucket: int, lat: tuple):
     """A real batch of ``bucket`` requests, one an edit where the traffic
     has edits, for the warm-up."""
     from repro.serving.scheduler import BatchPlan
@@ -104,18 +112,8 @@ def _warm_plan(cell, engine, bucket: int, lat: tuple):
                             seed=loadgen.fold(i, "warmup"),
                             edit=bool(cell.traffic.get("edit_every"))
                             and i == bucket - 1)
-        reqs.append(_request(cell, a, lat))
+        reqs.append(prog.request(cell, a, lat))
     return BatchPlan(requests=reqs, bucket=bucket, formed_at=time.monotonic())
-
-
-def _request(cell, a: loadgen.Arrival, lat: tuple):
-    from repro.serving.scheduler import DiffusionRequest
-    if a.edit:
-        return DiffusionRequest(
-            request_id=a.index, seed=a.seed,
-            init_latents=loadgen.edit_reference(a, lat),
-            edit_strength=cell.traffic["edit_strength"])
-    return DiffusionRequest(request_id=a.index, seed=a.seed)
 
 
 def warm_buckets(cell, buckets: List[int]) -> List[int]:
@@ -130,19 +128,16 @@ def serve(cell, seed: int, seconds: float, traced: bool, device,
     import jax
     import jax.numpy as jnp
     from repro.serving.async_engine import AsyncDiffusionEngine
-    from repro.serving.engine import DiffusionEngine
-    from bench import cell as cell_lib
-
-    prog = cell_lib.program(cell.family)
     run = Run(cell=cell, seed=seed, seconds=seconds, peak=peak,
               chips=cell.chips)
+    prog = run.program
 
     def mark(part: str) -> None:
         run.setup_marks[part] = time.perf_counter() - t_start
 
     mark("devices")
     tally = CompileTally()
-    model, eng_cfg = cell.model, cell.engine
+    model = cell.model
     lat = loadgen.latent_shape(cell.traffic, model["in_channels"])
     crf = (run.tokens, model["d_model"])
     full_fn, from_crf_fn = prog.denoiser(model, cell.config["name"])
@@ -150,17 +145,14 @@ def serve(cell, seed: int, seconds: float, traced: bool, device,
                           loadgen.fold(seed, "weights"), device)
     jax.block_until_ready(params)
     mark("weights")
-    engine = DiffusionEngine(full_fn, from_crf_fn, params, lat, crf,
-                             prog.policy(cell.policy),
-                             n_steps=eng_cfg["n_steps"],
-                             max_batch=eng_cfg["max_batch"],
-                             max_wait_s=eng_cfg["max_wait_s"])
+    engine = prog.engine(cell, full_fn, from_crf_fn, params, lat, crf,
+                         prog.policy(cell.policy))
     # warm-up: one real batch per bucket the traffic cuts, through the
     # same execute_plan the window drives; then the per-lane reads of a
     # batch's counts, whose shapes follow the number of real lanes
     buckets = warm_buckets(cell, engine.buckets)
     for b in buckets:
-        engine.execute_plan(_warm_plan(cell, engine, b, lat))
+        engine.execute_plan(_warm_plan(prog, cell, b, lat))
         mark(f"warm{b}")
         lanes = jax.device_put(jnp.zeros((b,), jnp.int32), device)
         for n in range(1, b + 1):
@@ -173,7 +165,7 @@ def serve(cell, seed: int, seconds: float, traced: bool, device,
 
     def submit(a: loadgen.Arrival):
         with jax.profiler.TraceAnnotation("bench.submit"):
-            return aeng.submit(_request(cell, a, lat))
+            return aeng.submit(prog.request(cell, a, lat))
 
     loop = loadgen.OpenLoop(run.plan, submit)
     if traced:

@@ -84,8 +84,9 @@ def _time_flops(model: dict) -> float:
 
 
 def forward_flops(model: dict, s: int) -> float:
-    """One denoiser forward of one image over ``s`` image tokens through
-    the single-stream blocks (the served path carries no text, so the
+    """The ``dit`` family's count (``bench/programs/dit.py``): one
+    denoiser forward of one image over ``s`` image tokens through the
+    single-stream blocks (its served path carries no text, so the
     dual-stream blocks do not run): patch embedding, time embedding,
     per block the 6-way modulation, Q/K/V/O and MLP projections and
     attention, then the final layer."""
@@ -103,10 +104,12 @@ def cache_k(policy: dict) -> int:
     return policy.get("high_order", 2) + 1
 
 
-def full_step_flops(model: dict, s: int, policy: dict) -> float:
-    """A full step: the forward, and for FreqCa the band split that
-    fills the cache."""
-    flops = forward_flops(model, s)
+def full_step_flops(forward: float, model: dict, s: int,
+                    policy: dict) -> float:
+    """A full step: ``forward``, the operations of one forward by the
+    family's count, and for FreqCa the band split that fills the cache
+    over the ``s`` image tokens."""
+    flops = forward
     if policy["name"] == "freqca":
         m = kept_bins(s, policy["rho"])
         flops += band_split(1, s, model["d_model"], m, model["dtype"]).flops
@@ -122,11 +125,11 @@ def cached_step_flops(model: dict, s: int, policy: dict) -> float:
             + _time_flops(model) + _final_layer_flops(model, s))
 
 
-def image_flops(model: dict, s: int, policy: dict, n_full: int,
-                n_steps: int) -> float:
+def image_flops(forward: float, model: dict, s: int, policy: dict,
+                n_full: int, n_steps: int) -> float:
     """Useful operations of one image whose schedule ran ``n_full`` full
-    steps of ``n_steps``."""
-    flops = n_full * full_step_flops(model, s, policy)
+    steps of ``n_steps``, a forward costing ``forward``."""
+    flops = n_full * full_step_flops(forward, model, s, policy)
     if n_steps > n_full:
         flops += (n_steps - n_full) * cached_step_flops(model, s, policy)
     return flops

@@ -103,3 +103,28 @@ def test_a_peak_table_entry_names_its_source():
     for kind, p in cell_lib.peaks().items():
         assert p["bf16_flops_per_s"] > 0 and p["hbm_bytes_per_s"] > 0
         assert p["source"]
+
+
+# each saturating cell's knee on a TPU v5e, the full-bucket throughput
+# bench/tools/sweep.py measured (images/s; PERF.md §4)
+KNEES = {"flux1-dev-cut.freqca-1024.sat": 1.369,
+         "dit-xl2-512.none.sat": 2.402}
+
+
+@pytest.mark.parametrize("name", sorted(KNEES))
+def test_a_saturating_mix_offers_a_full_bucket_every_batch(name):
+    """At 1.5x the knee with a backlog of three buckets, every batch the
+    chip starts by the window's close finds ``max_batch`` requests due,
+    served a full bucket per ``max_batch / knee`` seconds from the open:
+    every cut is the one bucket warmed."""
+    cell = cell_lib.load(name, False)
+    traffic, mb = cell.traffic, cell.engine["max_batch"]
+    assert traffic["backlog"] >= 3 * mb
+    assert traffic["rate_per_s"] == pytest.approx(1.5 * KNEES[name],
+                                                  rel=0.01)
+    batch_s = mb / KNEES[name]
+    due = np.array([a.due_s for a in loadgen.make_plan(
+        traffic, BIG, BENCH["run_seconds"])])
+    starts = np.arange(0.0, BENCH["run_seconds"] + batch_s, batch_s)
+    for k, t in enumerate(starts):
+        assert np.sum(due <= t) - k * mb >= mb, (t, k)

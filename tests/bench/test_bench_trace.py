@@ -55,7 +55,8 @@ def test_flash_roofline_of_the_chip_slice(chip_slice):
     cell = cell_lib.load("flux1-dev-cut.freqca-1024.sat", True)
     run = types.SimpleNamespace(cell=cell, trace=chip_slice, tokens=4096,
                                 full_lane_steps=1, total_lane_steps=1,
-                                peak=cell_lib.peaks()["TPU v5 lite"])
+                                peak=cell_lib.peaks()["TPU v5 lite"],
+                                program=cell_lib.program(cell.family))
     got = cell_lib.reader("flash_roofline").read(run)
     least = work.flash(8, 4096, 24, 128, "bfloat16").flops / 197e12
     assert got["bound"] == "compute" and got["calls"] == 2

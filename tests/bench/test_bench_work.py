@@ -49,10 +49,11 @@ def test_roofline_names_its_bound(flops, nbytes, bound):
 def test_cached_step_is_a_sliver_of_a_full_step():
     m = model("flux1-dev-cut")
     pol = bench_tiny.FREQCA
-    full = work.full_step_flops(m, 4096, pol)
+    forward = work.forward_flops(m, 4096)
+    full = work.full_step_flops(forward, m, 4096, pol)
     cached = work.cached_step_flops(m, 4096, pol)
     assert 0 < cached < 0.01 * full
-    img = work.image_flops(m, 4096, pol, n_full=12, n_steps=50)
+    img = work.image_flops(forward, m, 4096, pol, n_full=12, n_steps=50)
     assert img == pytest.approx(12 * full + 38 * cached)
 
 
