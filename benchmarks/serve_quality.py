@@ -71,11 +71,11 @@ def _stiff_fns(cfg, n_steps):
     full_fn, from_crf_fn = dit.denoiser(cfg)
     freq = 0.5 * n_steps          # ~0.5 rad per step at any n_steps
 
-    def stiff_full(params, x, t):
-        _, crf = full_fn(params, x, jnp.full((), 0.5))
+    def stiff_full(params, x, t, cond=()):
+        _, crf = full_fn(params, x, jnp.full((), 0.5), cond)
         # amplitude decays with t^2: early trajectory stiff, tail calm
         crf = crf * (1.0 + AMP * t * t * jnp.sin(freq * t))
-        return from_crf_fn(params, crf, t), crf
+        return from_crf_fn(params, crf, t, cond), crf
 
     return stiff_full, from_crf_fn
 

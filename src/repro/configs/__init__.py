@@ -8,14 +8,15 @@ from repro.configs import base
 from repro.configs.base import DiTConfig, ModelConfig, MoEConfig, SSMConfig
 
 from repro.configs import (command_r_plus_104b, deepseek_coder_33b, dit_small,
-                           flux1_dev, granite_moe_3b, jamba_15_large,
+                           flux1_dev, flux1_kontext_dev, granite_moe_3b,
+                           jamba_15_large,
                            llama3_405b, llava_next_34b, mamba2_370m,
                            phi35_moe_42b, seamless_m4t_medium, yi_9b)
 
 _MODULES = [mamba2_370m, deepseek_coder_33b, seamless_m4t_medium,
             phi35_moe_42b, granite_moe_3b, llama3_405b, yi_9b,
             jamba_15_large, command_r_plus_104b, llava_next_34b,
-            dit_small, flux1_dev]
+            dit_small, flux1_dev, flux1_kontext_dev]
 
 REGISTRY: Dict[str, Union[ModelConfig, DiTConfig]] = {
     m.CONFIG.arch_id: m.CONFIG for m in _MODULES
@@ -78,7 +79,9 @@ def reduced(cfg):
         return dataclasses.replace(
             cfg, n_layers=2, n_double=min(cfg.n_double, 1), d_model=64,
             n_heads=4, d_ff=128, text_dim=min(cfg.text_dim, 32),
-            n_text_tokens=min(cfg.n_text_tokens, 8), dtype="float32")
+            n_text_tokens=min(cfg.n_text_tokens, 8),
+            vec_in_dim=min(cfg.vec_in_dim, 16),
+            rope_axes=(4, 6, 6) if cfg.rope_axes else (), dtype="float32")
     n_layers = 2 if cfg.family != "hybrid" else cfg.attn_every
     d_model = 128
     head_dim = 32

@@ -132,6 +132,17 @@ class DiTConfig:
     norm_eps: float = 1e-6
     dtype: str = "float32"
     source: str = ""
+    # FLUX's conditioning vector: ``vec`` is the time embedding plus, when
+    # set, an MLP embedding of a pooled text vector of ``vec_in_dim``
+    # (CLIP, 768) and of the guidance scale (guidance-distilled models).
+    # 0 / False: the time embedding alone, as a plain DiT.
+    vec_in_dim: int = 0
+    guidance_embed: bool = False
+    # FLUX's ``EmbedND``: per-axis RoPE widths over (index, row, column)
+    # token ids, summing to the head size, applied to q and k.  Empty:
+    # 1-D sincos positions added to the image tokens.
+    rope_axes: Tuple[int, ...] = ()
+    rope_theta: float = 10000.0
 
     @property
     def head_dim(self) -> int:

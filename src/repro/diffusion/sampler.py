@@ -18,12 +18,15 @@ Each step the bank's ``decide`` returns a per-lane activation mask:
   a mixed generation+editing batch never shares one global activation
   decision.  A lane behaves exactly as it would alone in the batch.
 
-The denoiser is abstract: ``full_fn(params, x, t) -> (velocity, crf)``
-and ``from_crf_fn(params, crf, t) -> velocity``; both DiT and
-backbone-wrapped assigned architectures plug in (repro.models.dit).
+The denoiser is abstract: ``full_fn(params, x, t, cond) -> (velocity,
+crf)`` and ``from_crf_fn(params, crf, t, cond) -> velocity``; both DiT
+and backbone-wrapped assigned architectures plug in (repro.models.dit).
 The weights travel as the ``params`` argument, never as closure
 constants, so a jitted sampler takes them as inputs and its executable
-embeds none of them.
+embeds none of them.  ``cond`` is the batch's conditioning pytree (text
+tokens, pooled vector, guidance, reference latents: ``dit.cond_kwargs``),
+the same at every step and handed to both the full and the cached step;
+``()`` for an unconditioned model, which adds no input to the program.
 """
 from __future__ import annotations
 
@@ -58,13 +61,14 @@ class SampleResult(NamedTuple):
 def sample(full_fn: Callable, from_crf_fn: Callable, params,
            x_init: jnp.ndarray, ts: jnp.ndarray, policy: PolicyArg,
            crf_shape: Tuple[int, ...], crf_dtype=jnp.float32,
-           return_trajectory: bool = False) -> SampleResult:
+           return_trajectory: bool = False, cond=()) -> SampleResult:
     """Euler rectified-flow sampling from t=1 to t=0 under a cache policy.
 
     ts: [n_steps+1] decreasing times.  crf_shape: [B, *feat] shape of the
     CRF feature (needed to build the static cache state).  ``policy``
     may be a Policy object, a CachePolicy spec, or a per-lane sequence
-    of them (len == batch) for mixed-policy batches.
+    of them (len == batch) for mixed-policy batches.  ``cond``: the
+    batch's conditioning pytree, passed to every denoiser call.
     """
     n_steps = ts.shape[0] - 1
     batch = x_init.shape[0]
@@ -85,7 +89,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
         @jax.named_scope(FULL_STEP)
         def full_branch(op):
             x_, state_ = op
-            v_full, crf = full_fn(params, x_, t_now)
+            v_full, crf = full_fn(params, x_, t_now, cond)
             if bank.uses_error_feedback:
                 # score the prediction the cache WOULD have served for
                 # this step (pre-update state) against the fresh CRF,
@@ -102,7 +106,8 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
             # lanes that did not activate keep their own schedule: they
             # consume the cached prediction even though the batch paid
             # for a forward (quality decoupling across lanes)
-            v_hat = from_crf_fn(params, bank.predict(state_, ctx), t_now)
+            v_hat = from_crf_fn(params, bank.predict(state_, ctx), t_now,
+                                cond)
             m = mask.reshape((batch,) + (1,) * (v_full.ndim - 1))
             v = jnp.where(m, v_full, v_hat.astype(v_full.dtype))
             return v.astype(x_.dtype), state_
@@ -110,7 +115,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
         @jax.named_scope(CACHED_STEP)
         def cached_branch(op):
             x_, state_ = op
-            v = from_crf_fn(params, bank.predict(state_, ctx), t_now)
+            v = from_crf_fn(params, bank.predict(state_, ctx), t_now, cond)
             return v.astype(x_.dtype), state_
 
         if bank.always_full:
@@ -138,14 +143,14 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
 
 
 def reference_features(full_fn: Callable, params, x_init: jnp.ndarray,
-                       ts: jnp.ndarray):
+                       ts: jnp.ndarray, cond=()):
     """Run the un-cached sampler, returning per-step (x, crf) trajectories.
 
     Used by the Fig-2 frequency analysis and Fig-4 MSE benchmarks.
     """
     def step(x, tt):
         t_now, t_next = tt
-        v, crf = full_fn(params, x, t_now)
+        v, crf = full_fn(params, x, t_now, cond)
         x_next = x + (t_next - t_now).astype(x.dtype) * v.astype(x.dtype)
         return x_next, (x_next, crf)
 

@@ -43,6 +43,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.analysis import runtime as sanitize
@@ -73,6 +74,9 @@ class DiffusionResult(NamedTuple):
     # the engine's batch counter: lanes of one batch share it, and so do
     # the batch's ``serving.*`` profiler spans
     batch: int = 0
+    # host seconds the batch spent stacking and placing its lanes'
+    # conditioning (the ``serving.build_cond`` span; 0 for none)
+    cond_host_s: float = 0.0
 
 
 class DiffusionEngine:
@@ -126,16 +130,17 @@ class DiffusionEngine:
         self.next_batch = 0
         self._ts = schedule.timesteps(n_steps)
 
-        def run(params, x_init, lane_policies, crf_feat):
+        def run(params, x_init, lane_policies, crf_feat, cond=()):
             # batch size, the per-lane policy signature, and the
             # per-sample CRF shape are static at trace time -> one
             # executable per (shape, group, bucket) triple, cached for
-            # the process lifetime; the weights are its first input
+            # the process lifetime; the weights are its first input and
+            # the batch's conditioning (``()`` for none) its last
             batch = x_init.shape[0]
             res = sampler_lib.sample(
                 self.full_fn, self.from_crf_fn, params, x_init, self._ts,
                 lane_policies, crf_shape=(batch,) + tuple(crf_feat),
-                crf_dtype=self.crf_dtype)
+                crf_dtype=self.crf_dtype, cond=cond)
             # feedback is None (an empty pytree) unless some lane's
             # policy consumes error observations, so non-SLO signatures
             # stay byte-identical programs
@@ -220,7 +225,7 @@ class DiffusionEngine:
     def warmup(self, buckets: Optional[Sequence[int]] = None,
                lane_policy_sets: Sequence[Sequence[object]] = (),
                policies: Sequence[object] = (),
-               shapes: Sequence = ()) -> float:
+               shapes: Sequence = (), cond=()) -> float:
         """Precompile sampler executables for every bucket signature on
         the default policy, plus any extra per-lane policy signatures
         (``lane_policy_sets``: each entry is a full per-lane assignment
@@ -243,6 +248,10 @@ class DiffusionEngine:
         is exactly ``shapes x groups x buckets``
         (``signature_budget``), and a mixed-resolution stream then
         serves with zero steady-state recompiles.
+
+        ``cond`` — one lane's conditioning as the served requests carry
+        it (arrays or ``jax.ShapeDtypeStruct``s): each signature is
+        warmed with zeros of that structure at the bucket's batch.
 
         Returns wall seconds spent.  After warmup, serving any mix of
         batch sizes — and any warmed policy mix, at any declared shape
@@ -268,8 +277,10 @@ class DiffusionEngine:
         for lat, crf in self.shapes:
             for b, sig in sigs:
                 x = self._place(jnp.zeros((b,) + lat))
+                c = jax.tree.map(lambda a, b=b: self._place(
+                    jnp.zeros((b,) + tuple(a.shape), a.dtype)), cond)
                 cache_before = self.compiled_buckets()
-                out = self._jit_run(self.params, x, sig, crf)[0]
+                out = self._jit_run(self.params, x, sig, crf, c)[0]
                 out.block_until_ready()
                 self.metrics.observe_compile(
                     hit=self.compiled_buckets() == cache_before)
@@ -300,6 +311,16 @@ class DiffusionEngine:
         lanes += [jnp.zeros(lat)] * (plan.bucket - plan.n_real)
         return jnp.stack(lanes)
 
+    @staticmethod
+    def build_cond(plan: BatchPlan):
+        """The batch's conditioning on the host: each leaf of the lanes'
+        ``cond`` pytrees stacked to [bucket, ...], padded lanes copying
+        the first lane's (cuts are pure in the conditioning's
+        structure and shapes); ``()`` where the requests carry none."""
+        lanes = [r.cond for r in plan.requests]
+        lanes += lanes[:1] * (plan.bucket - plan.n_real)
+        return jax.tree.map(lambda *xs: np.stack(xs), *lanes)
+
     def _place(self, x: jnp.ndarray) -> jnp.ndarray:
         if self.mesh is None:
             return jax.device_put(x)
@@ -316,7 +337,8 @@ class DiffusionEngine:
 
         Each phase is a profiler span carrying the batch id (see
         ``AsyncDiffusionEngine`` for the worker's spans around it):
-        ``serving.build_x_init``, ``serving.dispatch`` (asynchronous),
+        ``serving.build_x_init``, ``serving.build_cond`` (conditioned
+        requests only), ``serving.dispatch`` (asynchronous),
         ``serving.sync`` (the device finishing the batch) and
         ``serving.results``."""
         batch = self.next_batch
@@ -327,6 +349,14 @@ class DiffusionEngine:
         with TraceAnnotation("serving.build_x_init", batch=batch,
                              requests=ids):
             x_init = self._place(self.build_x_init(plan))
+        cond, cond_s = (), 0.0
+        if jax.tree.leaves(plan.requests[0].cond):
+            t_cond = time.perf_counter()
+            with TraceAnnotation("serving.build_cond", batch=batch):
+                cond = jax.tree.map(self._place, self.build_cond(plan))
+            cond_s = time.perf_counter() - t_cond
+            self.metrics.observe_cond_bytes(
+                sum(a.nbytes for a in jax.tree.leaves(cond)))
         sig = self._normalize_signature(plan.lane_policies(self.policy))
         crf = (tuple(plan.crf_shape) if plan.crf_shape is not None
                else self.crf_shape)
@@ -343,7 +373,7 @@ class DiffusionEngine:
         with TraceAnnotation("serving.dispatch", batch=batch,
                              bucket=plan.bucket):
             x, n_forwards, lane_full, feedback = self._jit_run(params, x_init,
-                                                               sig, crf)
+                                                               sig, crf, cond)
         with TraceAnnotation("serving.sync", batch=batch):
             x.block_until_ready()
         wall = time.perf_counter() - t0
@@ -378,7 +408,8 @@ class DiffusionEngine:
                 out.append(DiffusionResult(r.request_id, x[i], lane_full[i],
                                            wall, wait, plan.bucket,
                                            realized_error=err,
-                                           budget_events=ev, batch=batch))
+                                           budget_events=ev, batch=batch,
+                                           cond_host_s=cond_s))
             return out
 
     # backwards-compatible alias (pre-async name)

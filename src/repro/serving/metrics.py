@@ -55,7 +55,8 @@ def _metrics_lock() -> threading.Lock:
 # (min / max / sum-of-present)
 _COUNTER_FIELDS = ("compile_hits", "compile_misses", "full_steps",
                    "total_steps", "forwards", "budget_events_total",
-                   "shed_events", "duplicate_results", "stale_pong_kills")
+                   "shed_events", "duplicate_results", "stale_pong_kills",
+                   "cond_bytes")
 _MAX_FIELDS = ("max_queue_depth", "max_lane_full_spread")
 _LIST_FIELDS = ("batch_walls", "batch_buckets", "batch_occupancy",
                 "request_waits", "request_latencies", "request_full_steps",
@@ -103,6 +104,9 @@ class ServeMetrics:
     # batch forwards (full steps of a batch, any lane activating) summed
     # over batches
     forwards: int = 0
+    # bytes of conditioning (text, pooled vector, guidance, reference
+    # latents) the engine placed on the device, padded lanes included
+    cond_bytes: int = 0
     # request-level observations
     request_waits: List[float] = dataclasses.field(default_factory=list)
     request_latencies: List[float] = dataclasses.field(default_factory=list)
@@ -189,6 +193,11 @@ class ServeMetrics:
         """Record the scheduler's cumulative shed counter (latest wins)."""
         with self._lock:
             self.shed_events = int(n)
+
+    def observe_cond_bytes(self, nbytes: int) -> None:
+        """A batch's conditioning was placed on the device."""
+        with self._lock:
+            self.cond_bytes += int(nbytes)
 
     def observe_duplicate_result(self) -> None:
         """An already-resolved future was resolved again (requeue race

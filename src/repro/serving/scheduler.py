@@ -57,6 +57,9 @@ import dataclasses
 import time
 from typing import List, NamedTuple, Optional, Tuple
 
+import jax
+import numpy as np
+
 from repro.analysis.runtime import make_condition
 
 
@@ -99,6 +102,17 @@ def resolve_shape_key(latent_shape, crf_shape,
         lat = lat if lat is not None else d[0]
         crf = crf if crf is not None else d[1]
     return (lat, crf)
+
+
+def cond_signature(cond) -> tuple:
+    """Structure, shapes and dtypes of a request's conditioning pytree;
+    () for none.  Lanes of one cut must agree on it: the engine stacks
+    their conditioning into one input of the executable."""
+    leaves, tree = jax.tree.flatten(cond)
+    if not leaves:
+        return ()
+    return (str(tree),) + tuple((tuple(np.shape(x)), np.result_type(x).name)
+                                for x in leaves)
 
 
 def validate_request_shape(req, default_shape: Optional[ShapeKey],
@@ -144,6 +158,13 @@ class DiffusionRequest:
     # optional conditioning (e.g. reference latents for editing)
     init_latents: Optional[object] = None
     edit_strength: float = 0.0
+    # a conditioned model's inputs, one lane's worth: a pytree of arrays
+    # (FLUX.1-Kontext: ``txt`` [T, text_dim], ``vec`` [vec_in_dim],
+    # ``guidance`` [], ``ref_latents`` [H, W, C]; ``dit.cond_kwargs``).
+    # The engine stacks the lanes' and hands the batch to both steps of
+    # the denoiser; lanes of one cut share its structure and shapes.
+    # () -> none.
+    cond: object = ()
     # per-request cache policy (CachePolicy spec or Policy object);
     # None -> the engine's default.  Requests with different policies
     # share a batch lane-by-lane (per-lane activation masks).
@@ -396,12 +417,18 @@ class Scheduler:
         The shape half ALWAYS folds in — mixed-shape lanes cannot share
         one executable (``jnp.stack`` would fail outright), so shape
         purity is a physical requirement of every former, grouped or
-        not.  The policy half folds in only under ``group_policies``
-        (the PR-5 ``compatibility_key()`` path).  A single-shape
-        ungrouped deployment collapses to one constant key — the
-        original whole-queue FIFO former, bit-identical.
+        not; a conditioned request's ``cond_signature`` joins it, so
+        lanes whose conditioning differs in shape are never cut
+        together.  The policy half folds in only under
+        ``group_policies`` (the ``compatibility_key()`` path).  A
+        single-shape ungrouped deployment collapses to one constant key
+        — the original whole-queue FIFO former, bit-identical.
         """
-        return (self.shape_of(req),
+        shape = self.shape_of(req)
+        sig = cond_signature(req.cond)
+        if sig:
+            shape = (shape or (None, None)) + (sig,)
+        return (shape,
                 self.group_key(req) if self.group_policies else None)
 
     def groups(self) -> dict:

@@ -191,12 +191,12 @@ def test_state_bytes_count_feedback_scalars():
 def _rough_fns(s=4, d=8, size=4, ch=2, amp=0.3, freq=8.0):
     """CRF oscillates fast in t, so Hermite forecasts err at a rate the
     budget can meter.  s*d must equal size*size*ch."""
-    def full_fn(params, x, t):
+    def full_fn(params, x, t, cond=()):
         crf = jnp.tanh(x.reshape(x.shape[0], s, d))
         crf = crf + amp * jnp.sin(freq * t)
         return crf.reshape(x.shape) * 0.1, crf
 
-    def from_crf_fn(params, crf, t):
+    def from_crf_fn(params, crf, t, cond=()):
         return crf.reshape(crf.shape[0], size, size, ch) * 0.1
 
     return full_fn, from_crf_fn

@@ -95,12 +95,12 @@ def test_backbone_denoiser_freqca():
     params = common.init_params(dit.backbone_denoiser_specs(cfg),
                                 jax.random.key(0))
 
-    def full_fn(params, x, t):
+    def full_fn(params, x, t, cond=()):
         tb = jnp.full((x.shape[0],), t)
         out = dit.backbone_denoiser_forward(params, x, tb, cfg)
         return out.velocity, out.crf
 
-    def from_crf_fn(params, crf, t):
+    def from_crf_fn(params, crf, t, cond=()):
         return dit.backbone_denoiser_from_crf(params, crf, cfg, 8, 8)
 
     x0 = jax.random.normal(jax.random.key(1), (2, 8, 8, 4))

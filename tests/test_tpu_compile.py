@@ -127,3 +127,40 @@ def test_step_scopes_leave_the_kernel_names(one_chip, monkeypatch):
     assert f"/{sampler.FULL_STEP}/" in kernels["_band_split_spectral_pallas"]
     assert f"/{sampler.CACHED_STEP}/" \
         in kernels["_freqca_predict_spectral_pallas"]
+
+
+def test_kontext_cut_full_step_compiles_with_flash_named(one_chip,
+                                                         monkeypatch):
+    """FLUX.1-Kontext-dev cut to 4 + 8 blocks at its published widths,
+    one full denoiser step of one lane with its conditioning (512 text
+    tokens, pooled vector, guidance, a 1024 px reference: 8704 joint
+    tokens): both block kinds call the flash kernel, named ``_flash``,
+    inside their ``dit.*_blocks`` scopes."""
+    import dataclasses
+    import re
+
+    from repro.configs import flux1_kontext_dev
+    from repro.models import dit
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "0")
+    cfg = dataclasses.replace(flux1_kontext_dev.CONFIG, n_double=4,
+                              n_layers=8)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: dit.random_params(cfg, 0)))
+    full_fn, _ = dit.denoiser(cfg)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cond = {"txt": arg((1, 512, 4096)), "vec": arg((1, 768)),
+            "guidance": arg((1,)), "ref_latents": arg((1, 128, 128, 16))}
+    text = jax.jit(full_fn).lower(params, arg((1, 128, 128, 16)),
+                                  arg(()), cond).compile().as_text()
+    scopes = [re.search(r'op_name="([^"]+)"', line).group(1)
+              for line in text.splitlines()
+              if "tpu_custom_call" in line
+              and re.match(r"\s*%?_flash(\.\d+)? = ", line)]
+    assert len(scopes) == 2
+    assert sum("dit.double_blocks" in s for s in scopes) == 1
+    assert sum("dit.single_blocks" in s for s in scopes) == 1
