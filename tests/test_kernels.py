@@ -225,6 +225,8 @@ def test_ops_band_split_spectral_backends_agree(monkeypatch):
     pytest.param(256, 2, 2, 128, (32, 128), id="256-2-2-hd128-q32-k128"),
     # the default tiles: (1024, 1024), one kv step
     pytest.param(1024, 2, 1, 72, (None, None), id="1024-2-1-hd72-tiles"),
+    # head-dim-major blocks of whole lanes (the tokens), two kv steps
+    pytest.param(256, 4, 2, 72, (128, 128), id="256-4-2-hd72-q128-k128"),
 ])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 24),
                                            (False, 0)])
@@ -283,6 +285,12 @@ def test_dit_joint_attention_flash_routing(monkeypatch):
                  id="bfloat16-hd128-tiles"),
     pytest.param(jnp.bfloat16, 1024, 72, False, (None, None),
                  id="bfloat16-hd72-tiles"),
+    # head-dim-major at (2048, 512): four kv steps
+    pytest.param(jnp.bfloat16, 2048, 72, False, (None, None),
+                 id="bfloat16-hd72-tiles-2048"),
+    pytest.param(jnp.bfloat16, 512, 64, False, (256, 128),
+                 id="bfloat16-hd64"),
+    pytest.param(jnp.float32, 512, 80, False, (256, 128), id="float32-hd80"),
 ])
 def test_flash_attention_dtypes(dtype, s, hd, causal, blocks):
     """f32 inputs meet the f32 oracle at 5e-5; bf16 inputs (the oracle
@@ -307,6 +315,11 @@ def test_flash_attention_dtypes(dtype, s, hd, causal, blocks):
         atol = 2 ** -7 * float(jnp.max(jnp.abs(v.astype(jnp.float32))))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref_out), atol=atol)
+    if hd % fa.LANES == 0:
+        # a head of whole lanes keeps the token-major kernel, bit for bit
+        qb, kb = fa.tiles(s, s, hd, dtype) if blocks[0] is None else blocks
+        same = fa._token_major(q, k, v, hq // hkv, causal, 0, qb, kb, True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(same))
 
 
 # the two served attention shapes: (S, head size, the tiles the caps

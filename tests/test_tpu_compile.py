@@ -88,6 +88,48 @@ def test_noncausal_flash_compiles_at_dit_xl2_width(one_chip):
     _compile(fn, one_chip, shape, shape, shape)
 
 
+@pytest.mark.parametrize("batch,seq,heads,hd", [
+    pytest.param(8, 1024, 16, 72, id="dit-xl2"),
+    pytest.param(4, S_IMG, HEADS, HD, id="flux"),
+])
+def test_single_block_feeds_flash_without_relayout(one_chip, monkeypatch,
+                                                   batch, seq, heads, hd):
+    """One single block as served, bf16 with the Pallas kernels: the
+    q/k/v projections reach the flash kernel, and its output the
+    out-projection, with no layout copy under ``jit(_flash)``.  At head
+    size 72 that holds because the kernel reads and writes the
+    head-dim-major layout XLA gives the projections."""
+    import re
+
+    from repro.configs.base import DiTConfig
+    from repro.models import dit
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "0")
+    d = heads * hd
+    cfg = DiTConfig(arch_id="block", n_layers=1, d_model=d, n_heads=heads,
+                    d_ff=4 * d, patch_size=2, in_channels=4, text_dim=0,
+                    n_text_tokens=0, dtype="bfloat16")
+    block = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape[1:], p.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: dit.random_params(cfg, 0))["single"])
+    x = jax.ShapeDtypeStruct((batch, seq, d), jnp.bfloat16, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((batch, d), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(functools.partial(dit.single_block, cfg=cfg)).lower(
+        block, x, c).compile().as_text()
+    kernels, copies = [], []
+    for line in text.splitlines():
+        name = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = ", line)
+        if name is None:
+            continue
+        if "tpu_custom_call" in line:
+            kernels.append(re.sub(r"\.\d+$", "", name.group(1)))
+        elif name.group(1).startswith("copy") and "jit(_flash)" in line:
+            copies.append(line.strip()[:160])
+    assert kernels == ["_flash"]
+    assert copies == []
+
+
 def test_step_scopes_leave_the_kernel_names(one_chip, monkeypatch):
     """The sampler's step scopes reach every kernel's op metadata and
     leave its instruction named after the jitted function that calls it,
