@@ -2,10 +2,8 @@
 FreqCa at 5x scheduled compute saving and compare with the uncached
 output.
 
-Cache policies are self-contained objects from the registry
-(``repro.core.policies``) — construct them directly and pass them to
-the sampler.  (The legacy ``CachePolicy(kind=...)`` spec still resolves
-but is deprecated.)
+Cache policies are self-contained objects from ``repro.core.policies``:
+construct them directly and pass them to the sampler.
 
   PYTHONPATH=src python examples/quickstart.py
 """
@@ -18,7 +16,7 @@ from repro.diffusion import sampler, schedule
 from repro.launch.train import train_dit
 from repro.models import dit
 
-print("registered cache policies:", ", ".join(policies.available()))
+print("cache policies:", ", ".join(policies.available()))
 
 cfg = config_lib.get_config("dit-small")
 params = train_dit(cfg, steps=120, batch=16, ckpt_dir="", size=32)

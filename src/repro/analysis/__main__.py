@@ -13,7 +13,7 @@ from pathlib import Path
 
 from repro.analysis.core import RULES, analyze_paths, summarize
 
-_CI_PATHS = ("src", "tests", "benchmarks")
+_CI_PATHS = ("src", "tests", "tools")
 
 
 def main(argv=None) -> int:
@@ -27,7 +27,7 @@ def main(argv=None) -> int:
              % " ".join(_CI_PATHS))
     parser.add_argument(
         "--ci", action="store_true",
-        help="CI mode: default paths to src/ tests/ benchmarks/ and "
+        help="CI mode: default paths to src/ tests/ tools/ and "
              "keep output terse")
     parser.add_argument(
         "--rules", action="store_true",

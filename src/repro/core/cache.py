@@ -1,16 +1,17 @@
-"""Legacy feature-cache API: the ``CachePolicy`` spec + function-style
-state machines.
+"""Reference feature-cache state machines: the tests' oracle for the
+policy objects in ``repro.core.policies``, not a user API.
 
-The sampler now drives self-contained policy *objects* registered in
-``repro.core.policies`` (per-lane activation masks, policy-owned
-adaptive state).  ``CachePolicy`` remains the user-facing spec — a thin
-compat shim whose ``.resolve()`` returns the registered policy object
-for its ``kind`` — and the function-style API below (``init_state`` /
-``should_activate`` / ``update`` / ``predict``) is kept for the
-layer-wise Table-5/Fig-4 ablations, the roofline step specs, and the
-golden-equivalence tests that pin the new objects against it.
+The served path drives policy objects (per-lane activation masks,
+policy-owned adaptive state).  The functions below are the plain
+spatial-cache reference the objects are pinned against: golden sampler
+equivalence, the layer-wise vs CRF ablation and cache-byte accounting.
+Each takes a policy object, branches on its class-level ``name`` and
+reads only the dataclass fields that kind has (``interval``,
+``method``, ``rho``, ``low_order``, ``high_order``, ``token_axis``,
+``tea_threshold``); it calls none of the object's methods or
+properties, so it stays independent of the code under test.
 
-Policies (``kind``):
+Kinds (``policy.name``):
   freqca      — paper: low band reused (order ``low_order``, default 0),
                 high band Hermite-predicted (order ``high_order``, default
                 2), bands split by ``method`` (fft | dct) at fraction
@@ -20,23 +21,14 @@ Policies (``kind``):
                 (no decomposition) == the paper's main forecast baseline.
   fora        — whole-feature reuse (order 0) == the paper's main reuse
                 baseline.
-  teacache    — TeaCache-style ADAPTIVE reuse: the sampler accumulates
-                the relative change of the model input x_t between
-                steps and triggers a full forward when it crosses
-                ``tea_threshold`` (the interval schedule is ignored);
-                prediction = reuse, like FORA.
-  foca        — forecast-then-calibrate (arXiv 2508.16211): in this
-                legacy API it degrades to the taylorseer forecast; the
-                registry object carries the per-lane calibration gain.
-  freqca_a    — beyond-paper ADAPTIVE FreqCa: at every activated step
-                the cache state already contains what FreqCa *would
-                have predicted* for that step — its relative error
-                against the freshly computed CRF is free to measure.
-                The sampler then budgets cached steps from it:
-                skip while (steps_since_full+1) · err_last <
-                ``tea_threshold``; bands/predictors identical to
-                freqca.  Unifies TeaCache's adaptivity with FreqCa's
-                frequency-split predictor.
+  teacache    — reuse like FORA; the activation rule (accumulated
+                relative change of x_t against ``tea_threshold``) lives
+                in the golden sampler of the tests.
+  foca        — forecast-then-calibrate: here the uncalibrated
+                taylorseer forecast (the object adds a per-lane gain).
+  freqca_a    — adaptive FreqCa: bands and predictors identical to
+                freqca; its error-budget rule lives in the golden
+                sampler of the tests.
   none        — never cache (ground truth / baseline latency).
 
 ``should_activate`` implements the paper's schedule: a full forward every
@@ -45,66 +37,25 @@ populated.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import frequency, hermite
+from repro.core.policies.base import Policy
+
+_REUSE = ("fora", "teacache")
+_FORECAST = ("taylorseer", "foca")
+_FREQCA = ("freqca", "freqca_a")
 
 
-@dataclasses.dataclass(frozen=True)
-class CachePolicy:
-    kind: str = "freqca"          # freqca | taylorseer | fora | none
-    interval: int = 5             # N: full forward every N steps
-    method: str = "dct"           # fft | dct | none (frequency transform)
-    rho: float = 0.0625           # low-frequency fraction of the spectrum
-    low_order: int = 0            # 0 = direct reuse (paper default)
-    high_order: int = 2           # Hermite order for the high band
-    token_axis: int = 1           # axis of [B, S, D] to transform over
-    tea_threshold: float = 0.15   # teacache / freqca_a error budget
-
-    @property
-    def k_low(self) -> int:
-        return self.low_order + 1
-
-    @property
-    def k_high(self) -> int:
-        return self.high_order + 1
-
-    @property
-    def cache_units(self) -> int:
-        """Number of feature-sized tensors held (paper §4.4.1)."""
-        if self.kind == "none":
-            return 0
-        if self.kind in ("fora", "teacache"):
-            return 1
-        if self.kind in ("taylorseer", "foca"):
-            return self.k_high
-        return self.k_low + self.k_high   # freqca / freqca_a
-
-    def resolve(self):
-        """Registered policy object for this spec (repro.core.policies).
-
-        .. deprecated:: construct the policy object directly
-           (``FreqCaPolicy(interval=5)``); the string-kind spec route
-           is kept only as a shim and warns once per process.
-        """
-        global _RESOLVE_WARNED
-        if not _RESOLVE_WARNED:
-            _RESOLVE_WARNED = True
-            import warnings
-            warnings.warn(
-                "CachePolicy.resolve() is deprecated; construct policy "
-                "objects from repro.core.policies directly "
-                "(e.g. FreqCaPolicy(interval=5))",
-                DeprecationWarning, stacklevel=2)
-        from repro.core.policies import registry
-        return registry.resolve(self)
+def _k_low(policy: Policy) -> int:
+    return policy.low_order + 1
 
 
-_RESOLVE_WARNED = False
+def _k_high(policy: Policy) -> int:
+    return policy.high_order + 1
 
 
 class CacheState(NamedTuple):
@@ -115,13 +66,14 @@ class CacheState(NamedTuple):
     n_valid: jnp.ndarray      # [] int32 — activated steps seen so far
 
 
-def init_state(policy: CachePolicy, feat_shape: Tuple[int, ...],
+def init_state(policy: Policy, feat_shape: Tuple[int, ...],
                dtype=jnp.float32) -> CacheState:
-    kl, kh = policy.k_low, policy.k_high
-    if policy.kind in ("fora", "teacache"):
+    if policy.name in _FREQCA:
+        kl, kh = _k_low(policy), _k_high(policy)
+    elif policy.name in _FORECAST:
+        kl, kh = 1, _k_high(policy)   # unused low slot kept tiny-but-static
+    else:                             # reuse kinds and none
         kl, kh = 1, 1
-    if policy.kind in ("taylorseer", "foca", "none"):
-        kl = 1  # unused slot kept tiny-but-static
     return CacheState(
         low_hist=jnp.zeros((kl,) + tuple(feat_shape), dtype),
         high_hist=jnp.zeros((kh,) + tuple(feat_shape), dtype),
@@ -131,19 +83,17 @@ def init_state(policy: CachePolicy, feat_shape: Tuple[int, ...],
     )
 
 
-def _needed_history(policy: CachePolicy) -> int:
-    if policy.kind in ("fora", "teacache"):
-        return 1
-    if policy.kind in ("taylorseer", "foca"):
-        return policy.k_high
-    if policy.kind in ("freqca", "freqca_a"):
-        return max(policy.k_low, policy.k_high)
+def _needed_history(policy: Policy) -> int:
+    if policy.name in _FORECAST:
+        return _k_high(policy)
+    if policy.name in _FREQCA:
+        return max(_k_low(policy), _k_high(policy))
     return 1
 
 
-def should_activate(policy: CachePolicy, state: CacheState,
+def should_activate(policy: Policy, state: CacheState,
                     step_idx: jnp.ndarray) -> jnp.ndarray:
-    if policy.kind == "none":
+    if policy.name == "none":
         return jnp.asarray(True)
     scheduled = (step_idx % policy.interval) == 0
     warmup = state.n_valid < _needed_history(policy)
@@ -156,12 +106,12 @@ def _push(hist, ts, value, t):
     return hist, ts
 
 
-def update(policy: CachePolicy, state: CacheState, z: jnp.ndarray,
+def update(policy: Policy, state: CacheState, z: jnp.ndarray,
            t) -> CacheState:
     """Push the freshly computed CRF ``z`` (activated step at time t)."""
-    if policy.kind == "none":
+    if policy.name == "none":
         return state
-    if policy.kind in ("fora", "taylorseer", "foca", "teacache"):
+    if policy.name in _REUSE + _FORECAST:
         low, high = jnp.zeros_like(z), z
     else:  # freqca / freqca_a
         bands = frequency.decompose(z, policy.rho, policy.method,
@@ -174,16 +124,15 @@ def update(policy: CachePolicy, state: CacheState, z: jnp.ndarray,
                       n_valid=state.n_valid + 1)
 
 
-def predict(policy: CachePolicy, state: CacheState, t) -> jnp.ndarray:
+def predict(policy: Policy, state: CacheState, t) -> jnp.ndarray:
     """Reconstruct ẑ_t from the cache (cached step at time t)."""
-    if policy.kind in ("fora", "teacache"):
+    if policy.name in _REUSE:
         return state.high_hist[-1]
-    if policy.kind in ("taylorseer", "foca"):
-        # legacy path has no per-lane gain state: foca degrades to the
-        # uncalibrated forecast (the registry object is the real thing)
+    if policy.name in _FORECAST:
+        # no per-lane gain state: foca is the uncalibrated forecast here
         return hermite.predict(state.ts_high, state.high_hist, t,
                                policy.high_order)
-    assert policy.kind in ("freqca", "freqca_a"), policy.kind
+    assert policy.name in _FREQCA, policy.name
     if policy.low_order == 0:
         low = state.low_hist[-1]
     else:
@@ -197,21 +146,21 @@ def predict(policy: CachePolicy, state: CacheState, t) -> jnp.ndarray:
     return low + high
 
 
-def cache_bytes(state: CacheState, policy: CachePolicy = None) -> int:
+def cache_bytes(state: CacheState, policy: Policy = None) -> int:
     """Bytes the policy actually caches.
 
     ``init_state`` keeps a tiny-but-static dummy ``low_hist`` slot for
     the kinds that never decompose (``update`` pushes zeros into it), so
     a plain pytree sum over-reports those policies.  Pass ``policy`` to
-    exclude the dummy slots (Table-5 memory accounting); without it the
-    raw pytree size is returned (allocation footprint).
+    exclude the dummy slots (the paper's Table-5 memory accounting);
+    without it the raw pytree size is returned (allocation footprint).
     """
     total = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
     if policy is None:
         return total
-    if policy.kind == "none":
+    if policy.name == "none":
         return 0
-    if policy.kind in ("fora", "taylorseer", "foca", "teacache"):
+    if policy.name in _REUSE + _FORECAST:
         return total - (state.low_hist.size * state.low_hist.dtype.itemsize
                         + state.ts_low.size * state.ts_low.dtype.itemsize)
     return total
@@ -228,9 +177,9 @@ class LayerwiseState(NamedTuple):
     n_valid: jnp.ndarray
 
 
-def layerwise_init(policy: CachePolicy, n_layers: int,
+def layerwise_init(policy: Policy, n_layers: int,
                    feat_shape: Tuple[int, ...], dtype=jnp.float32):
-    k = policy.k_high
+    k = _k_high(policy)
     return LayerwiseState(
         hist=jnp.zeros((k, n_layers) + tuple(feat_shape), dtype),
         ts=jnp.full((k,), -1.0, jnp.float32),
@@ -238,13 +187,13 @@ def layerwise_init(policy: CachePolicy, n_layers: int,
     )
 
 
-def layerwise_update(policy: CachePolicy, state: LayerwiseState,
+def layerwise_update(policy: Policy, state: LayerwiseState,
                      residuals: jnp.ndarray, t) -> LayerwiseState:
     hist, ts = _push(state.hist, state.ts, residuals, t)
     return LayerwiseState(hist=hist, ts=ts, n_valid=state.n_valid + 1)
 
 
-def layerwise_predict(policy: CachePolicy, state: LayerwiseState, t,
+def layerwise_predict(policy: Policy, state: LayerwiseState, t,
                       h0: jnp.ndarray) -> jnp.ndarray:
     """Predict each layer residual, reconstruct CRF = h0 + sum_l F̂^l."""
     res = hermite.predict(state.ts, state.hist, t, policy.high_order)

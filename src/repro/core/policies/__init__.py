@@ -1,13 +1,10 @@
-"""Registry of self-contained cache-policy objects (FreqCa + family).
+"""Self-contained cache-policy objects (FreqCa + family).
 
-Policies implement the four-method protocol in :mod:`.base` and register
-a ``spec -> Policy`` factory in :mod:`.registry`; the diffusion sampler
-drives them through a per-lane :class:`~.registry.PolicyBank` and never
-dispatches on policy names.  Policy objects are the public construction
-route — build them directly (``FreqCaPolicy(interval=5)``).  The legacy
-``repro.core.cache.CachePolicy`` string-kind spec is deprecated:
-``.resolve()`` still works (one DeprecationWarning) and ``resolve``
-here still accepts specs for the shim's sake.
+Policies implement the four-method protocol in :mod:`.base`; the
+diffusion sampler drives them through a per-lane
+:class:`~.registry.PolicyBank` and never dispatches on policy names.
+Build them directly (``FreqCaPolicy(interval=5)``); ``available()``
+lists the ``name`` of every class exported here.
 """
 from repro.core.policies.base import (ErrorFeedback, Policy,  # noqa: F401
                                       Ring, StepContext, lane_select)
@@ -20,7 +17,6 @@ from repro.core.policies.freqca_eb import (ERROR_TIERS,  # noqa: F401
                                            budget_tier)
 from repro.core.policies.none import NoCachePolicy  # noqa: F401
 from repro.core.policies.registry import (PolicyBank, available,  # noqa: F401
-                                          bank, compatibility_key, register,
-                                          resolve)
+                                          bank, compatibility_key, resolve)
 from repro.core.policies.taylorseer import TaylorSeerPolicy  # noqa: F401
 from repro.core.policies.teacache import TeaCachePolicy  # noqa: F401

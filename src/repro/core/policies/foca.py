@@ -1,10 +1,10 @@
 """FoCa-style forecast-then-calibrate policy (cf. arXiv 2508.16211).
 
-Registered to prove the registry absorbs new members of the policy
-family without touching the sampler.  Forecast = TaylorSeer's Hermite
-extrapolation of the whole CRF; calibrate = at every activated step the
-stale forecast for that step is scored against the fresh CRF and a
-per-lane scalar gain ``γ = ⟨forecast, crf⟩ / ||forecast||²`` (clipped to
+A member of the policy family that needed no change to the sampler.
+Forecast = TaylorSeer's Hermite extrapolation of the whole CRF;
+calibrate = at every activated step the stale forecast for that step
+is scored against the fresh CRF and a per-lane scalar gain
+``γ = ⟨forecast, crf⟩ / ||forecast||²`` (clipped to
 ``[1/calib_clip, calib_clip]``) is refit, then applied to subsequent
 cached-step forecasts.  A drifting forecast is pulled back toward the
 observed trajectory instead of being replayed verbatim.
@@ -16,7 +16,7 @@ from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 
-from repro.core.policies import base, registry
+from repro.core.policies import base
 
 
 class FoCaState(NamedTuple):
@@ -71,8 +71,3 @@ class FoCaPolicy(base.Policy):
         pred = base.ring_predict(state.hist, ctx.t_now, self.high_order)
         g = state.gain.reshape(state.gain.shape + (1,) * (pred.ndim - 1))
         return (g * pred.astype(jnp.float32)).astype(pred.dtype)
-
-
-@registry.register("foca")
-def _from_spec(spec) -> FoCaPolicy:
-    return FoCaPolicy(interval=spec.interval, high_order=spec.high_order)

@@ -10,7 +10,7 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
-from repro.core.policies import base, registry
+from repro.core.policies import base
 from repro.core.policies.taylorseer import ForecastState
 
 
@@ -31,8 +31,3 @@ class ForaPolicy(base.Policy):
 
     def predict(self, state, ctx):
         return base.ring_last(state.hist)
-
-
-@registry.register("fora")
-def _from_spec(spec) -> ForaPolicy:
-    return ForaPolicy(interval=spec.interval)

@@ -23,7 +23,7 @@ from typing import NamedTuple, Tuple
 import jax.numpy as jnp
 
 from repro.core import frequency
-from repro.core.policies import base, registry
+from repro.core.policies import base
 from repro.kernels import ops
 
 
@@ -131,11 +131,3 @@ class FreqCaPolicy(base.Policy):
         high = (base.ring_last(state.high) if self.high_order == 0 else
                 base.ring_predict(state.high, ctx.t_now, self.high_order))
         return low + high
-
-
-@registry.register("freqca")
-def _from_spec(spec) -> FreqCaPolicy:
-    return FreqCaPolicy(interval=spec.interval, method=spec.method,
-                        rho=spec.rho, low_order=spec.low_order,
-                        high_order=spec.high_order,
-                        token_axis=spec.token_axis)

@@ -17,7 +17,7 @@ from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 
-from repro.core.policies import base, registry
+from repro.core.policies import base
 from repro.core.policies.freqca import FreqCaPolicy
 
 
@@ -65,12 +65,3 @@ class FreqCaAdaptivePolicy(FreqCaPolicy):
             high=base.ring_push(state.high, high, ctx.t_now),
             n_valid=state.n_valid + 1,
             err_last=err)
-
-
-@registry.register("freqca_a")
-def _from_spec(spec) -> FreqCaAdaptivePolicy:
-    return FreqCaAdaptivePolicy(interval=spec.interval, method=spec.method,
-                                rho=spec.rho, low_order=spec.low_order,
-                                high_order=spec.high_order,
-                                token_axis=spec.token_axis,
-                                tea_threshold=spec.tea_threshold)

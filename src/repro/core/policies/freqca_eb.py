@@ -35,7 +35,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
-from repro.core.policies import base, registry
+from repro.core.policies import base
 from repro.core.policies.freqca import FreqCaPolicy
 
 # Budget quantization ladder: requested max_error snaps DOWN to the
@@ -136,12 +136,3 @@ class FreqCaErrorBudgetPolicy(FreqCaPolicy):
 
     def error_feedback(self, state):
         return base.ErrorFeedback(realized=state.peak, events=state.events)
-
-
-@registry.register("freqca_eb")
-def _from_spec(spec) -> FreqCaErrorBudgetPolicy:
-    # legacy specs carry no budget field; reuse the adaptive threshold
-    return FreqCaErrorBudgetPolicy(
-        interval=spec.interval, method=spec.method, rho=spec.rho,
-        low_order=spec.low_order, high_order=spec.high_order,
-        token_axis=spec.token_axis, budget=budget_tier(spec.tea_threshold))

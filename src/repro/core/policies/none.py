@@ -9,7 +9,7 @@ from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 
-from repro.core.policies import base, registry
+from repro.core.policies import base
 
 
 class NoCacheState(NamedTuple):
@@ -37,8 +37,3 @@ class NoCachePolicy(base.Policy):
     def predict(self, state, ctx):
         return jnp.zeros((ctx.batch,) + tuple(ctx.feat_shape),
                          ctx.crf_dtype)
-
-
-@registry.register("none")
-def _from_spec(spec) -> NoCachePolicy:
-    return NoCachePolicy(interval=1)

@@ -1,10 +1,9 @@
-"""Policy registry + per-lane policy banks.
+"""Policy lookup + per-lane policy banks.
 
-``register(name)`` decorates a factory ``spec -> Policy`` so new
-policies (FoCa, SpectralCache, ...) plug in without touching the
-sampler.  ``resolve`` accepts a registered name's spec (the legacy
-``repro.core.cache.CachePolicy`` dataclass, dispatched on ``.kind``) or
-an already-built :class:`~repro.core.policies.base.Policy` instance.
+``available()`` names the policy classes the package exports (a
+benchmark configuration selects a class by that ``name``); ``resolve``
+checks that a value is a :class:`~repro.core.policies.base.Policy`
+instance.
 
 ``bank(policy, batch)`` turns a policy — or a per-lane sequence of
 policies — into a :class:`PolicyBank`, the object the sampler actually
@@ -23,52 +22,31 @@ different state *structures*, share one compiled executable.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Sequence, Tuple, Union
+from typing import Sequence, Tuple, Union
 
 import jax.numpy as jnp
 
 from repro.core.policies import base
 
-_FACTORIES: Dict[str, Callable] = {}
-
-
-def register(name: str):
-    """Decorator: register a ``spec -> Policy`` factory under ``name``."""
-    def deco(factory: Callable) -> Callable:
-        _FACTORIES[name] = factory
-        return factory
-    return deco
-
-
-def _ensure_builtin() -> None:
-    # import for registration side effects; lazy to avoid import cycles
-    from repro.core.policies import (foca, fora, freqca,  # noqa: F401
-                                     freqca_a, freqca_eb, none, taylorseer,
-                                     teacache)
-
 
 def available() -> Tuple[str, ...]:
-    _ensure_builtin()
-    return tuple(sorted(_FACTORIES))
+    """Sorted ``name``s of the policy classes the package exports."""
+    from repro.core import policies
+    return tuple(sorted(
+        v.name for v in vars(policies).values()
+        if isinstance(v, type) and issubclass(v, base.Policy)
+        and v is not base.Policy))
 
 
 def resolve(policy) -> base.Policy:
-    """Spec (``.kind``-dispatched) or Policy instance -> Policy instance."""
-    if isinstance(policy, base.Policy):
-        return policy
-    kind = getattr(policy, "kind", None)
-    if kind is None:
-        raise TypeError(
-            f"expected a Policy or a spec with a .kind, got {policy!r}")
-    _ensure_builtin()
-    if kind not in _FACTORIES:
-        raise KeyError(f"unknown cache policy {kind!r}; "
-                       f"registered: {available()}")
-    return _FACTORIES[kind](policy)
+    """``policy`` itself if it is a Policy instance, else TypeError."""
+    if not isinstance(policy, base.Policy):
+        raise TypeError(f"expected a Policy object, got {policy!r}")
+    return policy
 
 
-def compatibility_key(policy) -> Tuple:
-    """Batch-compatibility key of a policy or spec (see
+def compatibility_key(policy: base.Policy) -> Tuple:
+    """Batch-compatibility key of a policy (see
     :meth:`~repro.core.policies.base.Policy.compatibility_key`).  The
     scheduler groups requests by this key so every cut batch is
     policy-homogeneous."""
@@ -242,12 +220,9 @@ class MixedBank(PolicyBank):
             events=jnp.concatenate([p.events for p in parts]))
 
 
-PolicyLike = Union[base.Policy, object]
-
-
-def bank(policy: Union[PolicyLike, Sequence[PolicyLike]],
+def bank(policy: Union[base.Policy, Sequence[base.Policy]],
          batch: int) -> PolicyBank:
-    """Policy / spec / per-lane sequence thereof -> PolicyBank."""
+    """Policy or per-lane sequence of policies -> PolicyBank."""
     if isinstance(policy, (list, tuple)):
         lanes = tuple(resolve(p) for p in policy)
         if len(lanes) != batch:

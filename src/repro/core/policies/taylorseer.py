@@ -11,7 +11,7 @@ from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 
-from repro.core.policies import base, registry
+from repro.core.policies import base
 
 
 class ForecastState(NamedTuple):
@@ -50,9 +50,3 @@ class TaylorSeerPolicy(base.Policy):
 
     def predict(self, state, ctx):
         return base.ring_predict(state.hist, ctx.t_now, self.high_order)
-
-
-@registry.register("taylorseer")
-def _from_spec(spec) -> TaylorSeerPolicy:
-    return TaylorSeerPolicy(interval=spec.interval,
-                            high_order=spec.high_order)

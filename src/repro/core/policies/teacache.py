@@ -15,7 +15,7 @@ from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 
-from repro.core.policies import base, registry
+from repro.core.policies import base
 
 
 class TeaCacheState(NamedTuple):
@@ -58,9 +58,3 @@ class TeaCachePolicy(base.Policy):
 
     def predict(self, state, ctx):
         return base.ring_last(state.hist)
-
-
-@registry.register("teacache")
-def _from_spec(spec) -> TeaCachePolicy:
-    return TeaCachePolicy(interval=spec.interval,
-                          tea_threshold=spec.tea_threshold)

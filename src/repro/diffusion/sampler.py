@@ -1,10 +1,10 @@
 """Diffusion sampling loop, policy-agnostic with per-lane activation.
 
 The whole sampler is one ``lax.scan`` over timesteps.  The cache policy
-is a self-contained object from ``repro.core.policies`` (or a legacy
-``CachePolicy`` spec, or a per-lane sequence of either — one policy per
-batch lane), driven through the four-method bank protocol; the sampler
-never dispatches on policy names.
+is a self-contained object from ``repro.core.policies`` (or a per-lane
+sequence of them — one policy per batch lane), driven through the
+four-method bank protocol; the sampler never dispatches on policy
+names.
 
 Each step the bank's ``decide`` returns a per-lane activation mask:
 
@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from repro.core.policies import base as policy_base
 from repro.core.policies import registry as policy_registry
 
-PolicyArg = Union[object, Sequence[object]]   # Policy | spec | per-lane seq
+PolicyArg = Union[policy_base.Policy, Sequence[policy_base.Policy]]
 
 # name scopes of the two step bodies: every op of a full step (the
 # denoiser forward and the cache update) or of a cached step (the CRF
@@ -66,9 +66,9 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
 
     ts: [n_steps+1] decreasing times.  crf_shape: [B, *feat] shape of the
     CRF feature (needed to build the static cache state).  ``policy``
-    may be a Policy object, a CachePolicy spec, or a per-lane sequence
-    of them (len == batch) for mixed-policy batches.  ``cond``: the
-    batch's conditioning pytree, passed to every denoiser call.
+    is a Policy object or a per-lane sequence of them (len == batch) for
+    mixed-policy batches.  ``cond``: the batch's conditioning pytree,
+    passed to every denoiser call.
     """
     n_steps = ts.shape[0] - 1
     batch = x_init.shape[0]
@@ -144,10 +144,7 @@ def sample(full_fn: Callable, from_crf_fn: Callable, params,
 
 def reference_features(full_fn: Callable, params, x_init: jnp.ndarray,
                        ts: jnp.ndarray, cond=()):
-    """Run the un-cached sampler, returning per-step (x, crf) trajectories.
-
-    Used by the Fig-2 frequency analysis and Fig-4 MSE benchmarks.
-    """
+    """Run the un-cached sampler, returning per-step (x, crf) trajectories."""
     def step(x, tt):
         t_now, t_next = tt
         v, crf = full_fn(params, x, t_now, cond)

@@ -2,7 +2,7 @@
 
 ``enable()`` is called once per process by every entry point that
 compiles for real (``chip_smoke.py``, ``repro.launch.serve``, the fleet
-``worker_main`` and ``benchmarks.run``), before its first compile.  It
+``worker_main`` and ``bench/run.py``), before its first compile.  It
 never runs at import time.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the cache

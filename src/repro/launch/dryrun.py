@@ -13,8 +13,7 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--out results/dryrun]
 
 Each run writes results/dryrun/<arch>__<shape>__<mesh>.json with
-bytes-per-device, HLO FLOPs/bytes, and per-collective byte counts —
-consumed by benchmarks/roofline.py and EXPERIMENTS.md.
+bytes-per-device, HLO FLOPs/bytes, and per-collective byte counts.
 """
 import argparse
 import json

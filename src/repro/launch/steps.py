@@ -383,10 +383,10 @@ def build_dit(arch_id: str, mesh: Mesh, batch: int = 64,
     n_tok = (latent // cfg.patch_size) ** 2
     t = jax.ShapeDtypeStruct((batch,), jnp.float32)
     if cached_step:
-        from repro.core.cache import CachePolicy
         from repro.core import cache as cache_lib
-        pol = CachePolicy(kind="freqca", interval=5, method="dct",
-                          rho=0.0625, high_order=2)
+        from repro.core.policies import FreqCaPolicy
+        pol = FreqCaPolicy(interval=5, method="dct", rho=0.0625,
+                           high_order=2)
         feat = (batch, n_tok, cfg.d_model)
         state_abs = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),

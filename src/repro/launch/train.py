@@ -2,8 +2,8 @@
 
 Two modes:
 * ``--arch dit-small`` (default): train the small DiT denoiser on the
-  procedural shapes dataset with the rectified-flow loss — this is the
-  model used by the paper-claims benchmarks.
+  procedural shapes dataset with the rectified-flow loss — the model
+  ``repro.launch.serve`` and the examples sample from.
 * ``--arch <assigned-lm-arch> --reduced``: train the reduced variant of
   an assigned architecture on the synthetic LM stream (smoke-scale).
 

@@ -20,7 +20,7 @@ crash-loopers; the router bounds per-replica in-flight work
 retry budget, and quarantines poison requests (``PoisonRequestError``)
 after a solo kill or a failed isolation probe.  ``faults.FaultInjector``
 is the deterministic chaos layer that exercises all of this in
-``tests/test_chaos.py`` and ``benchmarks/serve_chaos.py``.
+``tests/test_chaos.py``.
 """
 from repro.serving.fleet.faults import FaultInjector        # noqa: F401
 from repro.serving.fleet.fleet_metrics import FleetMetrics  # noqa: F401

@@ -165,7 +165,7 @@ class DiffusionRequest:
     # the denoiser; lanes of one cut share its structure and shapes.
     # () -> none.
     cond: object = ()
-    # per-request cache policy (CachePolicy spec or Policy object);
+    # per-request cache policy (a Policy object);
     # None -> the engine's default.  Requests with different policies
     # share a batch lane-by-lane (per-lane activation masks).
     policy: Optional[object] = None

@@ -42,7 +42,7 @@ def tiny_engine():
     import jax
 
     import repro.configs as config_lib
-    from repro.core.cache import CachePolicy
+    from repro.core.policies import FreqCaPolicy
     from repro.models import common, dit
     from repro.serving.engine import DiffusionEngine
 
@@ -52,7 +52,7 @@ def tiny_engine():
     return DiffusionEngine(full_fn, from_crf_fn, params,
                            (SIZE, SIZE, cfg.in_channels),
                            (16, cfg.d_model),
-                           CachePolicy(kind="freqca", interval=3),
+                           FreqCaPolicy(interval=3),
                            n_steps=N_STEPS, max_batch=MAX_BATCH,
                            max_wait_s=0.05)
 

@@ -12,8 +12,7 @@ import pytest
 from hypothesis_compat import given, st  # noqa: E402
 
 from repro.core import cache as cache_lib
-from repro.core import frequency, hermite
-from repro.core.cache import CachePolicy
+from repro.core import frequency, hermite, policies
 
 # ---------------------------------------------------------------------------
 # frequency decomposition
@@ -181,7 +180,7 @@ def _fill(policy, shape, traj, ts):
 
 
 def test_fora_reuses_last():
-    pol = CachePolicy(kind="fora", interval=3)
+    pol = policies.ForaPolicy(interval=3)
     shape = (1, 8, 4)
     traj = lambda t: jnp.full(shape, t)
     st_ = _fill(pol, shape, traj, [1.0, 0.8])
@@ -190,7 +189,7 @@ def test_fora_reuses_last():
 
 
 def test_taylorseer_extrapolates_quadratic():
-    pol = CachePolicy(kind="taylorseer", interval=3, high_order=2)
+    pol = policies.TaylorSeerPolicy(interval=3, high_order=2)
     shape = (1, 8, 4)
     traj = lambda t: jnp.full(shape, 1.0 + 2 * t - t * t)
     st_ = _fill(pol, shape, traj, [1.0, 0.8, 0.6])
@@ -202,8 +201,8 @@ def test_taylorseer_extrapolates_quadratic():
 def test_freqca_separates_bands():
     """Low band (constant) reused; high band (alternating) predicted."""
     s = 16
-    pol = CachePolicy(kind="freqca", interval=3, method="dct", rho=0.25,
-                      high_order=2)
+    pol = policies.FreqCaPolicy(interval=3, method="dct", rho=0.25,
+                                high_order=2)
     alt = jnp.tile(jnp.array([1.0, -1.0]), s // 2)[None, :, None]
     alt = jnp.broadcast_to(alt, (1, s, 4))
 
@@ -221,7 +220,7 @@ def test_freqca_separates_bands():
 
 
 def test_should_activate_schedule_and_warmup():
-    pol = CachePolicy(kind="freqca", interval=4, high_order=2)
+    pol = policies.FreqCaPolicy(interval=4, high_order=2)
     st_ = cache_lib.init_state(pol, (1, 4, 4))
     # no history yet -> always activate (warmup)
     assert bool(cache_lib.should_activate(pol, st_, jnp.asarray(1)))
@@ -233,15 +232,15 @@ def test_should_activate_schedule_and_warmup():
 
 def test_cache_units_match_paper():
     """Paper §4.4.1: FreqCa = 1 + 3 = 4 units; layer-wise = 2(m+1)L."""
-    pol = CachePolicy(kind="freqca", low_order=0, high_order=2)
+    pol = policies.FreqCaPolicy(low_order=0, high_order=2)
     assert pol.cache_units == 4
-    assert CachePolicy(kind="fora").cache_units == 1
-    assert CachePolicy(kind="taylorseer", high_order=2).cache_units == 3
+    assert policies.ForaPolicy().cache_units == 1
+    assert policies.TaylorSeerPolicy(high_order=2).cache_units == 3
 
 
 def test_cache_bytes_o1_vs_layerwise():
     feat = (2, 64, 32)
-    pol = CachePolicy(kind="freqca", high_order=2)
+    pol = policies.FreqCaPolicy(high_order=2)
     crf_state = cache_lib.init_state(pol, feat)
     lw_state = cache_lib.layerwise_init(pol, n_layers=57, feat_shape=feat)
     crf_b = cache_lib.cache_bytes(crf_state)

@@ -77,7 +77,7 @@ import json
 import jax
 import jax.numpy as jnp
 import repro.configs as config_lib
-from repro.core.cache import CachePolicy
+from repro.core import policies
 from repro.models import common, dit
 from repro.serving.engine import DiffusionEngine, DiffusionRequest
 from repro.sharding import partitioning
@@ -93,7 +93,7 @@ mesh = jax.make_mesh((4, 2), ("data", "model"),
 eng = DiffusionEngine(full_fn, from_crf_fn, params,
                       (SIZE, SIZE, cfg.in_channels),
                       (16, cfg.d_model),
-                      CachePolicy(kind="freqca", interval=3),
+                      policies.FreqCaPolicy(interval=3),
                       n_steps=6, max_batch=4, mesh=mesh)
 assert eng.group_policies and eng.scheduler.group_policies
 
@@ -109,7 +109,7 @@ assert all(s.data.shape == (1, SIZE, SIZE, cfg.in_channels)
 
 # end-to-end grouped serving: alternating default/fora requests fill
 # two compatibility groups -> two policy-pure sharded bucket-4 cuts
-fora = CachePolicy(kind="fora", interval=2)
+fora = policies.ForaPolicy(interval=2)
 for i in range(8):
     eng.submit(DiffusionRequest(request_id=i, seed=i,
                                 policy=fora if i % 2 else None), now=0.0)

@@ -1,19 +1,16 @@
 """Quality-SLO tests: error-budgeted activation (freqca_eb), budget
 tiers, the per-request ``max_error`` path through scheduler + engine,
-load shedding (relax, never drop), the deprecated ``CachePolicy``
-shim, and the golden guarantee that requests without a budget are
-bitwise-identical to the pre-SLO serving path (feedback stays a None
-pytree, so non-SLO jit signatures are unchanged programs).
+load shedding (relax, never drop), and the golden guarantee that
+requests without a budget are bitwise-identical to the pre-SLO serving
+path (feedback stays a None pytree, so non-SLO jit signatures are
+unchanged programs).
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro.configs as config_lib
-from repro.core import cache as cache_lib
 from repro.core import policies
 from repro.core.policies import base as policy_base
 from repro.core.policies.freqca_eb import (ERROR_TIERS, FreqCaEbState,
@@ -72,23 +69,6 @@ def test_with_budget_replaces_and_folds_into_key():
     # non-feedback policies ignore the budget (base default)
     fre = policies.FreqCaPolicy(interval=5)
     assert fre.with_budget(0.05) is fre
-
-
-def test_spec_route_builds_eb_from_threshold():
-    spec = cache_lib.CachePolicy(kind="freqca_eb", tea_threshold=0.3)
-    pol = policies.resolve(spec)
-    assert isinstance(pol, FreqCaErrorBudgetPolicy)
-    assert pol.budget == budget_tier(0.3)
-
-
-def test_cachepolicy_resolve_warns_exactly_once():
-    cache_lib._RESOLVE_WARNED = False
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        cache_lib.CachePolicy(kind="freqca").resolve()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")    # a second warn would raise
-        pol = cache_lib.CachePolicy(kind="fora").resolve()
-    assert pol == policies.ForaPolicy()
 
 
 # ---------------------------------------------------------------------------

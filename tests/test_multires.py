@@ -16,8 +16,7 @@ import numpy as np
 import pytest
 
 import repro.configs as config_lib
-from repro.core import frequency
-from repro.core.cache import CachePolicy
+from repro.core import frequency, policies
 from repro.serving import metrics as metrics_lib
 from repro.serving.engine import DiffusionEngine, DiffusionRequest
 from repro.serving.fleet import FleetRouter
@@ -51,7 +50,7 @@ def make_multi_engine(multi_fns, max_batch=2, **kw):
     pairs = [shape_pair(cfg, s) for s in SIZES]
     return DiffusionEngine(full_fn, from_crf_fn, params, pairs[0][0],
                            pairs[0][1],
-                           CachePolicy(kind="freqca", interval=3),
+                           policies.FreqCaPolicy(interval=3),
                            n_steps=N_STEPS, max_batch=max_batch,
                            shapes=pairs[1:], **kw)
 

@@ -1,7 +1,8 @@
-"""Policy-object registry tests: golden equivalence against the legacy
-string-`kind` sampler path, per-lane isolation in mixed batches,
-derived warm-up lengths, the FoCa extension, policy-aware cache-bytes
-accounting, and the open-loop Poisson arrival plan."""
+"""Policy-object tests: the package's policy names, golden equivalence
+against the reference sampler over ``repro.core.cache``, per-lane
+isolation in mixed batches, derived warm-up lengths, the FoCa
+extension, policy-aware cache-bytes accounting, and the open-loop
+Poisson arrival plan."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +11,6 @@ import pytest
 import repro.configs as config_lib
 from repro.core import cache as cache_lib
 from repro.core import policies
-from repro.core.cache import CachePolicy
 from repro.core.policies import base as policy_base
 from repro.diffusion import sampler, schedule
 from repro.models import common, dit
@@ -37,29 +37,51 @@ def test_registry_lists_policy_family():
         assert expected in names
 
 
-def test_resolve_spec_and_passthrough():
-    spec = CachePolicy(kind="freqca", interval=7, rho=0.25, high_order=3)
-    pol = policies.resolve(spec)
-    assert isinstance(pol, policies.FreqCaPolicy)
-    assert (pol.interval, pol.rho, pol.high_order) == (7, 0.25, 3)
+def test_available_names_every_policy_class_once():
+    """``available()`` is exactly the names of the concrete policy
+    classes in the package, each name one class: a benchmark
+    configuration picks its class by this name."""
+    import importlib
+    import pkgutil
+    for mod in pkgutil.iter_modules(policies.__path__):
+        importlib.import_module(f"{policies.__name__}.{mod.name}")
+    todo, classes = [policy_base.Policy], []
+    while todo:
+        cls = todo.pop()
+        todo.extend(cls.__subclasses__())
+        if (cls is not policy_base.Policy
+                and cls.__module__.startswith(policies.__name__)):
+            classes.append(cls)
+    names = [cls.name for cls in classes]
+    assert len(set(names)) == len(names), sorted(names)
+    assert policies.available() == tuple(sorted(names))
+
+
+def test_resolve_passes_objects_and_rejects_the_rest():
+    pol = policies.FreqCaPolicy(interval=7, rho=0.25, high_order=3)
     assert policies.resolve(pol) is pol            # objects pass through
-    assert policies.resolve(spec) == pol           # value-equal -> same key
-    with pytest.raises(KeyError):
-        policies.resolve(CachePolicy(kind="no-such-policy"))
-    with pytest.raises(TypeError):
-        policies.resolve(42)
+    assert policies.resolve(
+        policies.FreqCaPolicy(interval=7, rho=0.25, high_order=3)) == pol
+
+    class KindOnly:                                # a spec-shaped value
+        kind = "freqca"
+        interval = 7
+
+    for bad in (42, KindOnly()):
+        with pytest.raises(TypeError):
+            policies.resolve(bad)
+        with pytest.raises(TypeError):
+            policies.bank(bad, 1)
 
 
 def test_policy_metadata_matches_spec():
-    resolve = policies.resolve
-    assert resolve(CachePolicy(kind="freqca")).cache_units == 4
-    assert resolve(CachePolicy(kind="fora")).cache_units == 1
-    assert resolve(CachePolicy(kind="taylorseer")).cache_units == 3
-    assert resolve(CachePolicy(kind="none")).cache_units == 0
+    assert policies.FreqCaPolicy().cache_units == 4
+    assert policies.ForaPolicy().cache_units == 1
+    assert policies.TaylorSeerPolicy().cache_units == 3
+    assert policies.NoCachePolicy().cache_units == 0
     # warm-up length is derived from the predictor's history needs
-    assert resolve(CachePolicy(kind="freqca_a")).needed_history == 3
-    assert resolve(CachePolicy(kind="freqca_a",
-                               high_order=4)).needed_history == 5
+    assert policies.FreqCaAdaptivePolicy().needed_history == 3
+    assert policies.FreqCaAdaptivePolicy(high_order=4).needed_history == 5
 
 
 def test_compatibility_keys():
@@ -68,46 +90,45 @@ def test_compatibility_keys():
     adaptive policies key by full value (data-dependent masks only share
     with the identical policy)."""
     key = policies.compatibility_key
-    # identical resolved policies -> identical keys, spec or object
-    assert key(CachePolicy(kind="freqca", interval=5)) == \
-        key(policies.resolve(CachePolicy(kind="freqca", interval=5)))
+    none = policies.NoCachePolicy(interval=1)
+    # value-equal objects -> identical keys
+    assert key(policies.FreqCaPolicy(interval=5)) == \
+        key(policies.FreqCaPolicy(interval=5))
     # same (interval, needed_history) static schedule -> one family,
     # across different predictors
-    assert key(CachePolicy(kind="freqca", interval=5)) == \
-        key(CachePolicy(kind="taylorseer", interval=5))
-    assert key(CachePolicy(kind="fora", interval=1)) == \
-        key(CachePolicy(kind="none"))
+    assert key(policies.FreqCaPolicy(interval=5)) == \
+        key(policies.TaylorSeerPolicy(interval=5))
+    assert key(policies.ForaPolicy(interval=1)) == key(none)
     # schedule differences split the family
-    assert key(CachePolicy(kind="fora", interval=2)) != \
-        key(CachePolicy(kind="fora", interval=3))
-    assert key(CachePolicy(kind="fora", interval=5)) != \
-        key(CachePolicy(kind="freqca", interval=5))   # warmup differs
+    assert key(policies.ForaPolicy(interval=2)) != \
+        key(policies.ForaPolicy(interval=3))
+    assert key(policies.ForaPolicy(interval=5)) != \
+        key(policies.FreqCaPolicy(interval=5))   # warmup differs
     # adaptive policies: value-keyed, never share with static schedules
-    a1 = CachePolicy(kind="freqca_a", tea_threshold=0.3)
-    a2 = CachePolicy(kind="freqca_a", tea_threshold=0.2)
+    a1 = policies.FreqCaAdaptivePolicy(tea_threshold=0.3)
+    a2 = policies.FreqCaAdaptivePolicy(tea_threshold=0.2)
     assert key(a1) == key(a1) != key(a2)
-    assert key(a1) != key(CachePolicy(kind="freqca"))
-    assert key(CachePolicy(kind="teacache")) != key(a1)
+    assert key(a1) != key(policies.FreqCaPolicy())
+    assert key(policies.TeaCachePolicy()) != key(a1)
     # banks expose the key too: uniform -> the policy's, mixed ->
     # collapsed when every lane is compatible
     assert policies.bank(a1, 2).compatibility_key() == key(a1)
-    fam = policies.bank([CachePolicy(kind="fora", interval=1),
-                         CachePolicy(kind="none")], 2)
-    assert fam.compatibility_key() == key(CachePolicy(kind="none"))
-    mixed = policies.bank([a1, CachePolicy(kind="none")], 2)
-    assert mixed.compatibility_key() == (key(a1),
-                                         key(CachePolicy(kind="none")))
+    fam = policies.bank([policies.ForaPolicy(interval=1), none], 2)
+    assert fam.compatibility_key() == key(none)
+    mixed = policies.bank([a1, none], 2)
+    assert mixed.compatibility_key() == (key(a1), key(none))
 
 
 # ---------------------------------------------------------------------------
-# golden equivalence vs the legacy string-`kind` sampler
+# golden equivalence vs the reference sampler
 # ---------------------------------------------------------------------------
 
 def _legacy_sample(full_fn, from_crf_fn, params, x_init, ts, policy,
                    crf_shape,
                    crf_dtype=jnp.float32):
-    """Verbatim port of the seed sampler (string-`kind` dispatch +
-    sampler-resident tea0 carries) — the golden reference."""
+    """Verbatim port of the seed sampler (dispatch on the policy's
+    ``name`` + sampler-resident tea0 carries) over the reference state
+    machines of ``repro.core.cache`` — the golden reference."""
     n_steps = ts.shape[0] - 1
     state0 = cache_lib.init_state(policy, crf_shape, crf_dtype)
     tea0 = (jnp.zeros((), jnp.float32), jnp.zeros_like(x_init),
@@ -121,7 +142,7 @@ def _legacy_sample(full_fn, from_crf_fn, params, x_init, ts, policy,
         def full_branch(op):
             x_, state_ = op
             v, crf = full_fn(params, x_, t_now)
-            if policy.kind == "freqca_a":
+            if policy.name == "freqca_a":
                 pred = cache_lib.predict(policy, state_, t_now)
                 err = jnp.linalg.norm(
                     (pred - crf).astype(jnp.float32)) / jnp.maximum(
@@ -136,20 +157,20 @@ def _legacy_sample(full_fn, from_crf_fn, params, x_init, ts, policy,
             return (from_crf_fn(params, crf_hat, t_now), state_, 0,
                     jnp.zeros((), jnp.float32))
 
-        if policy.kind == "teacache":
+        if policy.name == "teacache":
             rel = jnp.mean(jnp.abs(x - prev_x)) / jnp.maximum(
                 jnp.mean(jnp.abs(prev_x)), 1e-6)
             acc = acc + rel.astype(jnp.float32)
             warm = state.n_valid < 1
             act = warm | (acc > policy.tea_threshold) | (i == 0)
             acc = jnp.where(act, 0.0, acc)
-        elif policy.kind == "freqca_a":
+        elif policy.name == "freqca_a":
             warm = state.n_valid < 3
             projected = (since.astype(jnp.float32) + 1.0) * err_last
             act = warm | (projected > policy.tea_threshold)
         else:
             act = cache_lib.should_activate(policy, state, i)
-        if policy.kind == "none":
+        if policy.name == "none":
             v, state, used, err_new = full_branch((x, state))
         else:
             v, state, used, err_new = jax.lax.cond(
@@ -168,12 +189,16 @@ def _legacy_sample(full_fn, from_crf_fn, params, x_init, ts, policy,
 
 
 SEED_CONFIGS = [
-    CachePolicy(kind="none"),
-    CachePolicy(kind="fora", interval=5),
-    CachePolicy(kind="taylorseer", interval=5, high_order=2),
-    CachePolicy(kind="freqca", interval=5, method="dct", rho=0.25),
-    CachePolicy(kind="freqca", interval=3, method="fft", rho=0.0625),
-    CachePolicy(kind="freqca", interval=5, method="none"),
+    pytest.param(policies.NoCachePolicy(), id="none-dct-5"),
+    pytest.param(policies.ForaPolicy(interval=5), id="fora-dct-5"),
+    pytest.param(policies.TaylorSeerPolicy(interval=5, high_order=2),
+                 id="taylorseer-dct-5"),
+    pytest.param(policies.FreqCaPolicy(interval=5, method="dct", rho=0.25),
+                 id="freqca-dct-5"),
+    pytest.param(policies.FreqCaPolicy(interval=3, method="fft",
+                                       rho=0.0625), id="freqca-fft-3"),
+    pytest.param(policies.FreqCaPolicy(interval=5, method="none"),
+                 id="freqca-none-5"),
 ]
 
 
@@ -182,15 +207,14 @@ def _assert_golden(pol, got, want):
     same projection as the legacy spatial cache, but a different matmul
     association — float tolerance for dct/fft.  ``method="none"`` (zero
     low band) and every non-decomposing policy stay BITWISE equal."""
-    if pol.kind.startswith("freqca") and pol.method != "none":
+    if pol.name.startswith("freqca") and pol.method != "none":
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
     else:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("pol", SEED_CONFIGS,
-                         ids=lambda p: f"{p.kind}-{p.method}-{p.interval}")
+@pytest.mark.parametrize("pol", SEED_CONFIGS)
 def test_golden_equivalence_scheduled(tiny_dit, pol):
     """Registered policy objects match the legacy spatial-cache path on
     the seed configs (scheduled policies, batch > 1) — bitwise except
@@ -209,9 +233,9 @@ def test_golden_equivalence_scheduled(tiny_dit, pol):
 
 
 @pytest.mark.parametrize("pol", [
-    CachePolicy(kind="teacache", tea_threshold=0.05),
-    CachePolicy(kind="freqca_a", tea_threshold=0.3, rho=0.25),
-], ids=lambda p: p.kind)
+    policies.TeaCachePolicy(tea_threshold=0.05),
+    policies.FreqCaAdaptivePolicy(tea_threshold=0.3, rho=0.25),
+], ids=lambda p: p.name)
 def test_golden_equivalence_adaptive_solo(tiny_dit, pol):
     """Adaptive policies match the legacy path at batch 1, where the
     legacy batch-global decision IS the lane decision.  (At batch > 1
@@ -238,8 +262,8 @@ def test_mixed_batch_lane_matches_solo(tiny_dit):
     lane matches its solo cached run, and per-lane n_full decouple."""
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(16)
-    mix = (CachePolicy(kind="none"),
-           CachePolicy(kind="freqca", interval=4, rho=0.25))
+    mix = (policies.NoCachePolicy(interval=1),
+           policies.FreqCaPolicy(interval=4, rho=0.25))
     res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, mix,
                          crf_shape=(2, 16, cfg.d_model))
     assert int(res.n_full_lanes[0]) == 16
@@ -259,7 +283,7 @@ def test_uniform_adaptive_batch_is_per_lane(tiny_dit):
     have flipped the old batch-global decision."""
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
-    pol = CachePolicy(kind="freqca_a", tea_threshold=0.3, rho=0.25)
+    pol = policies.FreqCaAdaptivePolicy(tea_threshold=0.3, rho=0.25)
     res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=(2, 16, cfg.d_model))
     for j in range(2):
@@ -281,15 +305,15 @@ def test_freqca_a_warmup_follows_high_order(tiny_dit):
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
     for high_order, want in [(2, 3), (4, 5)]:
-        pol = CachePolicy(kind="freqca_a", tea_threshold=1e9,
-                          high_order=high_order, rho=0.25)
+        pol = policies.FreqCaAdaptivePolicy(
+            tea_threshold=1e9, high_order=high_order, rho=0.25)
         res = sampler.sample(full_fn, from_crf_fn, params, x0[:1], ts, pol,
                              crf_shape=(1, 16, cfg.d_model))
         assert int(res.n_full_lanes[0]) == want, (high_order, want)
 
 
 # ---------------------------------------------------------------------------
-# FoCa (registry extensibility)
+# FoCa (a policy added without touching the sampler)
 # ---------------------------------------------------------------------------
 
 def _ctx(t, batch=1, feat_shape=(4,)):
@@ -321,7 +345,7 @@ def test_foca_samples_end_to_end(tiny_dit):
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
     res = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
-                         CachePolicy(kind="foca", interval=5),
+                         policies.FoCaPolicy(interval=5),
                          crf_shape=(2, 16, cfg.d_model))
     assert bool(jnp.isfinite(res.x).all())
     assert int(res.n_full) < 20
@@ -333,26 +357,27 @@ def test_foca_samples_end_to_end(tiny_dit):
 
 def test_cache_bytes_excludes_dummy_low_slot():
     feat = (1, 32, 16)
-    for kind in ("taylorseer", "foca", "fora", "teacache"):
-        pol = CachePolicy(kind=kind, high_order=2)
+    for pol in (policies.TaylorSeerPolicy(high_order=2),
+                policies.FoCaPolicy(high_order=2), policies.ForaPolicy(),
+                policies.TeaCachePolicy()):
         state = cache_lib.init_state(pol, feat)
         raw = cache_lib.cache_bytes(state)
         real = cache_lib.cache_bytes(state, pol)
         dummy = (state.low_hist.size * state.low_hist.dtype.itemsize
                  + state.ts_low.size * state.ts_low.dtype.itemsize)
-        assert real == raw - dummy, kind
+        assert real == raw - dummy, pol.name
         # memory scales with cache_units, matching §4.4.1 accounting
         per_unit = (state.high_hist.size // pol.cache_units
                     * state.high_hist.dtype.itemsize)
-        assert real >= per_unit * pol.cache_units, kind
-    pol = CachePolicy(kind="none")
+        assert real >= per_unit * pol.cache_units, pol.name
+    pol = policies.NoCachePolicy(interval=1)
     assert cache_lib.cache_bytes(cache_lib.init_state(pol, feat), pol) == 0
     # freqca uses both bands: nothing excluded
-    pol = CachePolicy(kind="freqca")
+    pol = policies.FreqCaPolicy()
     state = cache_lib.init_state(pol, feat)
     assert cache_lib.cache_bytes(state, pol) == cache_lib.cache_bytes(state)
     # the new policy objects carry no dummy slots at all
-    obj = policies.resolve(CachePolicy(kind="taylorseer", high_order=2))
+    obj = policies.TaylorSeerPolicy(high_order=2)
     st = obj.init(1, feat)
     want = (np.prod((1, 3) + feat) * 4      # hist [B, K, *feat] f32
             + 3 * 4                          # ts [B, K]
@@ -437,7 +462,7 @@ def test_freqca_state_is_spectral_and_small():
     assert pol.state_bytes(state) < (2 * (pol.k_low + pol.k_high)
                                      * s * d * 4)
     # freqca_a shares the spectral layout
-    pol_a = policies.resolve(CachePolicy(kind="freqca_a", rho=rho))
+    pol_a = policies.FreqCaAdaptivePolicy(rho=rho)
     st_a = pol_a.init(1, (s, d))
     assert st_a.low.vals.shape == (1, pol_a.k_low, m, d)
 
@@ -466,7 +491,7 @@ def test_sampler_pallas_dispatch_matches_xla(tiny_dit, monkeypatch):
     datapath from rotting."""
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(12)
-    pol = CachePolicy(kind="freqca", interval=4, method="dct", rho=0.25)
+    pol = policies.FreqCaPolicy(interval=4, method="dct", rho=0.25)
     crf_shape = (2, 16, cfg.d_model)
     monkeypatch.setenv("REPRO_KERNELS", "xla")
     want = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,

@@ -14,7 +14,7 @@ import pytest
 
 import repro.configs as config_lib
 from repro.core import cache as cache_lib
-from repro.core.cache import CachePolicy
+from repro.core import policies
 from repro.diffusion import sampler, schedule
 from repro.models import common, dit
 
@@ -29,16 +29,20 @@ def tiny_dit():
     return cfg, full_fn, from_crf_fn, params, x0
 
 
-@pytest.mark.parametrize("kind", ["none", "fora", "taylorseer", "foca",
-                                  "freqca"])
-def test_policies_sample_finite(tiny_dit, kind):
+@pytest.mark.parametrize("pol", [
+    policies.NoCachePolicy(interval=1),
+    policies.ForaPolicy(interval=5),
+    policies.TaylorSeerPolicy(interval=5),
+    policies.FoCaPolicy(interval=5),
+    policies.FreqCaPolicy(interval=5, method="dct", rho=0.25),
+], ids=lambda p: p.name)
+def test_policies_sample_finite(tiny_dit, pol):
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(20)
-    pol = CachePolicy(kind=kind, interval=5, method="dct", rho=0.25)
     res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=(2, 16, cfg.d_model))
     assert bool(jnp.isfinite(res.x).all())
-    if kind == "none":
+    if pol.name == "none":
         assert int(res.n_full) == 20
     else:
         # 4 scheduled + warmup fills
@@ -49,7 +53,7 @@ def test_speedup_matches_interval(tiny_dit):
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     n_steps = 50
     ts = schedule.timesteps(n_steps)
-    pol = CachePolicy(kind="freqca", interval=5, method="dct")
+    pol = policies.FreqCaPolicy(interval=5, method="dct")
     res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                          crf_shape=(2, 16, cfg.d_model))
     # paper: speedup ~ N as C_pred -> 0; 50 steps at N=5 -> 10 + warmup 2
@@ -60,18 +64,17 @@ def test_freqca_not_worse_than_fora(tiny_dit):
     cfg, full_fn, from_crf_fn, params, x0 = tiny_dit
     ts = schedule.timesteps(30)
     ref = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
-                         CachePolicy(kind="none"),
+                         policies.NoCachePolicy(interval=1),
                          crf_shape=(2, 16, cfg.d_model))
 
-    def err(kind, **kw):
-        pol = CachePolicy(kind=kind, interval=5, method="dct", rho=0.25,
-                          **kw)
+    def err(pol):
         res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                              crf_shape=(2, 16, cfg.d_model))
         return float(jnp.mean(jnp.square(res.x - ref.x)))
 
-    e_freqca = err("freqca")
-    e_fora = err("fora")
+    e_freqca = err(policies.FreqCaPolicy(interval=5, method="dct",
+                                         rho=0.25))
+    e_fora = err(policies.ForaPolicy(interval=5))
     assert np.isfinite(e_freqca) and np.isfinite(e_fora)
     assert e_freqca <= e_fora * 1.5, (e_freqca, e_fora)
 
@@ -89,7 +92,7 @@ def test_layerwise_vs_crf_prediction():
     as summing per-layer predictions, at a fraction of the memory."""
     rng = jax.random.key(0)
     n_layers, feat = 6, (1, 8, 4)
-    pol = CachePolicy(kind="taylorseer", high_order=2)
+    pol = policies.TaylorSeerPolicy(high_order=2)
 
     def layer_traj(t):  # smooth per-layer residuals
         base = jnp.arange(n_layers, dtype=jnp.float32)[:, None, None, None]
@@ -97,7 +100,7 @@ def test_layerwise_vs_crf_prediction():
 
     h0 = jnp.zeros(feat)
     lw = cache_lib.layerwise_init(pol, n_layers, feat)
-    crf_pol = CachePolicy(kind="taylorseer", high_order=2)
+    crf_pol = policies.TaylorSeerPolicy(high_order=2)
     crf = cache_lib.init_state(crf_pol, feat)
     for t in [1.0, 0.8, 0.6]:
         lw = cache_lib.layerwise_update(pol, lw, layer_traj(t), t)
@@ -119,7 +122,7 @@ def test_teacache_adaptive_compute(tiny_dit):
     ts = schedule.timesteps(20)
     fulls = []
     for th in (0.01, 1e9):
-        pol = CachePolicy(kind="teacache", tea_threshold=th)
+        pol = policies.TeaCachePolicy(tea_threshold=th)
         res = sampler.sample(full_fn, from_crf_fn, params, x0, ts, pol,
                              crf_shape=(2, 16, cfg.d_model))
         fulls.append(int(res.n_full))

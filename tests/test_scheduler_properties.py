@@ -37,7 +37,7 @@ import dataclasses
 
 import pytest
 
-from repro.core.cache import CachePolicy
+from repro.core import policies
 from repro.serving.scheduler import (DiffusionRequest, Scheduler,
                                      bucket_for, bucket_sizes)
 
@@ -48,18 +48,18 @@ from repro.serving.scheduler import (DiffusionRequest, Scheduler,
 from hypothesis_compat import given, st  # noqa: E402
 
 
-DEFAULT = CachePolicy(kind="freqca", interval=5)
+DEFAULT = policies.FreqCaPolicy(interval=5)
 # deliberately includes compatible static families: taylorseer(5) keys
 # with freqca(5) — same (interval, needed_history) — and fora(1) keys
 # with none (both activate every step)
 POLICIES = [
     None,                                            # -> engine default
-    CachePolicy(kind="taylorseer", interval=5),      # same key as DEFAULT
-    CachePolicy(kind="fora", interval=2),
-    CachePolicy(kind="fora", interval=1),            # same key as "none"
-    CachePolicy(kind="none"),
-    CachePolicy(kind="freqca_a", tea_threshold=0.3, rho=0.25),
-    CachePolicy(kind="teacache", tea_threshold=0.2),
+    policies.TaylorSeerPolicy(interval=5),           # same key as DEFAULT
+    policies.ForaPolicy(interval=2),
+    policies.ForaPolicy(interval=1),                 # same key as "none"
+    policies.NoCachePolicy(interval=1),
+    policies.FreqCaAdaptivePolicy(tea_threshold=0.3, rho=0.25),
+    policies.TeaCachePolicy(tea_threshold=0.2),
 ]
 # multi-resolution streams: (latent, CRF) shape pairs a request may
 # declare; None = undeclared (the engine-default pseudo-shape)

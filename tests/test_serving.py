@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 import repro.configs as config_lib
-from repro.core.cache import CachePolicy
+from repro.core import policies
 from repro.data import synthetic
 from repro.diffusion import sampler, schedule
 from repro.serving import metrics as metrics_lib
@@ -44,7 +44,7 @@ def make_engine(dit_fns, max_batch=4, n_steps=N_STEPS, **kw):
     return DiffusionEngine(full_fn, from_crf_fn, params, (SIZE, SIZE,
                                                   cfg.in_channels),
                            (16, cfg.d_model),
-                           CachePolicy(kind="freqca", interval=3),
+                           policies.FreqCaPolicy(interval=3),
                            n_steps=n_steps, max_batch=max_batch, **kw)
 
 
@@ -173,12 +173,12 @@ def test_scheduler_policy_grouping_and_families():
     """Grouped formation cuts policy-pure batches; compatible static
     families share one group (taylorseer(5) with the freqca(5) default,
     fora(interval=1) with none)."""
-    fre = CachePolicy(kind="freqca", interval=5)
+    fre = policies.FreqCaPolicy(interval=5)
     sched = Scheduler(max_batch=4, max_wait_s=0.0, clock=lambda: 0.0,
                       group_policies=True, default_policy=fre)
-    pols = [None, CachePolicy(kind="taylorseer", interval=5),
-            CachePolicy(kind="fora", interval=1),
-            CachePolicy(kind="none")]
+    pols = [None, policies.TaylorSeerPolicy(interval=5),
+            policies.ForaPolicy(interval=1),
+            policies.NoCachePolicy(interval=1)]
     for i, p in enumerate(pols):
         sched.submit(DiffusionRequest(request_id=i, seed=i, policy=p),
                      now=0.0)
@@ -192,8 +192,8 @@ def test_scheduler_policy_grouping_and_families():
     # full-group trigger is per group: 3 groups of 2 fill no bucket of 4
     sched2 = Scheduler(max_batch=4, max_wait_s=100.0, clock=lambda: 0.0,
                        group_policies=True, default_policy=fre)
-    mixed = [fre, CachePolicy(kind="fora", interval=2),
-             CachePolicy(kind="freqca_a", tea_threshold=0.3, rho=0.25)]
+    mixed = [fre, policies.ForaPolicy(interval=2),
+             policies.FreqCaAdaptivePolicy(tea_threshold=0.3, rho=0.25)]
     for i in range(6):
         sched2.submit(DiffusionRequest(request_id=i, seed=i,
                                        policy=mixed[i % 3]), now=0.0)
@@ -343,8 +343,8 @@ def test_mixed_policy_batch_per_lane_accounting(dit_fns):
     once warm."""
     eng = make_engine(dit_fns, max_batch=2, n_steps=12,
                       group_policies=False)
-    pol_a = CachePolicy(kind="freqca_a", tea_threshold=0.3, rho=0.25)
-    pol_b = CachePolicy(kind="fora", interval=2)
+    pol_a = policies.FreqCaAdaptivePolicy(tea_threshold=0.3, rho=0.25)
+    pol_b = policies.ForaPolicy(interval=2)
     lanes = (pol_a, pol_b)
     warm_s = eng.warmup(buckets=[1], lane_policy_sets=[lanes])
     assert warm_s > 0 and eng.metrics.compile_misses >= 2
@@ -383,7 +383,7 @@ def test_uniform_nondefault_policy_collapses_signature(dit_fns):
     eng = make_engine(dit_fns, max_batch=2, n_steps=6)
     eng.warmup()
     misses = eng.metrics.compile_misses
-    pol = CachePolicy(kind="fora", interval=3)
+    pol = policies.ForaPolicy(interval=3)
     for rep in range(2):
         for i in range(2):
             eng.submit(DiffusionRequest(request_id=i, seed=i, policy=pol))
@@ -398,8 +398,8 @@ def test_uniform_nondefault_policy_collapses_signature(dit_fns):
 # ---------------------------------------------------------------------------
 
 MIXED_POLS = (None,                                  # engine default
-              CachePolicy(kind="fora", interval=2),
-              CachePolicy(kind="freqca_a", tea_threshold=0.3, rho=0.25))
+              policies.ForaPolicy(interval=2),
+              policies.FreqCaAdaptivePolicy(tea_threshold=0.3, rho=0.25))
 
 
 def _mixed_requests(n=6):
@@ -492,8 +492,8 @@ def test_family_batch_composition_signature(dit_fns):
     composition under a different arrival interleaving adds zero
     compiles, and each lane bitwise-matches its solo run."""
     eng = make_engine(dit_fns, max_batch=2, n_steps=6)
-    fora1 = CachePolicy(kind="fora", interval=1)
-    none = CachePolicy(kind="none")
+    fora1 = policies.ForaPolicy(interval=1)
+    none = policies.NoCachePolicy(interval=1)
     assert eng.scheduler.group_key(
         DiffusionRequest(request_id=0, seed=0, policy=fora1)) == \
         eng.scheduler.group_key(
@@ -668,7 +668,6 @@ def test_sampler_executables_take_weights_as_inputs():
     copy of its weights."""
     import dataclasses
 
-    from repro.core import policies
     from repro.models import common, dit
     cfg = config_lib.reduced(config_lib.get_config("dit-small"))
     sizes = {}
@@ -700,7 +699,6 @@ def test_sampler_executables_take_weights_as_inputs():
 def test_sampler_step_scopes_in_op_metadata(dit_fns, policy, scopes):
     """Every op of a full step and of a cached step carries its step's
     name scope; the uncached policy calls the full step alone."""
-    from repro.core import policies
     cfg, full_fn, from_crf_fn, params = dit_fns
     pol = (policies.FreqCaPolicy(interval=3) if policy == "freqca"
            else policies.NoCachePolicy())

@@ -11,7 +11,7 @@ import pytest
 
 import repro.configs as config_lib
 from repro.checkpointing import checkpoint
-from repro.core.cache import CachePolicy
+from repro.core import policies
 from repro.data import synthetic
 from repro.diffusion import sampler, schedule, training
 from repro.launch.train import train_dit, train_lm
@@ -60,7 +60,7 @@ def test_serving_engine_end_to_end():
     eng = DiffusionEngine(full_fn, from_crf_fn, params,
                           (8, 8, cfg.in_channels),
                           (16, cfg.d_model),
-                          CachePolicy(kind="freqca", interval=5),
+                          policies.FreqCaPolicy(interval=5),
                           n_steps=20, max_batch=4)
     for i in range(6):
         eng.submit(DiffusionRequest(request_id=i, seed=i))
@@ -79,7 +79,7 @@ def test_editing_request_denoises_from_reference():
     eng = DiffusionEngine(full_fn, from_crf_fn, params,
                           (8, 8, cfg.in_channels),
                           (16, cfg.d_model),
-                          CachePolicy(kind="freqca", interval=3),
+                          policies.FreqCaPolicy(interval=3),
                           n_steps=10, max_batch=2)
     ref_img = synthetic.shapes_batch(jax.random.key(5), 1, size=8,
                                      channels=cfg.in_channels)[0]
@@ -106,7 +106,7 @@ def test_backbone_denoiser_freqca():
     x0 = jax.random.normal(jax.random.key(1), (2, 8, 8, 4))
     ts = schedule.timesteps(12)
     res = sampler.sample(full_fn, from_crf_fn, params, x0, ts,
-                         CachePolicy(kind="freqca", interval=4, rho=0.25),
+                         policies.FreqCaPolicy(interval=4, rho=0.25),
                          crf_shape=(2, 16, cfg.d_model))
     assert bool(jnp.isfinite(res.x).all())
     assert int(res.n_full) < 12
