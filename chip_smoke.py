@@ -194,6 +194,33 @@ def kernel_phase(batch: int, s: int, d: int, heads: int, n_text: int,
                "a convex mix of V rows: bf16 probabilities and output move "
                "it by < 2^-8 of max|v|; 2^-7 of max|v|")
 
+    # q/k of FLUX.1-Kontext's joint [text | image | reference]: each
+    # stream's per-head LayerNorm, then 3-axis RoPE over the join
+    from repro.models import common, dit
+    side = math.isqrt(s)
+    axes = (hd // 8, 7 * hd // 16, 7 * hd // 16)
+    rope = dit.rope_tables(dit.token_ids(n_text, (side, side), (side, side)),
+                           axes, 10000.0)
+    xt = jax.random.normal(keys[5], (batch, n_text, d), jnp.bfloat16)
+    xi = jax.random.normal(keys[6], (batch, 2 * s, d), jnp.bfloat16)
+    scales = (1 + 0.25 * jax.random.normal(keys[7], (2, hd))
+              ).astype(jnp.bfloat16)
+
+    def qk_oracle(xt_, xi_, scales_):
+        normed = [common.layernorm(x.reshape(*x.shape[:2], heads, hd),
+                                   scale=sc)
+                  for x, sc in zip((xt_, xi_), scales_)]
+        return dit.apply_rope(jnp.concatenate(normed, axis=1), rope)
+
+    tol = 2 ** -7 * float(jnp.max(jnp.abs(
+        qk_oracle(xt, xi, scales).astype(jnp.float32))))
+    _check(f"qk_norm_rope (B={batch}, S={n_text}+{2 * s})",
+           lambda a, b, sc: ops.qk_norm_rope([a, b], [sc[0], sc[1]], rope,
+                                             heads),
+           (xt, xi, scales), qk_oracle, tol,
+           "the oracle's f32 arithmetic with reductions in another order: "
+           "its bf16 roundings may differ by an ulp; 2^-7 of the peak")
+
 
 def forward_phase(cfg, params, size: int, n_text: int, seed: int) -> None:
     """(d) one jitted forward with text: dual-stream + joint attention."""

@@ -5,9 +5,10 @@ here; unset, it defaults to ``pallas`` on TPU and ``xla`` elsewhere
 (interpret-mode Pallas is correct but slow on CPU, so off-TPU the
 pure-jnp paths win).  Consumers — ``core.frequency.decompose``,
 ``core.policies.base.ring_predict``, ``core.policies.freqca``,
-``models.dit._joint_attention`` — route their hot paths through this
-module so the cached step, the band split, and joint attention run the
-fused kernels on TPU without forking any call sites.
+``models.dit._joint_attention``, ``models.dit._joint_qkv`` — route
+their hot paths through this module so the cached step, the band split,
+the q/k norm and RoPE, and joint attention run the fused kernels on TPU
+without forking any call sites.
 
 Both the backend and interpret mode are read **lazily at call time**
 (``backend()`` / ``interpret()``), never frozen at import, so a test
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from repro.core import hermite
 from repro.kernels import dct as dct_kernel
 from repro.kernels import freqca_fused as fused_kernel
+from repro.kernels import qk_norm_rope as qk_kernel
 from repro.kernels import ref
 from repro.kernels import ssd_scan as ssd_kernel
 
@@ -223,3 +225,21 @@ def flash(q, k, v, q_per_kv: int, causal: bool = True, window: int = 0,
     shape (``flash_attention.tiles``)."""
     return _flash(q, k, v, q_per_kv, causal, window, q_block, kv_block,
                   interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "interpret_"))
+def _qk_norm_rope(xs, scales, cos, sin, n_heads, interpret_):
+    out = qk_kernel.norm_rope(xs, scales, cos, sin, n_heads,
+                              interpret=interpret_)
+    return out.transpose(0, 2, 1, 3)
+
+
+def qk_norm_rope(xs, scales, rope, n_heads: int):
+    """q or k [B, S, H, hd] of projections ``xs`` [B, S_i, H·hd] joined
+    along the tokens: each stream LayerNormed per head by its own
+    ``scales[i]``, then rotated by ``rope``'s (cos, sin) of the joined
+    sequence — one kernel pass per stream, written in the layout flash's
+    token-major blocks read (the oracle: ``models.dit.apply_rope`` of
+    ``common.layernorm``, which the XLA path runs)."""
+    return _qk_norm_rope(tuple(xs), tuple(scales), *rope, n_heads,
+                         interpret())

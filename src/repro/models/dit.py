@@ -116,6 +116,32 @@ def _qkv_heads(p, x, n_heads):
     return q, k, v
 
 
+def _fuses_qk(rope, hd: int) -> bool:
+    """Whether q and k go from their projections to flash's layout in one
+    kernel pass (``ops.qk_norm_rope``): with RoPE tables, at a head size
+    of whole 128 lanes, on the Pallas backend.  Elsewhere the block runs
+    ``_qkv_heads`` then ``apply_rope``."""
+    from repro.kernels import flash_attention as fa
+    return rope is not None and hd % fa.LANES == 0 and ops.use_pallas()
+
+
+def _joint_qkv(attns, xs, n_heads, rope):
+    """q, k, v [B, S, H, hd] of the streams ``xs`` [B, S_i, d] joined
+    along the tokens, each through its own ``attns`` weights and q/k
+    norms; q and k normed and rotated by the one-pass kernel, which
+    writes the joined sequence itself (``_fuses_qk``)."""
+    b, _, d = xs[0].shape
+    def project(w, x):
+        return x @ w.astype(x.dtype).reshape(d, d)
+    q, k = (ops.qk_norm_rope([project(p[w], x) for p, x in zip(attns, xs)],
+                             [p[norm] for p in attns], rope, n_heads)
+            for w, norm in (("wq", "q_norm"), ("wk", "k_norm")))
+    vs = [project(p["wv"], x).reshape(b, x.shape[1], n_heads, d // n_heads)
+          for p, x in zip(attns, xs)]
+    v = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=1)
+    return q, k, v
+
+
 # ---------------------------------------------------------------------------
 # FLUX's 3-axis RoPE (``EmbedND``)
 # ---------------------------------------------------------------------------
@@ -201,8 +227,11 @@ def single_block(params, x, cond, cfg: DiTConfig, rope=None):
     """Single-stream joint block with AdaLN-zero."""
     sh1, sc1, g1, sh2, sc2, g2 = _modulation(params["mod"], cond, 6)
     h = common.layernorm(x, cfg.norm_eps) * (1 + sc1) + sh1
-    q, k, v = _qkv_heads(params["attn"], h, cfg.n_heads)
-    q, k = apply_rope(q, rope), apply_rope(k, rope)
+    if _fuses_qk(rope, cfg.d_model // cfg.n_heads):
+        q, k, v = _joint_qkv([params["attn"]], [h], cfg.n_heads, rope)
+    else:
+        q, k, v = _qkv_heads(params["attn"], h, cfg.n_heads)
+        q, k = apply_rope(q, rope), apply_rope(k, rope)
     x = x + g1 * _joint_attention(q, k, v, params["attn"]["wo"], x.dtype)
     h = common.layernorm(x, cfg.norm_eps) * (1 + sc2) + sh2
     y = jax.nn.gelu(h @ params["mlp"]["wi"].astype(x.dtype))
@@ -218,18 +247,27 @@ def double_block(params, img, txt, cond, cfg: DiTConfig, rope=None):
     """Dual-stream MMDiT block: separate params, joint attention over
     [txt | img] computed once, each stream's slice through its own
     ``wo``."""
+    fused = _fuses_qk(rope, cfg.d_model // cfg.n_heads)
     streams = {"img": img, "txt": txt}
-    qkvs, mods = {}, {}
+    hs, qkvs, mods = {}, {}, {}
     for name in ("img", "txt"):
         p = params[name]
         mods[name] = _modulation(p["mod"], cond, 6)
         sh1, sc1 = mods[name][0], mods[name][1]
         h = common.layernorm(streams[name], cfg.norm_eps) * (1 + sc1) + sh1
-        qkvs[name] = _qkv_heads(p["attn"], h, cfg.n_heads)
+        if fused:
+            hs[name] = h
+        else:
+            qkvs[name] = _qkv_heads(p["attn"], h, cfg.n_heads)
     s_txt = txt.shape[1]
-    q, k, v = (jnp.concatenate([qkvs["txt"][i], qkvs["img"][i]], axis=1)
-               for i in range(3))
-    o = _attention(apply_rope(q, rope), apply_rope(k, rope), v)
+    if fused:
+        q, k, v = _joint_qkv([params["txt"]["attn"], params["img"]["attn"]],
+                             [hs["txt"], hs["img"]], cfg.n_heads, rope)
+    else:
+        q, k, v = (jnp.concatenate([qkvs["txt"][i], qkvs["img"][i]], axis=1)
+                   for i in range(3))
+        q, k = apply_rope(q, rope), apply_rope(k, rope)
+    o = _attention(q, k, v)
     parts = {"txt": o[:, :s_txt], "img": o[:, s_txt:]}
     outs = {}
     for name in ("img", "txt"):

@@ -13,6 +13,7 @@ only one process at a time may load the TPU library, and pytest-xdist
 workers all import this file.
 """
 import functools
+import math
 import os
 
 import jax
@@ -26,6 +27,8 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import freqca_fused
 
 S_IMG, S_JOINT, D, HEADS, HD = 4096, 4096 + 512, 3072, 24, 128
+# FLUX.1-Kontext: 512 text tokens, a 1024 px image and its reference
+S_KONTEXT, ROPE_AXES = 512 + 2 * 4096, (16, 56, 56)
 
 
 @pytest.fixture(scope="module")
@@ -88,46 +91,122 @@ def test_noncausal_flash_compiles_at_dit_xl2_width(one_chip):
     _compile(fn, one_chip, shape, shape, shape)
 
 
-@pytest.mark.parametrize("batch,seq,heads,hd", [
-    pytest.param(8, 1024, 16, 72, id="dit-xl2"),
-    pytest.param(4, S_IMG, HEADS, HD, id="flux"),
-])
-def test_single_block_feeds_flash_without_relayout(one_chip, monkeypatch,
-                                                   batch, seq, heads, hd):
-    """One single block as served, bf16 with the Pallas kernels: the
-    q/k/v projections reach the flash kernel, and its output the
-    out-projection, with no layout copy under ``jit(_flash)``.  At head
-    size 72 that holds because the kernel reads and writes the
-    head-dim-major layout XLA gives the projections."""
-    import re
-
+def _block_cfg(heads, hd, rope):
     from repro.configs.base import DiTConfig
-    from repro.models import dit
-    monkeypatch.setenv("REPRO_KERNELS", "pallas")
-    monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "0")
     d = heads * hd
-    cfg = DiTConfig(arch_id="block", n_layers=1, d_model=d, n_heads=heads,
-                    d_ff=4 * d, patch_size=2, in_channels=4, text_dim=0,
-                    n_text_tokens=0, dtype="bfloat16")
-    block = jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct(p.shape[1:], p.dtype,
-                                       sharding=one_chip),
-        jax.eval_shape(lambda: dit.random_params(cfg, 0))["single"])
-    x = jax.ShapeDtypeStruct((batch, seq, d), jnp.bfloat16, sharding=one_chip)
-    c = jax.ShapeDtypeStruct((batch, d), jnp.bfloat16, sharding=one_chip)
-    text = jax.jit(functools.partial(dit.single_block, cfg=cfg)).lower(
-        block, x, c).compile().as_text()
-    kernels, copies = [], []
+    return DiTConfig(arch_id="block", n_layers=1, n_double=1, d_model=d,
+                     n_heads=heads, d_ff=4 * d, patch_size=2, in_channels=4,
+                     text_dim=0, n_text_tokens=0,
+                     rope_axes=ROPE_AXES if rope else (), dtype="bfloat16")
+
+
+def _compile_block(fn, sharding, params, *shapes, rope_tokens=0):
+    """``fn(params, *activations, cond[, rope])`` compiled for one chip;
+    the activations' layouts in and out left to the compiler, as a
+    scan's carry is, so the program holds no copy at the block's
+    edges."""
+    from jax.experimental.layout import Format, Layout
+    auto = Format(Layout.AUTO, sharding)
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes]
+    # params, the activations, then the conditioning vector
+    in_shardings = [sharding] + [auto] * (len(shapes) - 1) + [sharding]
+    if rope_tokens:
+        half = jax.ShapeDtypeStruct((rope_tokens, HD // 2), jnp.float32)
+        args.append((half, half))
+        in_shardings.append(sharding)
+    return jax.jit(fn, in_shardings=tuple(in_shardings),
+                   out_shardings=auto).lower(
+        params, *args).compile().as_text()
+
+
+def _qk_path(text, qk_elems):
+    """Kernel names in order; the copies under ``jit(_flash)``; and every
+    copy and every f32 array of at least ``qk_elems`` elements that the
+    program writes (not the instructions inside a fusion)."""
+    import re
+    kernels, flash_copies, big_copies, big_f32 = [], [], [], []
+    fused = False
     for line in text.splitlines():
-        name = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = ", line)
+        if re.match(r"\S", line):
+            fused = line.startswith("%fused") or "fusion" in line.split(
+                "(")[0]
+        name = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = (\w+)\[([\d,]*)\]",
+                        line)
         if name is None:
             continue
         if "tpu_custom_call" in line:
             kernels.append(re.sub(r"\.\d+$", "", name.group(1)))
-        elif name.group(1).startswith("copy") and "jit(_flash)" in line:
-            copies.append(line.strip()[:160])
-    assert kernels == ["_flash"]
-    assert copies == []
+            continue
+        elems = math.prod(int(n) for n in name.group(3).split(",") if n)
+        if name.group(1).startswith("copy"):
+            if "jit(_flash)" in line:
+                flash_copies.append(line.strip()[:160])
+            if elems >= qk_elems:
+                big_copies.append(line.strip()[:160])
+        elif not fused and name.group(2) == "f32" and elems >= qk_elems:
+            big_f32.append(line.strip()[:160])
+    return kernels, flash_copies, big_copies, big_f32
+
+
+@pytest.mark.parametrize("batch,seq,heads,hd,rope", [
+    pytest.param(8, 1024, 16, 72, False, id="dit-xl2"),
+    pytest.param(4, S_IMG, HEADS, HD, False, id="flux"),
+    pytest.param(4, S_KONTEXT, HEADS, HD, True, id="kontext"),
+])
+def test_single_block_feeds_flash_without_relayout(one_chip, monkeypatch,
+                                                   batch, seq, heads, hd,
+                                                   rope):
+    """One single block as served, bf16 with the Pallas kernels: the
+    q/k/v projections reach the flash kernel, and its output the
+    out-projection, with no layout copy under ``jit(_flash)``.  At head
+    size 72 that holds because the kernel reads and writes the
+    head-dim-major layout XLA gives the projections.  With RoPE tables
+    (Kontext: 512 text + 2 x 4096 image and reference tokens) q and k
+    each take one pass of the norm-and-RoPE kernel straight into flash's
+    layout: no copy and no f32 array of q's size anywhere in the block."""
+    from repro.models import dit
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "0")
+    cfg = _block_cfg(heads, hd, rope)
+    block = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape[1:], p.dtype),
+        jax.eval_shape(lambda: dit.random_params(cfg, 0))["single"])
+    text = _compile_block(
+        lambda p, x, c, *r: dit.single_block(p, x, c, cfg, *r), one_chip,
+        block, (batch, seq, cfg.d_model), (batch, cfg.d_model),
+        rope_tokens=seq if rope else 0)
+    kernels, flash_copies, big_copies, big_f32 = _qk_path(
+        text, batch * seq * cfg.d_model)
+    assert flash_copies == []
+    if rope:
+        assert kernels == ["_qk_norm_rope", "_qk_norm_rope", "_flash"]
+        assert big_copies == [] and big_f32 == []
+    else:
+        assert kernels == ["_flash"]
+
+
+def test_double_block_writes_the_joined_qk_for_flash(one_chip, monkeypatch):
+    """Kontext's dual-stream block at bucket 4 (512 text tokens, 8192
+    image and reference tokens): q and k of each stream normed and
+    rotated straight into the joined ``[text | image]`` sequence flash
+    reads, so no concatenate copy of q or k, no other copy and no f32
+    array of q's size."""
+    from repro.models import dit
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "0")
+    cfg = _block_cfg(HEADS, HD, True)
+    block = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape[1:], p.dtype),
+        jax.eval_shape(lambda: dit.random_params(cfg, 0))["double"])
+    s_txt = S_KONTEXT - 2 * S_IMG
+    text = _compile_block(
+        lambda p, i, t, c, r: dit.double_block(p, i, t, c, cfg, r),
+        one_chip, block, (4, 2 * S_IMG, D), (4, s_txt, D), (4, D),
+        rope_tokens=S_KONTEXT)
+    kernels, flash_copies, big_copies, big_f32 = _qk_path(
+        text, 4 * 2 * S_IMG * D)
+    assert kernels == ["_qk_norm_rope"] * 4 + ["_flash"]
+    assert flash_copies == big_copies == big_f32 == []
 
 
 def test_step_scopes_leave_the_kernel_names(one_chip, monkeypatch):
